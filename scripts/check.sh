@@ -7,7 +7,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== syntax =="
-python -m compileall -q tnc_tpu tests examples scripts bench.py __graft_entry__.py
+python -m compileall -q tnc_tpu tests examples scripts bench.py chip_smoke.py __graft_entry__.py
 
 echo "== lint =="
 python scripts/lint.py

@@ -104,6 +104,7 @@ def main() -> int:
         _fused_transpose_layouts,
         combine_array,
         fused_transpose_ineligible_reason,
+        interpret_for,
         kernel_plan_summary,
     )
 
@@ -147,6 +148,7 @@ def main() -> int:
             jnp, program, buffers, 8.0,
             split_complex=True, precision="float32",
             sync=jax.block_until_ready, policy=policy,
+            interpret=interpret_for(),
         )
         reg = obs.get_registry()
         amp = combine_array(*out).reshape(program.result_shape)

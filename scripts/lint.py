@@ -97,7 +97,10 @@ def lint_file(path: str) -> list[str]:
 
 
 def main(argv: list[str]) -> int:
-    roots = argv or ["tnc_tpu", "tests", "scripts", "bench.py", "__graft_entry__.py"]
+    roots = argv or [
+        "tnc_tpu", "tests", "scripts", "bench.py", "chip_smoke.py",
+        "__graft_entry__.py",
+    ]
     files: list[str] = []
     for root in roots:
         full = os.path.join(REPO, root)

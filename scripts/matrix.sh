@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Configuration-matrix tier (VERDICT r4 #8) — the Python analogue of the
+# Configuration-matrix tier — the Python analogue of the
 # reference's `cargo hack --feature-powerset` CI
 # (.github/workflows/check.yml): re-run the knob-sensitive test subset
 # under each configuration axis. The default configuration's FULL suite

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """CI smoke: the bf16x3 dot-precision rung's numerical contract, on CPU.
 
-``scripts/hw_campaign2.sh`` step 1b promotes ``precision="high"``
-(3-pass bf16x3 MXU emulation) only after a slice-subset parity check
-against the oracle — but that logic only ever runs inside a live
-hardware window. This smoke is its CI-runnable half: it *emulates* the
+``precision="high"`` (3-pass bf16x3 MXU emulation) may only be
+promoted after a slice-subset parity check against the oracle on the
+device. This smoke is the CI-runnable half of that gate: it *emulates* the
 bf16x3 recomposition explicitly (split each f32 operand into bf16
 (hi, mid) terms, keep the hi·hi + hi·mid + mid·hi cross products,
 accumulate in f32 — the arithmetic the 3-pass mode performs) and
@@ -23,11 +22,11 @@ representative contraction length per shape bucket:
   the ladder is monotone.
 
 What this does NOT validate: the libtpu pass count of
-``lax.Precision.HIGH`` on a given device generation — that stays with
-the hardware campaign's measured A/B (step 1b/1c). The smoke pins the
+``lax.Precision.HIGH`` on a given device generation — that needs a
+measured A/B on the chip, which has not been run. The smoke pins the
 *numerical contract* the promotion logic budgets against.
 
-Mirrors the campaign's promotion verdict: prints
+Prints
 ``promote precision=high: ok`` when every bucket passes its rung.
 Wired into scripts/check.sh.
 """
@@ -154,8 +153,8 @@ def main() -> int:
         run_bucket(name, k, seed, rung)
     print(
         "[precision smoke] promote precision=high: ok "
-        f"(all buckets under {rung:.1e}; hardware pass-count A/B stays "
-        "with hw_campaign2.sh 1b/1c)"
+        f"(all buckets under {rung:.1e}; the pass-count A/B on the chip "
+        "has not been run)"
     )
     print("[precision smoke] PASSED")
     return 0

@@ -3,7 +3,7 @@
 CI — ``cargo test --doc``, ``.github/workflows/test.yml``): executes the
 doctest examples across the WHOLE public module tree and enforces a
 coverage floor — every public module must carry at least one runnable
-example (VERDICT r4 #7), mirroring the reference's per-function examples
+example, mirroring the reference's per-function examples
 (``tnc/src/tensornetwork/tensor.rs:74-83`` and throughout).
 
 Pins the CPU platform first — examples must not depend on accelerator

@@ -1,11 +1,14 @@
-"""Unit tier for ``bench.py``'s pure helpers: the hardware-promoted
-config marker, the hardware-device rule shared with
-``scripts/consolidate_bench.py``, and the cpu-fallback provenance
-attach (the round-3 'lost hardware evidence' failure mode)."""
+"""Unit tier for ``bench.py``'s pure helpers (the config marker, the
+precision ladder) and for what a run may not hide: the compile-cache
+rule, the HBM budget of an unknown accelerator, and a benchmark without
+a chip."""
 
 import json
 import os
+import subprocess
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -48,38 +51,69 @@ def test_tuned_default_reads_marker_and_validates(tmp_path):
     )
 
 
-def test_is_hw_device_rule():
-    assert bench._is_hw_device("tpu:TPU v5 lite")
-    assert bench._is_hw_device("gpu:H100")
-    assert not bench._is_hw_device("cpu:cpu")
-    assert not bench._is_hw_device("cpu-fallback")
-    assert not bench._is_hw_device("virtual8:cpu")
-    assert not bench._is_hw_device("")
-
-
-def test_attach_last_hw_record(tmp_path):
-    hw = {"device": "tpu:TPU v5 lite", "value": 1.9, "vs_baseline": 129489.0}
-    (tmp_path / "BENCH_ALL_r03.json").write_text(
-        json.dumps({"northstar": {"device": "tpu:old", "value": 9.0}})
+def test_bench_without_a_chip_is_an_error():
+    """No accelerator and no BENCH_FORCE_CPU=1: exit 1 with an error
+    line — never a CPU number under a device metric's name."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {
+        k: v for k, v in os.environ.items() if not k.startswith("BENCH_")
+    }
+    env.update(JAX_PLATFORMS="cpu", BENCH_CONFIG="ghz3")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "bench.py")],
+        capture_output=True, text=True, env=env, cwd=root, timeout=300,
     )
-    (tmp_path / "BENCH_ALL_r04.json").write_text(
-        json.dumps({"northstar": hw, "cpu_cfg": {"device": "cpu:cpu"}})
+    assert proc.returncode == 1
+    record = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert "BENCH_FORCE_CPU" in record["error"]
+    assert "device" not in record
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_directory_rule(monkeypatch, tmp_path, placed):
+    """A cache directory placed from outside stands and the code sets
+    none; otherwise the fixed in-checkout path."""
+    import jax
+
+    from tnc_tpu.utils import compile_cache
+
+    calls = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: calls.append((k, v))
     )
-    rec: dict = {}
-    bench._attach_last_hw_record(rec, "northstar", root=str(tmp_path))
-    # newest round artifact wins
-    assert rec["last_hw_record"] == hw
-    assert rec["last_hw_record_source"] == "BENCH_ALL_r04.json"
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = compile_cache.enable_compile_cache()
+    set_dirs = [v for k, v in calls if k == "jax_compilation_cache_dir"]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed:
+        assert got == str(tmp_path) and set_dirs == []
+    else:
+        assert got == os.path.join(root, ".cache", "jax_cache")
+        assert set_dirs == [got]
+    assert got == compile_cache.enable_compile_cache()  # fixed, no pid/time
 
-    # cpu records are never attached as hardware provenance
-    rec2: dict = {}
-    bench._attach_last_hw_record(rec2, "cpu_cfg", root=str(tmp_path))
-    assert "last_hw_record" not in rec2
 
-    # missing config / corrupt artifact: best-effort, no raise
-    bench._attach_last_hw_record({}, "absent", root=str(tmp_path))
-    (tmp_path / "BENCH_ALL_r05.json").write_text("[1, 2]")
-    bench._attach_last_hw_record({}, "northstar", root=str(tmp_path))
+def test_device_hbm_bytes_raises_for_unknown_accelerator(monkeypatch):
+    from tnc_tpu.ops.budget import device_hbm_bytes
+
+    class Dev:
+        def __init__(self, platform, kind, stats=None):
+            self.platform, self.device_kind, self._stats = platform, kind, stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.delenv("TNC_TPU_HBM_BYTES", raising=False)
+    with pytest.raises(ValueError, match="unknown accelerator"):
+        device_hbm_bytes(Dev("tpu", "TPU v9 mega"))
+    assert device_hbm_bytes(Dev("tpu", "TPU v5 lite")) == 16 << 30
+    assert device_hbm_bytes(
+        Dev("tpu", "TPU v9 mega", {"bytes_limit": 123})
+    ) == 123
+    assert device_hbm_bytes(Dev("cpu", "cpu")) == 64 << 30
 
 
 def test_resolve_precision_ladder():
@@ -99,7 +133,7 @@ def test_resolve_precision_ladder():
 def test_bind_resident_repeat_stable():
     """Donation-off contract: the bound executable reuses resident
     buffers across calls bit-identically (the small-network steady-state
-    timing discipline, VERDICT r4 #2)."""
+    timing discipline)."""
     import numpy as np
 
     from tnc_tpu.contractionpath.paths import Greedy, OptMethod

@@ -1,5 +1,5 @@
 """HBM budget model: padded-footprint estimates, batch clamping, and the
-compiled-peak preflight — the regression tests for the BENCH_r02 OOM
+compiled-peak preflight — the regression tests for an early benchmark OOM
 (a 34 GB tile-padded allocation compiled into 16 GB of HBM)."""
 
 import numpy as np

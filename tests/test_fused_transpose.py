@@ -312,8 +312,8 @@ def test_forced_mode_falls_back_counted_on_ineligible(monkeypatch):
 
 
 def test_auto_ladder_end_to_end_matches_oracle(monkeypatch):
-    """The FULL auto ladder (fused-transpose + strassen + chains +
-    precision rungs, planned from an injected calibrated model with a
+    """The FULL auto ladder (fused-transpose + strassen + precision
+    rungs, planned from an injected calibrated model with a
     bandwidth term) through the jitted executor, allclose-pinned
     against the complex128 numpy oracle."""
     from tnc_tpu.obs.calibrate import CalibratedCostModel
@@ -328,7 +328,10 @@ def test_auto_ladder_end_to_end_matches_oracle(monkeypatch):
     )
     policy = plan_kernels(program, cost_model=model)
     assert "fused_transpose" in policy.modes, policy.modes
-    fn = jit_program(program, True, "float32", donate=False, policy=policy)
+    fn = jit_program(
+        program, True, "float32", donate=False, policy=policy,
+        interpret=True,
+    )
     out = fn(place_buffers(arrays, "complex64", True))
     got = np.asarray(combine_array(*out)).reshape(program.result_shape)
     want = NumpyBackend(dtype=np.complex128).execute(program, arrays)
@@ -364,27 +367,22 @@ def test_auto_promotion_requires_bandwidth_evidence():
     assert plan_kernels(program, cost_model=fast_bytes).modes == ("gauss",)
 
 
-def test_chain_bucket_expansion_follows_dispatch_cost():
-    """PR 6's chain rung extended upward: a fitted model whose
-    dispatch overhead dwarfs MIN_FLOPS raises the chain ceiling
-    (chain_flop_ceiling) so medium-bucket steps fuse; a cheap-dispatch
-    model keeps the static small-step ceiling — chains engage exactly
-    when dispatch_equivalent_flops pays."""
+def test_chain_ceiling_admits_medium_steps_only_when_forced():
+    """``chain_groups`` admits a VMEM-small medium-step run under a
+    raised ``max_flops``; ``plan_kernels`` reaches it only through
+    ``force="chain"`` — the unforced ladder plans no chain whatever a
+    fitted model says about dispatch cost (the chain kernel has never
+    compiled for a TPU)."""
     from tnc_tpu.obs.calibrate import CalibratedCostModel
     from tnc_tpu.ops.program import chain_groups
-    from tnc_tpu.ops.split_complex import chain_flop_ceiling
 
-    cheap = CalibratedCostModel(flops_per_s=1e12, dispatch_s=1e-9)
-    assert chain_flop_ceiling(cheap) == float(MIN_FLOPS)
-    assert chain_flop_ceiling(None) == float(MIN_FLOPS)
     costly = CalibratedCostModel(flops_per_s=1e12, dispatch_s=1e-2)
-    ceiling = chain_flop_ceiling(costly)
-    assert ceiling == 2.0 * costly.dispatch_equivalent_flops() > MIN_FLOPS
+    ceiling = 2.0 * costly.dispatch_equivalent_flops()
+    assert ceiling > MIN_FLOPS
 
     # a matrix-product chain whose every step is ABOVE the static
     # small-step bound (2*256^3 = 2^25 flops) yet VMEM-small and
-    # trivially carried — the dispatch-bound medium regime the
-    # calibrated ceiling exists for
+    # trivially carried
     from tnc_tpu.contractionpath.contraction_path import ContractionPath
     from tnc_tpu.ops.program import build_program, step_flops
     from tnc_tpu.ops.split_complex import plan_kernels
@@ -409,10 +407,11 @@ def test_chain_bucket_expansion_follows_dispatch_cost():
     expanded = chain_groups(program.steps, max_flops=ceiling)
     assert expanded, "raised ceiling did not admit the medium-step chain"
 
-    # and plan_kernels wires the ceiling end to end: the costly model
-    # fuses the run, the cheap one doesn't
-    assert plan_kernels(program, cost_model=costly).chains
-    assert not plan_kernels(program, cost_model=cheap).chains
+    assert plan_kernels(
+        program, force="chain", chain_max_flops=ceiling
+    ).chains
+    assert not plan_kernels(program, cost_model=costly).chains
+    assert not plan_kernels(program).chains
 
 
 # -- precision ladder ---------------------------------------------------
@@ -690,7 +689,7 @@ def test_run_steps_timed_credits_saved_transpose_and_precision(enabled_obs=None)
             run_steps_timed(
                 jnp, program, buffers, 8.0, split_complex=True,
                 precision="float32", sync=jax.block_until_ready,
-                policy=policy,
+                policy=policy, interpret=True,
             )
             return [
                 r for r in obs.get_registry().span_records()

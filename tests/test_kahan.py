@@ -2,9 +2,9 @@
 
 The sliced executors accumulate thousands of per-slice contributions
 whose total cancels to far below the individual terms; plain f32
-accumulation loses the 1e-5 parity target there (VERDICT r3 #2,
-reference accuracy contract ``tnc/tests/integration_tests.rs`` epsilon
-assertions). These tests pin down that:
+accumulation loses the 1e-5 parity target there (reference accuracy
+contract: ``tnc/tests/integration_tests.rs`` epsilon assertions).
+These tests pin down that:
 
 - ``kahan_add`` actually compensates (XLA must not algebraically cancel
   ``y - (t - s)`` under jit — it doesn't: XLA preserves FP semantics

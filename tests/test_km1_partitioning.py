@@ -3,8 +3,7 @@
 The reference embeds two distinct KaHyPar configs — cut vs km1 — plus a
 ``Custom(path)`` variant (``tnc/src/tensornetwork/partition_config.rs:
 12-36``, selected at ``partitioning.rs:40-55``). These tests pin down
-that the two presets here are *actually different objectives* (VERDICT
-r3 missing #1): km1 refinement strictly improves the connectivity metric
+that the two presets here are *actually different objectives*: km1 refinement strictly improves the connectivity metric
 on a fixture where cut and km1 disagree, the Python and native
 refinements agree on the metric they optimize, and the config object
 overrides presets.

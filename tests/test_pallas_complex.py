@@ -1,8 +1,8 @@
 """Fused split-complex Pallas kernel (interpret mode on CPU).
 
 The ``fused`` complex-mult mode computes re/im in one kernel with each
-operand tile loaded once (docs/future_work.md item 2); the hardware A/B
-runs in scripts/hw_campaign.sh. These tests pin interpret-mode
+operand tile loaded once (docs/future_work.md item 2); tests/test_v5e_compile.py
+compiles the kernel for a described v5e. These tests pin interpret-mode
 correctness against complex128 numpy, the vmap path the chunked
 executor uses, eligibility gating, and the per-step fallback inside
 ``apply_step_split``.
@@ -323,7 +323,7 @@ def test_chain_fused_vs_unfused_policy_allclose():
 
     buffers = place_buffers(arrays, "complex64", True)
     fused = run_steps_split(
-        jnp, program, buffers, "float32", policy=policy
+        jnp, program, buffers, "float32", policy=policy, interpret=True
     )
     seq_policy = KernelPolicy(policy.modes, ())
     buffers = place_buffers(arrays, "complex64", True)

@@ -74,7 +74,7 @@ def test_extra_legs_minimize_total_flops():
 
 def test_rank_solution_gates_budget_missing_plans():
     """A plan whose global slicing cannot reach the modeled budget must
-    rank unplaceable (the 53q OOM class, TPU_EVIDENCE_r05.md)."""
+    rank unplaceable (the 53q OOM class)."""
     import os
     import sys
 

@@ -162,7 +162,7 @@ def test_validate_path():
 
 
 def test_hyper_trials_parallel_matches_serial(monkeypatch):
-    """The spawn-pool trial runner (VERDICT r3 #8) must reproduce the
+    """The spawn-pool trial runner must reproduce the
     serial winner exactly — trial t always draws from Random(seed+t) and
     results merge by trial index, so worker count cannot change the
     outcome."""

@@ -102,7 +102,7 @@ def test_random_network_sliced_consistency(seed):
 def test_random_network_split_complex_mult_modes(seed, mode, monkeypatch):
     """Fuzz both complex-multiply lowerings (split-complex f32) against
     the complex128 oracle on random networks — the naive 4-dot mode is
-    the benchmark default (VERDICT r3 #2)."""
+    the benchmark default."""
     from tnc_tpu.ops.backends import JaxBackend
     from tnc_tpu.ops.program import build_program, flat_leaf_tensors
 

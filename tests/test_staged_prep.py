@@ -2,7 +2,7 @@
 
 Large operands whose naive reshape→transpose would materialize a
 high-rank view with tiny trailing dims (XLA tile-pads those 16-128× —
-the BENCH_r02/r03 OOM mode) get a staged op plan from the compiler
+the OOM mode of early benchmark rounds) get a staged op plan from the compiler
 (`program._staged_ops`): leading-dim transposes over an intact ≥128
 fused tail plus one exact lane permutation. These tests pin (a) the
 planner's bit-exactness and minor-dim invariant on randomized

@@ -193,18 +193,20 @@ def test_auto_policy_promotes_stem_to_strassen(monkeypatch):
     assert "strassen" not in small_policy.modes  # nothing clears 8^3
 
 
-def test_auto_policy_respects_cost_model_dispatch():
-    """A zero-dispatch-overhead model kills every chain (fusing saves
-    nothing, the naive-vs-gauss flop cost remains); a huge overhead
-    keeps them all."""
+def test_auto_policy_never_plans_chains():
+    """The chain kernel has never compiled for a TPU, so the unforced
+    ladder plans no chain — with no model, and with a fitted model at
+    any dispatch overhead; ``force="chain"`` is the only way to one."""
     from tnc_tpu.obs.calibrate import CalibratedCostModel
     from tnc_tpu.ops.split_complex import plan_kernels
 
     program, _ = _program()
+    assert plan_kernels(program).chains == ()
     free_dispatch = CalibratedCostModel(flops_per_s=1e12, dispatch_s=0.0)
     assert plan_kernels(program, cost_model=free_dispatch).chains == ()
     costly = CalibratedCostModel(flops_per_s=1e12, dispatch_s=1e-3)
-    assert plan_kernels(program, cost_model=costly).chains != ()
+    assert plan_kernels(program, cost_model=costly).chains == ()
+    assert plan_kernels(program, force="chain").chains != ()
 
 
 def test_chained_steps_carry_naive_mode():

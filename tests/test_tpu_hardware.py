@@ -181,8 +181,8 @@ def test_donation_keeps_result_correct_on_repeat(device):
 def test_compiled_peak_matches_budget_model(device):
     """Near-HBM-scale compile: XLA's measured footprint must stay within
     ~1.5x of the budget model's padded prediction — the regression test
-    for the BENCH_r02 failure, where a 2.1 GB logical buffer compiled to
-    a 34 GB tile-padded allocation (VERDICT round 2, weak #1/#2)."""
+    for an early benchmark failure, where a 2.1 GB logical buffer compiled to
+    a 34 GB tile-padded allocation."""
     import jax
 
     from tnc_tpu.ops.budget import compiled_peak_bytes, program_peak_bytes
@@ -209,7 +209,7 @@ def test_compiled_peak_matches_budget_model(device):
         return run_steps_split(jnp, program, list(buffers), "float32")
 
     compiled = compiled_peak_bytes(fn, (specs,))
-    # compiled footprint must not blow past the model (the BENCH_r02
+    # compiled footprint must not blow past the model (that
     # failure mode was a ~16x overshoot)
     assert compiled <= est.peak_bytes * 1.5, (compiled, est.peak_bytes)
 
@@ -296,7 +296,7 @@ def test_naive_mult_kahan_bench_arithmetic_parity(device):
     """The benchmark's exact arithmetic on device — naive 4-dot complex
     multiply + Kahan-compensated slice accumulation at
     precision='float32' — vs the complex128 oracle, on a deep sliced
-    program (the round-4 parity mechanisms, VERDICT r3 #2)."""
+    program (the round-4 parity mechanisms)."""
     from tnc_tpu.builders.connectivity import ConnectivityLayout
     from tnc_tpu.builders.random_circuit import random_circuit
     from tnc_tpu.contractionpath.contraction_path import ContractionPath

@@ -1,0 +1,326 @@
+"""Ask the TPU v5e's compiler, without the chip.
+
+Every program here is lowered on ``jax.ShapeDtypeStruct``s that carry a
+*described* ``v5e:2x2`` device's sharding and compiled with
+``interpret=False`` — what Mosaic and XLA:TPU refuse here they refuse
+on the chip. Nothing runs, so these say nothing about results or times.
+
+The topology is described inside a module-scoped fixture (only the
+xdist worker that is handed this file loads the TPU library); nothing
+at import time touches it.
+"""
+
+from __future__ import annotations
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described chip, under the configuration a chip run has: x64
+    off (the suite's conftest turns it on; the device path is f32), and
+    the persistent compile cache off — a compile for a described device
+    is written to it but can never be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    x64 = jax.config.jax_enable_x64
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_x64", x64)
+    jax.config.update("jax_enable_compilation_cache", cache)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _pair_specs(shapes, sharding):
+    """Split-complex (real, imag) f32 shape pairs on ``sharding``."""
+    return [
+        (jax.ShapeDtypeStruct(tuple(s), jnp.float32, sharding=sharding),) * 2
+        for s in shapes
+    ]
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _total_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return int(
+        ma.argument_size_in_bytes
+        + ma.output_size_in_bytes
+        + ma.temp_size_in_bytes
+    )
+
+
+@pytest.fixture(scope="module")
+def chain_bearing():
+    """A 24-qubit depth-8 Sycamore-layout amplitude network, greedy
+    path: a program ``chain_groups`` finds fusable runs in."""
+    from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
+    from tnc_tpu.contractionpath.paths import Greedy, OptMethod
+    from tnc_tpu.ops.program import build_program, flat_leaf_tensors
+
+    tn, _ = sycamore_circuit(
+        24, 8, np.random.default_rng(42)
+    ).into_amplitude_network("0" * 24)
+    path = Greedy(OptMethod.GREEDY).find_path(tn).replace_path()
+    program = build_program(tn, path)
+    shapes = [leaf.data.into_data().shape for leaf in flat_leaf_tensors(tn)]
+    return program, shapes
+
+
+@pytest.fixture(scope="module")
+def northstar():
+    """Sycamore-53 m=14 at the 2^29 slice target, planned by the
+    cheapest planner that reaches the target (the tests are of shapes
+    at the target, not of plan quality): the hoisted split, the leaf
+    shapes and the budget-clamped slice batch the executor would run."""
+    from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
+    from tnc_tpu.contractionpath.contraction_path import ContractionPath
+    from tnc_tpu.contractionpath.paths import Greedy, OptMethod
+    from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
+    from tnc_tpu.ops.budget import clamp_slice_batch
+    from tnc_tpu.ops.hoist import hoist_sliced_program
+    from tnc_tpu.ops.program import flat_leaf_tensors
+    from tnc_tpu.ops.sliced import build_sliced_program
+    from tnc_tpu.tensornetwork.simplify import simplify_network
+
+    raw, _ = sycamore_circuit(
+        53, 14, np.random.default_rng(42)
+    ).into_amplitude_network("0" * 53)
+    tn = simplify_network(raw)
+    result = Greedy(OptMethod.GREEDY).find_path(tn)
+    pairs, slicing = slice_and_reconfigure(
+        list(tn.tensors), result.ssa_path.toplevel, 2.0**29
+    )
+    sp = build_sliced_program(tn, ContractionPath.simple(pairs), slicing)
+    hp = hoist_sliced_program(sp)
+    assert not hp.is_noop
+    shapes = [leaf.data.into_data().shape for leaf in flat_leaf_tensors(tn)]
+    batch = clamp_slice_batch(
+        hp.residual.program, 8, hbm_bytes=V5E_HBM_BYTES
+    )
+    while slicing.num_slices % batch:
+        batch -= 1
+    return sp, hp, shapes, batch
+
+
+def test_interpret_follows_the_backend_device(topo):
+    """Interpret mode comes from the device a backend targets, not from
+    the process: in this CPU process a backend built for the described
+    chip compiles its kernels for real."""
+    from tnc_tpu.ops.backends import JaxBackend
+    from tnc_tpu.ops.split_complex import interpret_for
+
+    on_chip = JaxBackend(device=topo.devices[0])
+    assert on_chip.split_complex and not on_chip.interpret
+    on_cpu = JaxBackend(device=jax.devices("cpu")[0])
+    assert on_cpu.interpret and not on_cpu.split_complex
+    assert not interpret_for(topo.devices[0])
+    assert interpret_for() == (jax.devices()[0].platform == "cpu")
+
+
+def test_default_policy_compiles_for_chain_bearing_program(
+    one_chip, chain_bearing
+):
+    """The unforced policy on a program that has chain groups plans no
+    chain and compiles (before this test existed it planned three
+    kinds of Pallas chain call here and Mosaic refused the program)."""
+    from tnc_tpu.ops.backends import jit_program
+    from tnc_tpu.ops.split_complex import plan_kernels
+
+    program, shapes = chain_bearing
+    assert plan_kernels(program, force="chain").chains
+    policy = plan_kernels(program)
+    assert policy.chains == ()
+    fn = jit_program(
+        program, True, "float32", donate=False, policy=policy,
+        interpret=False,
+    )
+    fn.jitted.lower(_pair_specs(shapes, one_chip)).compile()
+
+
+def test_chain_kernel_is_still_refused(
+    one_chip, chain_bearing
+):
+    """Why the default plans no chain: forced, the chain kernel's
+    in-kernel regroup of the carried value is refused by Mosaic when
+    the enclosing jit compiles. When this starts to pass, chains can be
+    priced into the unforced policy again (ROADMAP C2)."""
+    from tnc_tpu.ops.backends import jit_program
+    from tnc_tpu.ops.split_complex import plan_kernels
+
+    program, shapes = chain_bearing
+    policy = plan_kernels(program, force="chain")
+    fn = jit_program(
+        program, True, "float32", donate=False, policy=policy,
+        interpret=False,
+    )
+    with pytest.raises(Exception, match="Mosaic failed to compile"):
+        fn.jitted.lower(_pair_specs(shapes, one_chip)).compile()
+
+
+def test_northstar_prelude_compiles(one_chip, northstar):
+    from tnc_tpu.ops.chunked import _prelude_fn
+
+    _, hp, shapes, _ = northstar
+    full = _pair_specs(shapes, one_chip)
+    pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
+    compiled = _prelude_fn(hp, True, "float32", interpret=False).lower(
+        pins
+    ).compile()
+    assert _total_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_northstar_chunk_compiles_within_hbm(
+    one_chip, northstar
+):
+    """The first chunk of the chunked sliced executor — the one that
+    holds the budget model's peak step — at its real shapes and slice
+    batch, against 16 GB."""
+    from tnc_tpu.ops.budget import program_peak_bytes
+    from tnc_tpu.ops.chunked import _compiled_plan, _prelude_fn
+
+    sp, hp, shapes, batch = northstar
+    residual = hp.residual
+    chunks, chunk_fns = _compiled_plan(
+        residual, batch, 64, True, "float32", interpret=False
+    )
+    assert program_peak_bytes(residual.program).peak_step < len(
+        chunks[0].steps
+    )
+    full = _pair_specs(shapes, one_chip)
+    pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
+    cached = iter(
+        _on(
+            one_chip,
+            jax.eval_shape(
+                _prelude_fn(hp, True, "float32", interpret=False), pins
+            ),
+        )
+    )
+    inputs = [
+        full[ref] if kind == "leaf" else next(cached)
+        for kind, ref in hp.residual_sources
+    ]
+    ins = tuple(inputs[slot] for slot in chunks[0].in_slots)
+    idx = jax.ShapeDtypeStruct(
+        (batch, len(sp.slicing.dims)), jnp.int32, sharding=one_chip
+    )
+    compiled = chunk_fns[0].lower(ins, idx).compile()
+    assert _total_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_fused_complex_dot_compiles(one_chip):
+    from jax import lax
+
+    from tnc_tpu.ops.pallas_complex import (
+        fused_complex_dot_kl,
+        ineligible_reason,
+    )
+
+    assert ineligible_reason(512, 1024, 1024) is None
+    op = jax.ShapeDtypeStruct((512, 1024), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda ar, ai, br, bi: fused_complex_dot_kl(
+            ar, ai, br, bi, interpret=False,
+            precision=lax.Precision.HIGHEST,
+        )
+    ).lower(op, op, op, op).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_transpose_dot_compiles(one_chip):
+    from jax import lax
+
+    from tnc_tpu.ops.pallas_complex import (
+        fused_transpose_dot_kl,
+        operand_layout,
+        transpose_dot_ineligible_reason,
+    )
+
+    # first operand stored (M, K) with a macro transpose to (K, M)
+    a_lay = operand_layout((1024, 512), (1, 0), (512, 1024), True)
+    b_lay = operand_layout((512, 1024), None, (512, 1024), True)
+    assert (
+        transpose_dot_ineligible_reason(a_lay, b_lay, 512, 1024, 1024)
+        is None
+    )
+    a = jax.ShapeDtypeStruct((1024, 512), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((512, 1024), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda ar, ai, br, bi: fused_transpose_dot_kl(
+            ar, ai, br, bi, a_lay, b_lay, interpret=False,
+            precision=lax.Precision.HIGHEST,
+        )
+    ).lower(a, a, b, b).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_slice_spmd_compiles_for_four_chips(topo):
+    """The slice-SPMD function over the four described devices:
+    replicated leaves in, each device's share of the slice loop, one
+    all-reduce out. (A smaller sliced network than the north-star: the
+    53-qubit body compiles too — 142 s, 3.3 GiB per device, asked once
+    by hand — but not inside this file's time.)"""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
+    from tnc_tpu.contractionpath.contraction_path import ContractionPath
+    from tnc_tpu.contractionpath.paths import Greedy, OptMethod
+    from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
+    from tnc_tpu.ops.program import flat_leaf_tensors
+    from tnc_tpu.ops.sliced import build_sliced_program
+    from tnc_tpu.parallel.sliced_parallel import _make_spmd_fn
+    from tnc_tpu.tensornetwork.simplify import simplify_network
+
+    raw, _ = sycamore_circuit(
+        30, 10, np.random.default_rng(42)
+    ).into_amplitude_network("0" * 30)
+    tn = simplify_network(raw)
+    result = Greedy(OptMethod.GREEDY).find_path(tn)
+    pairs, slicing = slice_and_reconfigure(
+        list(tn.tensors), result.ssa_path.toplevel, result.size / 64.0
+    )
+    assert slicing.num_slices % 4 == 0
+    sp = build_sliced_program(tn, ContractionPath.simple(pairs), slicing)
+    mesh = Mesh(np.asarray(topo.devices), ("slices",))
+    assert mesh.shape["slices"] == 4
+    fn = _make_spmd_fn(
+        sp, mesh, "slices", "complex64", True, "float32", hoist=True
+    )
+    shapes = [leaf.data.into_data().shape for leaf in flat_leaf_tensors(tn)]
+    replicated = NamedSharding(mesh, PartitionSpec())
+    compiled = fn.lower(*_pair_specs(shapes, replicated)).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert _total_bytes(compiled) < V5E_HBM_BYTES

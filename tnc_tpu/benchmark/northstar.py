@@ -1,10 +1,9 @@
 """Shared north-star plan-cache identifiers.
 
 Single source of truth for the plan cache key and the plan-content
-fingerprint, imported by ``bench.py``, ``scripts/oracle_status.py``, and
-``scripts/stamp_oracle_fp.py`` — hand-copied key construction desyncs
-silently on the next version bump, and a desynced status probe makes a
-live hardware window redo cached oracle work.
+fingerprint, imported by ``bench.py`` — hand-copied key construction
+desyncs silently on the next version bump, and a desynced key makes a
+run redo cached oracle work.
 """
 
 from __future__ import annotations

@@ -493,7 +493,7 @@ class Hyperoptimizer(Pathfinder):
         spawn-safe process pool when the host has cores to spare — the
         rayon-style search parallelism the reference applies to its SA
         trials (``repartitioning/simulated_annealing.rs:113-135``),
-        applied to the hyper search (VERDICT r3 #8).
+        applied to the hyper search.
 
         Deterministic merge: trial ``t`` always uses
         ``random.Random(seed + t)``, and results come back indexed by

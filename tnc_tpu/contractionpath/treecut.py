@@ -13,7 +13,7 @@ work penalty: on the config-5 instance the best SA-rebalanced plan
 summed to 3.4e10 flops while a single good serial tree (the native
 hyper-optimizer's) needs only 4.6e9 (measured round 5).
 
-This module takes the opposite route — the VERDICT-r4 #5 suggestion of
+This module takes the opposite route —
 cutting the contraction **tree** top-down so fan-in latencies balance:
 
 1. Start from one good *serial* tree over the whole network (the caller

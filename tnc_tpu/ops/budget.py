@@ -9,8 +9,8 @@ node RAM. On TPU the binding constraint is tighter — a single chip's HBM
 because f32 buffers are stored in (sublane × 128-lane) tiles: a trailing
 dim below 128 pads up to it.
 
-This module is the executor-side guardrail the round-2 bench lacked
-(BENCH_r02 compiled a 34 GB padded buffer into 16 GB of HBM): it models
+This module is the executor-side guardrail (an early benchmark round
+compiled a 34 GB padded buffer into 16 GB of HBM): it models
 the padded footprint of a compiled program step by step and clamps the
 chunked executor's ``slice_batch`` — or reports that a deeper slicing
 target is needed — so the plan provably fits before anything is
@@ -47,7 +47,10 @@ def device_hbm_bytes(device=None) -> int:
     """Usable accelerator memory for ``device`` (default: first device).
 
     Order: ``TNC_TPU_HBM_BYTES`` env override → live ``memory_stats()``
-    → device-kind table → 16 GiB fallback.
+    → device-kind table. An accelerator that reports no stats and whose
+    kind is not in the table raises — a guessed budget would let a plan
+    through that does not fit. The CPU backend gets a host-RAM-class
+    64 GiB (tests).
     """
     env = os.environ.get("TNC_TPU_HBM_BYTES")
     if env:
@@ -56,19 +59,22 @@ def device_hbm_bytes(device=None) -> int:
         import jax
 
         device = jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:  # pragma: no cover - backend-dependent
-        pass
+    stats = device.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
     kind = getattr(device, "device_kind", "").lower()
     for tag, n in _HBM_BYTES.items():
         if tag in kind:
             return n
-    if getattr(device, "platform", "") == "cpu":
+    if device.platform == "cpu":
         return 64 << 30  # host RAM-class budget for the CPU backend
-    return 16 << 30
+    raise ValueError(
+        f"unknown accelerator {device.platform!r} kind "
+        f"{getattr(device, 'device_kind', '')!r}: it reports no "
+        "memory_stats() and is not in the HBM table "
+        "(tnc_tpu.ops.budget._HBM_BYTES); add it there or set "
+        "TNC_TPU_HBM_BYTES"
+    )
 
 
 def padded_elems(shape: tuple[int, ...]) -> int:

@@ -241,6 +241,7 @@ def run_prelude_steps(
     prelude_buffers: Sequence[Any],
     split_complex: bool = False,
     precision=None,
+    interpret: bool = False,
 ) -> list[Any]:
     """Execute the prelude steps over ``prelude_buffers`` (one buffer
     per ``hp.prelude_inputs`` entry, in that order; (real, imag) pairs
@@ -270,7 +271,8 @@ def run_prelude_steps(
 
         def kernel(a, b, step):
             return apply_step_split(
-                xp, a, b, step, precision, mode=auto_step_mode(step)
+                xp, a, b, step, precision, mode=auto_step_mode(step),
+                interpret=interpret,
             )
 
     else:
@@ -298,6 +300,7 @@ def run_prelude(
     arrays: Sequence[Any],
     split_complex: bool = False,
     precision=None,
+    interpret: bool = False,
 ) -> list[Any]:
     """Execute the prelude once and assemble the residual input buffers.
 
@@ -314,6 +317,7 @@ def run_prelude(
             [arrays[orig] for _, orig in hp.prelude_inputs],
             split_complex,
             precision,
+            interpret,
         )
     )
     return [

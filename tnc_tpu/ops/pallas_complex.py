@@ -13,8 +13,7 @@ one pass:
 with each operand tile loaded into VMEM **once** per grid cell and both
 accumulators living in VMEM scratch across the K loop — roughly halving
 operand HBM traffic on bandwidth-bound steps and deleting the epilogue
-passes entirely (docs/future_work.md item 2; the MFU-attribution work of
-VERDICT r3 #4).
+passes entirely (docs/future_work.md item 2).
 
 Layout: operands arrive exactly as the program compiler's dot layout
 produces them — contract-dim-leading 2-D views ``A:(K, M)``,
@@ -24,9 +23,10 @@ and shapes must divide their tiles (program dims are powers of two, so
 any dim ≥ the tile divides it; smaller/ragged shapes fall back).
 
 Selected with ``TNC_TPU_COMPLEX_MULT=fused``; correctness is pinned in
-interpret mode on CPU (tests/test_pallas_complex.py) and the hardware
-A/B runs in ``scripts/hw_campaign.sh``. Meant to be called inside an
-outer ``jax.jit`` (every executor's step kernel already is).
+interpret mode on CPU (tests/test_pallas_complex.py) and the kernel is
+compiled for a described v5e in tests/test_v5e_compile.py. Meant to be
+called inside an outer ``jax.jit`` (every executor's step kernel
+already is).
 
 This module also carries the **fused multi-step chain kernel**
 (:func:`fused_chain_kl`): a run of consecutive small residual PairSteps

@@ -290,7 +290,7 @@ def _padded_elems(shape) -> float:
 def _naive_prep_bad(view, perm) -> bool:
     """True when executing ``reshape(view); transpose(perm)`` would
     materialize a buffer padded more than ``_STAGED_PAD_FACTOR``× its
-    logical size (the BENCH_r02/r03 OOM mode: high-rank views with tiny
+    logical size (the OOM mode of early benchmark rounds: high-rank views with tiny
     trailing dims tile-pad 16-128×)."""
     if perm is None:
         return False

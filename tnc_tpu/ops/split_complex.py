@@ -57,19 +57,19 @@ EFFECTIVE_FLOP_FACTOR = {
 #: recomposition (closest to true f32 — the backend's ``float32``
 #: default), ``high`` = the 3-pass bf16x3 (≈2× dot throughput at
 #: ≈2^-21 per-product relative error; the rung
-#: ``scripts/hw_campaign2.sh`` step 1b A/Bs and
-#: ``scripts/precision_parity_smoke.py`` pins numerically).
+#: ``scripts/precision_parity_smoke.py`` pins numerically — its device
+#: pass count has never been A/B'd on a chip).
 DOT_PRECISION_MODES = ("highest", "high")
 
 #: documented per-dot relative-error rung of bf16x3 (``high``): the
 #: 3-term recomposition drops the mid·mid and lo cross products, so
 #: its error floor is ~2^-18 relative to the result magnitude —
 #: measured per bucket k-length at ≤5.3e-6 by
-#: ``scripts/precision_parity_smoke.py`` (the CI half of
-#: ``hw_campaign2.sh`` step 1b). :func:`plan_precision_modes` only
-#: promotes when the run's parity budget clears this rung with 2×
-#: headroom; the hardware campaign's slice-subset parity oracle stays
-#: the final gate.
+#: ``scripts/precision_parity_smoke.py``.
+#: :func:`plan_precision_modes` only promotes when the run's parity
+#: budget clears this rung with 2× headroom; a slice-subset parity
+#: check against the complex128 oracle on the device stays the final
+#: gate.
 HIGH_PRECISION_STEP_REL = 2.0 ** -18
 
 
@@ -90,7 +90,7 @@ def complex_mult_env() -> str:
       (the classic Karatsuba instability).
     - ``naive``: 4 real dots (rr-ii, ri+ir) — each dot's error is
       relative to its own product magnitude (the half-digit-tighter
-      rung of the parity ladder, VERDICT r3 #2).
+      rung of the parity ladder).
     - ``fused``: one Pallas kernel computing both outputs with each
       operand tile loaded once (:mod:`tnc_tpu.ops.pallas_complex`);
       naive-mode arithmetic, ~half the operand HBM traffic. Steps the
@@ -201,6 +201,20 @@ def resolved_step_mode(step, mode: str | None = None) -> str:
     return "gauss"
 
 
+def interpret_for(device=None) -> bool:
+    """Should Pallas kernels aimed at ``device`` (default: the first JAX
+    device) run in interpret mode? Only a CPU device interprets; every
+    accelerator compiles the kernel for real. Resolved once where an
+    executor learns its device and passed down as ``interpret=`` — the
+    kernels never ask the process what it runs on, so a compile for a
+    described chip lowers the chip's kernel, not the interpreter's."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    return device.platform == "cpu"
+
+
 def split_array(array: np.ndarray, dtype: str = "float32") -> tuple[np.ndarray, np.ndarray]:
     """Complex array -> contiguous (real, imag) float pair.
 
@@ -294,7 +308,8 @@ def _strassen_step(xp, ar, ai, br, bi, step, precision):
 
 
 def apply_step_split(
-    xp, apair, bpair, step, precision=None, mode=None, precision_mode=None
+    xp, apair, bpair, step, precision=None, mode=None, precision_mode=None,
+    interpret: bool = False,
 ):
     """Split-complex analogue of ``backends.apply_step``: one pairwise
     contraction of (real, imag) pairs. The single source of truth
@@ -304,17 +319,21 @@ def apply_step_split(
     ``precision_mode`` is the policy's per-step dot-precision rung
     (``high``/``highest``; empty defers to the
     ``TNC_TPU_DOT_PRECISION`` override, then the backend
-    ``precision``)."""
+    ``precision``). ``interpret`` runs the Pallas kernels of the
+    ``fused`` / ``fused_transpose`` modes in interpret mode — decided by
+    the caller from the device it targets (:func:`interpret_for`), never
+    from the process."""
     from tnc_tpu.ops.backends import _prep_operand
 
     if mode == "fused_transpose" and xp is not np:
         # the fused transpose-dot consumes the RAW stored views — it
         # must run BEFORE _prep_operand materializes the macro
-        # transpose (that pass is exactly what it deletes); on
-        # fallback the standard prep+naive path below takes over
+        # transpose (that pass is exactly what it deletes); a step the
+        # eligibility gate routes away takes the prep+naive path below
         out = _try_fused_transpose_step(
             apair, bpair, step,
             _resolve_step_precision(precision, precision_mode),
+            interpret,
         )
         if out is not None:
             return out
@@ -374,10 +393,10 @@ def apply_step_split(
         return lax.dot_general(x, y, ((ca, cb), ((), ())), precision=prec)
 
     if mode == "fused":
-        out = _try_fused_step(ar, ai, br, bi, step, prec)
+        out = _try_fused_step(ar, ai, br, bi, step, prec, interpret)
         if out is not None:
             return out
-        mode = "naive"  # per-step fallback: same arithmetic
+        mode = "naive"  # routed away by eligibility: same arithmetic
     if mode == "naive":
         re = dot(ar, br) - dot(ai, bi)
         im = dot(ar, bi) + dot(ai, br)
@@ -418,19 +437,14 @@ def _note_fused_fallback(reason: str, k: int, m: int, n: int, detail=""):
         logger.warning(msg)
 
 
-def _try_fused_step(ar, ai, br, bi, step, precision):
+def _try_fused_step(ar, ai, br, bi, step, precision, interpret=False):
     """Route one step through the fused Pallas kernel when its layout
     allows (both operands contract-dim-leading, tileable shapes, big
     enough to amortize the grid); None means 'use the naive dots'.
-    Every fallback is counted (``ops.fused_fallback``) with its
-    eligibility reason — layout vs dtype vs tile/flop floor vs a
-    kernel error — so bench records show *why* fused didn't fire.
-
-    Caveat on failure surfaces: this runs at *trace* time under the
-    executor's jit, so only trace-time errors can trigger the fallback
-    (logged, not silent). A Mosaic lowering failure surfaces later when
-    the enclosing jit compiles — the campaign's fused A/B stage is
-    self-contained so such a failure costs one stage, not the window.
+    Every routed-away step is counted (``ops.fused_fallback``) with its
+    eligibility reason — layout vs dtype vs tile/flop floor — so
+    records show *why* fused didn't fire. Routing is planning; a kernel
+    that was routed here and cannot trace or compile fails the run.
     """
     k = int(step.a_dot[0]) if step.a_cfirst else int(step.a_dot[-1])
     m = int(np.prod(step.a_dot, dtype=np.int64)) // max(k, 1)
@@ -452,23 +466,16 @@ def _try_fused_step(ar, ai, br, bi, step, precision):
     if reason is not None:
         _note_fused_fallback(reason, k, m, n)
         return None
-    import jax
-
-    interpret = jax.default_backend() != "tpu"
     a2r, a2i = ar.reshape(k, -1), ai.reshape(k, -1)
     b2r, b2i = br.reshape(k, -1), bi.reshape(k, -1)
-    try:
-        if step.swap:
-            re, im = fused_complex_dot_kl(
-                b2r, b2i, a2r, a2i, interpret=interpret, precision=precision
-            )
-        else:
-            re, im = fused_complex_dot_kl(
-                a2r, a2i, b2r, b2i, interpret=interpret, precision=precision
-            )
-    except Exception as e:  # trace-time only; see docstring
-        _note_fused_fallback("kernel_error", k, m, n, f"{type(e).__name__}: {e}")
-        return None
+    if step.swap:
+        re, im = fused_complex_dot_kl(
+            b2r, b2i, a2r, a2i, interpret=interpret, precision=precision
+        )
+    else:
+        re, im = fused_complex_dot_kl(
+            a2r, a2i, b2r, b2i, interpret=interpret, precision=precision
+        )
     return re.reshape(step.out_store), im.reshape(step.out_store)
 
 
@@ -543,8 +550,7 @@ def fused_transpose_runtime_ineligible_reason(apair, bpair, step) -> str | None:
     ONE predicate shared by the kernel route
     (:func:`_try_fused_transpose_step`) and the span accounting
     (``backends.run_steps_timed``), so what the spans credit and what
-    the kernel actually does can never diverge (``kernel_error`` stays
-    the documented blind spot)."""
+    the kernel actually does can never diverge."""
     ar, br = apair[0], bpair[0]
     if str(ar.dtype) != "float32" or str(br.dtype) != "float32":
         return "dtype"
@@ -555,15 +561,16 @@ def fused_transpose_runtime_ineligible_reason(apair, bpair, step) -> str | None:
     return None
 
 
-def _try_fused_transpose_step(apair, bpair, step, precision):
+def _try_fused_transpose_step(apair, bpair, step, precision, interpret=False):
     """Route one step through the fused transpose-dot Pallas kernel
     (:func:`tnc_tpu.ops.pallas_complex.fused_transpose_dot_kl`) when
     its layout allows; ``None`` means 'run the standard prep + naive
     dots'. Takes the RAW stored (real, imag) pairs — the whole point
     is that the macro transpose is applied in the kernel's index maps,
-    not materialized through HBM. Every fallback is counted
-    (``ops.fused_transpose_fallback{reason=...}``). Same trace-time
-    failure surface as :func:`_try_fused_step`."""
+    not materialized through HBM. Every routed-away step is counted
+    (``ops.fused_transpose_fallback{reason=...}``); like
+    :func:`_try_fused_step`, a kernel that cannot trace or compile
+    fails the run."""
     from tnc_tpu.ops.program import step_dims
 
     m, k, n = step_dims(step)
@@ -581,20 +588,11 @@ def _try_fused_transpose_step(apair, bpair, step, precision):
     a2 = (ar.reshape(step.a_view), ai.reshape(step.a_view))
     b2 = (br.reshape(step.b_view), bi.reshape(step.b_view))
     first, second = (b2, a2) if step.swap else (a2, b2)
-    import jax
-
-    interpret = jax.default_backend() != "tpu"
-    try:
-        re, im = fused_transpose_dot_kl(
-            first[0], first[1], second[0], second[1],
-            first_lay, second_lay,
-            interpret=interpret, precision=precision,
-        )
-    except Exception as e:  # trace-time only; see _try_fused_step
-        _note_fused_transpose_fallback(
-            "kernel_error", k, m, n, f"{type(e).__name__}: {e}"
-        )
-        return None
+    re, im = fused_transpose_dot_kl(
+        first[0], first[1], second[0], second[1],
+        first_lay, second_lay,
+        interpret=interpret, precision=precision,
+    )
     return re.reshape(step.out_store), im.reshape(step.out_store)
 
 
@@ -641,28 +639,6 @@ class KernelPolicy:
         return len(self.modes) - len(self.chained_steps()) + len(self.chains)
 
 
-def _chain_pays(cost_model, steps) -> bool:
-    """Is fusing this run of steps into one dispatch a predicted win?
-    Saves ``len(steps) - 1`` dispatch overheads; costs the naive-vs-
-    gauss flop difference (the chain kernel runs 4 dots where the
-    default ladder would run 3). With no fitted model the grouping
-    pass's own size bound (steps under the fused kernel's flop floor)
-    already selects dispatch-dominated steps — accept."""
-    if cost_model is None:
-        return True
-    from tnc_tpu.ops.program import step_flops
-
-    flops = sum(step_flops(st) for st in steps)
-    # complex k*m*n units → real-multiply units: naive 8x, gauss 6x,
-    # so fusing costs 2 extra units per k*m*n; each saved dispatch is
-    # worth its flop-equivalent under the fitted model
-    extra_flops = 2.0 * flops
-    saved_flops = (
-        len(steps) - 1
-    ) * cost_model.dispatch_equivalent_flops()
-    return saved_flops > extra_flops
-
-
 def _strassen_saving_s(cost_model, m: int, k: int, n: int) -> float:
     """Predicted seconds one Strassen level saves over gauss on an
     eligible step (negative = loses): the saved multiplies (0.75 →
@@ -707,27 +683,10 @@ def _fused_transpose_saving_s(cost_model, step) -> float:
         return float("-inf")  # no transpose pass to save
     # f32 split pairs: 8 bytes per complex element, the device width
     saved_s = prep * 8.0 / cost_model.bytes_per_s
-    # naive 8 vs gauss 6 real-multiply units per k*m*n (same convention
-    # as _chain_pays); the fitted flops_per_s is per k*m*n unit
+    # naive 8 vs gauss 6 real-multiply units per k*m*n; the fitted
+    # flops_per_s is per k*m*n unit
     extra_s = 2.0 * step_flops(step) / cost_model.flops_per_s
     return saved_s - extra_s
-
-
-def chain_flop_ceiling(cost_model) -> float:
-    """Chain-candidate step-size ceiling in the fused kernel's
-    ``2*k*m*n`` units, priced in calibrated seconds: a step is worth
-    chaining while its compute time is within ~one dispatch overhead
-    (:meth:`~tnc_tpu.obs.calibrate.CalibratedCostModel.
-    dispatch_equivalent_flops`), so the ceiling rises above the static
-    ``MIN_FLOPS`` small-step bucket exactly when the fitted overhead
-    says bigger steps are still dispatch-bound — PR 6's chain fusion
-    extended upward. Never *below* ``MIN_FLOPS``: the static bound is
-    the no-model floor."""
-    from tnc_tpu.ops.pallas_complex import MIN_FLOPS
-
-    if cost_model is None:
-        return float(MIN_FLOPS)
-    return max(float(MIN_FLOPS), 2.0 * cost_model.dispatch_equivalent_flops())
 
 
 def plan_precision_modes(
@@ -799,18 +758,15 @@ def plan_kernels(
     ``naive``/``gauss``/``fused``/``fused_transpose`` uniformly
     (the fused rungs fall back per step at trace time, counted);
     ``strassen`` promotes every step over the crossover (others run
-    gauss); ``chain`` fuses every groupable run (others run gauss).
+    gauss); ``chain`` fuses every groupable run (others run gauss) —
+    the only way to a chain: the unforced ladder plans none, because
+    the chain kernel has never compiled for a TPU.
     The per-step dot-precision rung is planned alongside
     (:func:`plan_precision_modes` — ``TNC_TPU_DOT_PRECISION`` forces
     it independently of the mode override). Unforced, the ladder is
     cost-model-driven (``cost_model``: a
     :class:`tnc_tpu.obs.calibrate.CalibratedCostModel` or None):
 
-    - runs of consecutive steps under the calibrated chain ceiling
-      (:func:`chain_flop_ceiling` — ``MIN_FLOPS`` statically, rising
-      with the fitted ``dispatch_overhead_s``) whose fusion saves more
-      dispatch overhead than the naive-vs-gauss flop difference costs
-      → one fused **chain** dispatch;
     - transpose-carrying steps the fused transpose-dot can stream
       where the deleted HBM transpose pass beats the extra naive dot
       (:func:`_fused_transpose_saving_s` — needs a fitted bandwidth
@@ -865,13 +821,18 @@ def plan_kernel_steps(
                 pmodes = ()
         return KernelPolicy(modes, (), pmodes)
 
-    if chain_max_flops is None and force != "chain":
-        chain_max_flops = chain_flop_ceiling(cost_model)
-    chains = chain_groups(steps, max_flops=chain_max_flops)
-    if force != "chain":  # auto: keep only the chains the model likes
-        chains = tuple(
-            (s, e) for s, e in chains if _chain_pays(cost_model, steps[s:e])
-        )
+    # The chain kernel has never compiled for a TPU: Mosaic refuses
+    # _chain_compute's in-kernel regroup of the carried value
+    # ("infer-vector-layout: unsupported shape cast", asked of the v5e
+    # compiler off-chip), and that refusal surfaces when the enclosing
+    # jit compiles, past any trace-time handling. So the unforced
+    # policy plans no chains, with or without a fitted model; only
+    # force="chain" does (the interpret-mode tests).
+    chains = (
+        chain_groups(steps, max_flops=chain_max_flops)
+        if force == "chain"
+        else ()
+    )
     chained = {i for s, e in chains for i in range(s, e)}
     modes = []
     for i, st in enumerate(steps):
@@ -1009,23 +970,20 @@ def kernel_plan_summary(
     }
 
 
-def _run_chain_split(steps, buffers, precision, precision_mode=""):
+def _run_chain_split(steps, buffers, precision, precision_mode="",
+                     interpret=False):
     """Execute a grouped run of steps as ONE fused Pallas dispatch.
 
     Non-carried operands are prepped to contract-dim-leading 2-D
     outside the kernel (XLA-land, where transposes are free to fuse);
     the carried value flows through the kernel in VMEM. Returns the
-    final (re, im) pair reshaped to the last step's ``out_store``.
-    Raises on any trace-time problem — the caller falls back to the
-    sequential naive loop (same arithmetic)."""
-    import jax
+    final (re, im) pair reshaped to the last step's ``out_store``."""
     import jax.numpy as jnp
 
     from tnc_tpu.ops.backends import _prep_operand
     from tnc_tpu.ops.pallas_complex import ChainLink, fused_chain_kl
 
     prec = _resolve_step_precision(precision, precision_mode)
-    interpret = jax.default_backend() != "tpu"
 
     def prep_kl(pair, view, perm, dot_shape, ops, cfirst):
         r = _prep_operand(jnp, pair[0], view, perm, dot_shape, ops)
@@ -1081,28 +1039,16 @@ def _run_chain_split(steps, buffers, precision, precision_mode=""):
     return re.reshape(out_store), im.reshape(out_store)
 
 
-def run_chain_split(xp, steps, buffers, precision=None, precision_mode=""):
+def run_chain_split(xp, steps, buffers, precision=None, precision_mode="",
+                    interpret=False):
     """Execute one chain group with full buffer bookkeeping — the
     fused dispatch on device, the sequential naive loop on the host
-    oracle (bit-identical arithmetic) or when the kernel can't trace
-    (counted as ``ops.fused_chain_fallback``). ``precision_mode`` is
-    the chain's dot-precision rung (one rung per chain — the policy's
+    oracle (bit-identical arithmetic). ``precision_mode`` is the
+    chain's dot-precision rung (one rung per chain — the policy's
     head-step entry). Mutates ``buffers`` the same way the sequential
-    loop would."""
-    from tnc_tpu import obs
-
-    out = None
-    if xp is not np:
-        try:
-            out = _run_chain_split(steps, buffers, precision, precision_mode)
-        except Exception as e:  # trace-time only — same contract as fused
-            obs.counter_add("ops.fused_chain_fallback")
-            logger.warning(
-                "fused chain kernel fell back to the sequential loop "
-                "(%d steps): %s: %s", len(steps), type(e).__name__, e,
-            )
-            out = None
-    if out is None:
+    loop would. A chain that was planned and cannot trace or compile
+    fails the run."""
+    if xp is np:
         for st in steps:
             buffers[st.lhs] = apply_step_split(
                 xp, buffers[st.lhs], buffers[st.rhs], st, precision,
@@ -1110,6 +1056,9 @@ def run_chain_split(xp, steps, buffers, precision=None, precision_mode=""):
             )
             buffers[st.rhs] = None
         return buffers[steps[-1].lhs]
+    out = _run_chain_split(
+        steps, buffers, precision, precision_mode, interpret
+    )
     for st in steps:
         buffers[st.rhs] = None
     buffers[steps[-1].lhs] = out
@@ -1122,12 +1071,14 @@ def run_steps_split(
     buffers: list[tuple[Any, Any] | None],
     precision=None,
     policy: KernelPolicy | None = None,
+    interpret: bool = False,
 ):
     """Split-complex analogue of ``backends._run_steps``; ``buffers`` are
     (real, imag) pairs and the result is a pair in **stored** shape
     (callers reshape to ``result_shape`` on the host). ``policy`` (a
     :class:`KernelPolicy`) promotes steps per the kernel ladder; None
-    runs every step under the env mode (``gauss`` default)."""
+    runs every step under the env mode (``gauss`` default).
+    ``interpret``: see :func:`apply_step_split`."""
     steps = program.steps
     chain_end = (
         {s: e for s, e in policy.chains} if policy is not None else {}
@@ -1139,6 +1090,7 @@ def run_steps_split(
             run_chain_split(
                 xp, steps[i:end], buffers, precision,
                 precision_mode=policy.precision_mode(i),
+                interpret=interpret,
             )
             i = end
             continue
@@ -1149,6 +1101,7 @@ def run_steps_split(
             precision_mode=(
                 policy.precision_mode(i) if policy is not None else None
             ),
+            interpret=interpret,
         )
         buffers[step.rhs] = None
         i += 1

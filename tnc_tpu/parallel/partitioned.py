@@ -416,8 +416,8 @@ def local_contract_partitions(
     once before its slice loop (:mod:`tnc_tpu.ops.hoist`).
 
     Sliced partitions run through the chunked executor by default (the
-    on-device ``fori_loop`` is ~150× slower on real TPUs,
-    TPU_EVIDENCE_r03.md); each partition's buffers are committed to its
+    on-device ``fori_loop`` was measured ~150× slower on a real TPU in
+    an earlier round); each partition's buffers are committed to its
     device, so the per-partition chunk dispatches execute there and the
     k local phases still overlap. ``sliced_strategy="loop"`` keeps the
     single-dispatch loop program (fewer host round-trips — the virtual
@@ -446,6 +446,9 @@ def local_contract_partitions(
     logger.debug("local phase: %d partition programs", len(comm.programs))
     from tnc_tpu.ops.chunked import run_sliced_chunked_placed
     from tnc_tpu.ops.sliced import SlicedProgram, make_jax_sliced_fn
+    from tnc_tpu.ops.split_complex import interpret_for
+
+    interpret = interpret_for(comm.devices[0])
 
     # mesh strategy: hand the spare devices (slots beyond the partition
     # count) to the sliced partitions, round-robin
@@ -518,6 +521,7 @@ def local_contract_partitions(
                         device=_dev,
                         max_slices=max_slices,
                         hoist=hoist,
+                        interpret=interpret,
                     )
 
                 return run
@@ -527,8 +531,11 @@ def local_contract_partitions(
                 precision=precision,
                 num_slices=max_slices,
                 hoist=hoist,
+                interpret=interpret,
             )
-        return jit_program(program, split_complex, precision)
+        return jit_program(
+            program, split_complex, precision, interpret=interpret
+        )
 
     def run_job(i, fn, bufs):
         # runs on the pool worker thread, so each partition's span lands
@@ -637,6 +644,9 @@ def intermediate_reduce(
     """
     import jax
 
+    from tnc_tpu.ops.split_complex import interpret_for
+
+    interpret = interpret_for(comm.devices[0])
     metas = list(comm.results_meta)
     held: list[Any] = list(results)
     if levels is None:
@@ -676,7 +686,10 @@ def intermediate_reduce(
                         # return immediately; the level's pairs overlap
                         # on their devices while the host loops on
                         moved = jax.device_put(held[y], target)
-                        fn = jit_program(programs[pi], split_complex, precision)
+                        fn = jit_program(
+                            programs[pi], split_complex, precision,
+                            interpret=interpret,
+                        )
                         out = fn([held[x], moved])
                     except Exception as exc:  # noqa: BLE001 — name the site
                         raise PartitionExecutionError(
@@ -759,6 +772,9 @@ def _process_sharded_contraction(
     local_devices = jax.local_devices()
     if split_complex is None:
         split_complex = local_devices[0].platform != "cpu"
+    from tnc_tpu.ops.split_complex import interpret_for
+
+    interpret = interpret_for(local_devices[0])
 
     children = list(tn.tensors)
     k = len(children)
@@ -913,7 +929,8 @@ def _process_sharded_contraction(
                     if ox == me:
                         try:
                             fn = jit_program(
-                                pair_programs[pi], split_complex, precision
+                                pair_programs[pi], split_complex, precision,
+                                interpret=interpret,
                             )
                             held[x] = fn([held.pop(x), moved])
                         except Exception as exc:  # noqa: BLE001
@@ -1225,7 +1242,11 @@ def partitioned_sliced_executor(
         build_sliced_program,
         index_buffer,
     )
-    from tnc_tpu.ops.split_complex import plan_kernels, run_steps_split
+    from tnc_tpu.ops.split_complex import (
+        interpret_for,
+        plan_kernels,
+        run_steps_split,
+    )
 
     if devices is None:
         devices = jax.devices()
@@ -1235,6 +1256,7 @@ def partitioned_sliced_executor(
             devices = devices[:n_devices]
     if split_complex is None:
         split_complex = devices[0].platform != "cpu"
+    interpret = interpret_for(devices[0])
 
     flat_leaves, flat_pairs = flatten_partitioned_path(tn, contract_path)
     if target_size is None:
@@ -1281,7 +1303,7 @@ def partitioned_sliced_executor(
                 ]
                 return run_steps_split(
                     jnp, sp.program, sliced, precision,
-                    policy=plan_kernels(sp.program),
+                    policy=plan_kernels(sp.program), interpret=interpret,
                 )
             sliced = [
                 index_buffer(jnp, arr, info, indices)
@@ -1350,7 +1372,7 @@ def partitioned_sliced_executor(
                     moved = jax.device_put(held[y], target)
                     pair_fn = jit_program(
                         pair_programs[pi], split_complex, precision,
-                        donate=False,
+                        donate=False, interpret=interpret,
                     )
                     level_bytes += _buffer_nbytes(held[y])
                     level_flops += pair_flops[pi]

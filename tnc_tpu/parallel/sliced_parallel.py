@@ -33,28 +33,6 @@ from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
 from tnc_tpu.tensornetwork.tensordata import TensorData
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Replication-unchecked shard_map across jax versions: top-level
-    ``jax.shard_map`` with ``check_vma`` on jax >= 0.8, the
-    ``jax.experimental.shard_map`` spelling with ``check_rep`` on the
-    0.4.x line (psum inside the body trips the strict checker either
-    way)."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
 def _effective_chunk(
     num_slices: int, n_devices: int, max_slices: int | None
 ) -> int:
@@ -100,8 +78,8 @@ def _make_spmd_fn(
 
     ``unroll > 1`` runs each device's chunk as ``lax.scan(unroll=)``
     over its slice ids instead of a ``fori_loop`` — on real TPUs XLA
-    pessimizes while-loop bodies ~150× (TPU_EVIDENCE_r03.md), and the
-    unrolled scan presents straight-line step groups.
+    pessimizes while-loop bodies (~150×, measured in an earlier round),
+    and the unrolled scan presents straight-line step groups.
 
     ``max_slices`` probe subsets: each device's chunk shrinks to
     ``ceil(max_slices / n_devices)`` and device ``d`` covers slice ids
@@ -137,6 +115,10 @@ def _make_spmd_fn(
             hp = cand
     loop_sp = hp.residual if hp is not None else sp
 
+    from tnc_tpu.ops.split_complex import interpret_for
+
+    # Pallas interpret mode follows the mesh's devices, not the process
+    interpret = interpret_for(mesh.devices.flat[0])
     dims = sp.slicing.dims
     part_dtype = "float64" if "128" in str(dtype) else "float32"
 
@@ -171,7 +153,8 @@ def _make_spmd_fn(
                 for (re, im), info in zip(loop_buffers, loop_sp.slot_slices)
             ]
             return run_steps_split(
-                jnp, loop_sp.program, buffers, precision, policy=loop_policy
+                jnp, loop_sp.program, buffers, precision, policy=loop_policy,
+                interpret=interpret,
             )
 
         def add(acc, contrib):
@@ -207,7 +190,8 @@ def _make_spmd_fn(
             from tnc_tpu.ops.hoist import run_prelude
 
             loop_buffers = run_prelude(
-                jnp, hp, list(full_buffers), split_complex, precision
+                jnp, hp, list(full_buffers), split_complex, precision,
+                interpret,
             )
         else:
             loop_buffers = full_buffers
@@ -228,8 +212,11 @@ def _make_spmd_fn(
         return lax.psum(partial, axis)
 
     in_specs = tuple(P() for _ in range(sp.program.num_inputs))  # replicated
-    fn = _shard_map(
-        device_fn, mesh=mesh, in_specs=in_specs, out_specs=P()
+    # check_vma off: the psum inside the body trips the strict
+    # replication checker
+    fn = jax.shard_map(
+        device_fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
+        check_vma=False,
     )
     return jax.jit(fn)
 

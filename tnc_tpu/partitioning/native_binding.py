@@ -13,6 +13,7 @@ Set ``TNC_TPU_NO_NATIVE=1`` to force the Python path.
 from __future__ import annotations
 
 import ctypes
+import logging
 import math
 import os
 import subprocess
@@ -31,6 +32,8 @@ _SOURCES = [
 _SRC = _SOURCES[0]  # kept for back-compat with external callers
 _LIB_PATH = _NATIVE_DIR / "_partitioner.so"
 
+logger = logging.getLogger(__name__)
+
 _lib: ctypes.CDLL | None = None
 _load_failed = False
 
@@ -42,10 +45,12 @@ def _build_library() -> bool:
     # written .so
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=str(_NATIVE_DIR))
     os.close(fd)
+    # no -march=native: the library may be built on one host and loaded
+    # on another (a copied checkout), where a host-specific instruction
+    # is an uncatchable SIGILL
     cmd = [
         compiler,
         "-O3",
-        "-march=native",
         "-std=c++17",
         "-shared",
         "-fPIC",
@@ -55,10 +60,6 @@ def _build_library() -> bool:
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=240)
-        if proc.returncode != 0:
-            # retry without -march=native (unsupported on some toolchains)
-            cmd.remove("-march=native")
-            proc = subprocess.run(cmd, capture_output=True, timeout=240)
         if proc.returncode != 0:
             print(
                 f"tnc_tpu: native partitioner build failed:\n"
@@ -95,6 +96,12 @@ def load_native() -> ctypes.CDLL | None:
             stale = not _LIB_PATH.exists()
         if stale and not _build_library():
             _load_failed = True
+            # once per process (_load_failed short-circuits later calls)
+            logger.warning(
+                "native planner library could not be built (compiler %r); "
+                "the pure-Python partitioner and planners take over",
+                os.environ.get("CXX", "g++"),
+            )
             return None
         lib = ctypes.CDLL(str(_LIB_PATH))
         lib.tnc_partition_kway.restype = ctypes.c_int
