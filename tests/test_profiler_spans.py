@@ -367,10 +367,13 @@ def test_service_stats_total_the_dispatch_boundaries(path_trace):
     for key in phases:
         assert row[key] > 0, key
     assert sum(row[key] for key in phases) <= row["total_s"] * 1.001
-    # 2 riders x 5 bras of (2,) complex128 + the gate leaves, as
-    # (real, imag) float64 pairs: every leaf is placed on every dispatch
+    # the bytes copied, not the bytes looked at: a leaf already resident
+    # behind place_buffers is a hit and counts nothing
     (place,) = trace.named("backend.place_buffers")
     assert row["h2d_bytes"] == place[4]["bytes"] > 0
+    assert row["leaves_placed"] == place[4]["placed"] >= 5  # the bras
+    assert row["leaf_hits"] == place[4]["hits"]
+    assert place[4]["placed"] + place[4]["hits"] == place[4]["n"]
 
 
 def _lowered(program_kind: str):

@@ -152,12 +152,15 @@ class TestRebind:
         backend = JaxBackend(dtype="complex128", donate=False)
         got = bp.amplitudes(bits, backend)
         assert np.allclose(got, want, atol=1e-12)
-        # the gate leaves were staged to the device once and are reused
+        # the gate leaves were placed on the device once and are reused
         # (only the bras transfer per dispatch)
-        resident = bp._resident[(str(backend.dtype), backend.device)]
-        again = bp.amplitudes(bits, backend)
+        with obs.collect_phases() as phases:
+            again = bp.amplitudes(bits, backend)
         assert np.allclose(again, want, atol=1e-12)
-        assert bp._resident[(str(backend.dtype), backend.device)] is resident
+        assert phases["backend.place_buffers.placed"] == len(bp.bra_slots)
+        assert phases["backend.place_buffers.hits"] == (
+            len(bp.arrays) - len(bp.bra_slots)
+        )
 
     def test_empty_batched_slots_is_explicit_error(self):
         bp = bind_circuit(make_circuit(seed=0))

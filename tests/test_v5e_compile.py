@@ -170,6 +170,35 @@ def test_default_policy_compiles_for_chain_bearing_program(
     fn.jitted.lower(_pair_specs(shapes, one_chip)).compile()
 
 
+def test_batched_program_donates_its_stacked_slots_only(
+    one_chip, chain_bearing
+):
+    """The served batch's executable as ``JaxBackend.execute_batched``
+    compiles it (donating): ``(stacked, shared)`` arguments, of which
+    only the stacked bras are donated, so the shared gate leaves can
+    stay resident behind ``place_buffers``."""
+    from tnc_tpu.ops.backends import jit_program
+
+    program, shapes = chain_bearing
+    # the 24 kets and 24 bras: every vector leaf carries the batch axis
+    bras = frozenset(i for i, s in enumerate(shapes) if tuple(s) == (2,))
+    assert len(bras) == 48 < len(shapes)
+    fn = jit_program(
+        program, True, "float32", donate=True, batched=bras,
+        interpret=False,
+    )
+    stacked = _pair_specs([(32, 2)] * len(bras), one_chip)
+    shared = _pair_specs(
+        [s for i, s in enumerate(shapes) if i not in bras], one_chip
+    )
+    lowered = fn.jitted.lower(stacked, shared)
+    donated = jax.tree.leaves(lowered.args_info[0])
+    assert all(a.donated for a in donated[: 2 * len(bras)])
+    assert not any(a.donated for a in donated[2 * len(bras):])
+    assert "jit_tnc_program_batched" in lowered.as_text()
+    lowered.compile()
+
+
 def test_chain_kernel_is_still_refused(
     one_chip, chain_bearing
 ):

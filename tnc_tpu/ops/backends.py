@@ -10,6 +10,8 @@ contractor is a runtime-pluggable backend:
   HBM for intermediates and the peak matches the analytic
   ``contract_size_tensors`` prediction. Matmuls land on the MXU; default
   dtype is complex64 (TPU has no native f64; parity target is 1e-5).
+- :func:`place_buffers` — the one host-to-device placement rule, behind
+  which :class:`ResidentLeaves` keeps unchanged leaves on the device.
 
 Compiled executables are cached by program signature + dtype, so repeated
 contractions of equal-shaped networks (e.g. amplitude sweeps) recompile
@@ -18,12 +20,13 @@ nothing.
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import threading
 import warnings
 from collections import OrderedDict
-from typing import Any, Sequence
+from typing import Any, Iterable, Sequence
 
 import numpy as np
 
@@ -398,6 +401,14 @@ def jit_program(
     whole path is ``jax.vmap``-ed over them (amplitude sweeps,
     :meth:`JaxBackend.execute_batched`).
 
+    **Donation rule** (a buffer of :data:`RESIDENT_LEAVES` is never
+    donated): ``donate=True`` donates every input of an unbatched
+    program, so its caller places them all ``transient``
+    (:func:`place_buffers`); of a batched program it donates the
+    ``batched`` slots only, which are transient by nature, and the
+    shared slots (gate leaves, which could back no intermediate anyway)
+    stay resident across batches.
+
     ``policy``: a :class:`tnc_tpu.ops.split_complex.KernelPolicy` —
     the per-step kernel promotion ladder the trace bakes in (split
     mode only). Part of the cache key: two policies over the same
@@ -460,6 +471,23 @@ def jit_program(
                 for slot in range(program.num_inputs)
             ]
             run = jax.vmap(run, in_axes=(axes,))
+        split_args = batched is not None and donate
+        if split_args:
+            # (stacked, shared) so that only the stacked slots donate
+            stacked_slots = sorted(batched)
+            shared_slots = [
+                s for s in range(program.num_inputs) if s not in batched
+            ]
+            run_merged = run
+
+            def run(stacked, shared):
+                buffers = [None] * program.num_inputs
+                for slot, buf in zip(stacked_slots, stacked):
+                    buffers[slot] = buf
+                for slot, buf in zip(shared_slots, shared):
+                    buffers[slot] = buf
+                return run_merged(buffers)
+
         jitted = named_jit(
             run,
             "tnc_program" if batched is None else "tnc_program_batched",
@@ -475,7 +503,13 @@ def jit_program(
             # ladders. The no-failure path costs one extra frame.
             def _dispatch():
                 _faults.fault_point("backend.dispatch")
-                out = _jitted(buffers)
+                if split_args:
+                    out = _jitted(
+                        [buffers[s] for s in stacked_slots],
+                        [buffers[s] for s in shared_slots],
+                    )
+                else:
+                    out = _jitted(buffers)
                 if _retry.sync_dispatch():
                     # surface async device failures inside this guarded
                     # region instead of at the next use of the result
@@ -522,40 +556,161 @@ def jit_program(
     return fn
 
 
+class ResidentLeaves:
+    """Content-addressed LRU of leaf buffers already on a device:
+    ``(host shape, host dtype, host bytes, placed dtype, split flag,
+    placement target)`` → the buffer (or (real, imag) pair) that content
+    was placed as. Keyed by content, never identity: numpy arrays are
+    mutable and ``TensorData.into_data()`` builds a fresh array per
+    call, so an in-place edit must miss and a rebuilt equal gate must
+    hit. Equal leaves of one call share one buffer, which is why a
+    stored buffer must never reach a donating executable (see
+    :func:`place_buffers`). Host dict operations under a lock only:
+    nothing here touches the device or compiles."""
+
+    # leaves above this bypass the store: hashing them costs what the
+    # copy does, and one of them would evict every gate tensor
+    MAX_LEAF_BYTES = 1 << 20
+    # all stored buffers together: 0.4 % of a v5e's 16 GB of HBM
+    MAX_TOTAL_BYTES = 64 << 20
+    # host bytes up to this are the key themselves; above, their digest
+    _INLINE_BYTES = 256
+
+    def __init__(
+        self,
+        max_total_bytes: int = MAX_TOTAL_BYTES,
+        max_leaf_bytes: int = MAX_LEAF_BYTES,
+    ):
+        self.max_total_bytes = max_total_bytes
+        self.max_leaf_bytes = max_leaf_bytes
+        self._lock = threading.Lock()
+        self._entries: "OrderedDict[tuple, tuple[Any, int]]" = OrderedDict()
+        self.total_bytes = 0
+
+    def key(self, array: np.ndarray, placed_dtype: str, split: bool, target):
+        """The store key of a host leaf, or None when it bypasses the
+        store (over the leaf limit)."""
+        if array.nbytes > self.max_leaf_bytes:
+            return None
+        if array.nbytes <= self._INLINE_BYTES:
+            content = array.tobytes()
+        else:
+            content = hashlib.blake2b(
+                np.ascontiguousarray(array), digest_size=16
+            ).digest()
+        return (
+            array.shape, array.dtype.str, content, placed_dtype, split, target
+        )
+
+    def get(self, key):
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                return None
+            self._entries.move_to_end(key)
+            return entry[0]
+
+    def put(self, key, buffer, nbytes: int):
+        """Store ``buffer`` under ``key`` and return the buffer to use:
+        the one already there when another thread placed the same
+        content first. Evicts least-recently-used entries down to the
+        bound (dropping the store's reference only: a caller still
+        holding an evicted buffer keeps it alive)."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is not None:
+                self._entries.move_to_end(key)
+                return entry[0]
+            self._entries[key] = (buffer, nbytes)
+            self.total_bytes += nbytes
+            while self.total_bytes > self.max_total_bytes:
+                _, (_, evicted) = self._entries.popitem(last=False)
+                self.total_bytes -= evicted
+            return buffer
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._entries)
+
+
+RESIDENT_LEAVES = ResidentLeaves()
+
+
 def place_buffers(
     arrays: Sequence[Any],
     dtype,
     split_complex: bool,
     device=None,
+    transient: Iterable[int] = (),
 ) -> list[Any]:
     """Host arrays → device buffers: complex arrays as-is, or (real, imag)
-    float pairs in split mode. Shared by :class:`JaxBackend` and the
-    distributed executors (the placement rule must not diverge)."""
+    float pairs in split mode. The one placement rule of
+    :class:`JaxBackend`, the chunked executor and the distributed
+    executors.
+
+    Each leaf is looked up in :data:`RESIDENT_LEAVES` by content first
+    and copied host-to-device only on a miss, so a served batch, a
+    sliced call or an SPMD call places its unchanged gate tensors once
+    per process, not once per call. ``device`` is the placement target
+    and part of the key: a ``Device``, ``None`` (uncommitted, default
+    device) or a ``Sharding`` (the SPMD entry's replicated
+    ``NamedSharding``).
+
+    ``transient``: slots that get a buffer of their own, neither looked
+    up nor stored — the request-dependent slots of a batch, and **every
+    slot of a call whose executable donates its inputs**: a stored
+    buffer is shared (by equal leaves, by later calls), so it must
+    never be donated. Leaves over ``ResidentLeaves.MAX_LEAF_BYTES`` and
+    arrays already on a device are transient too.
+
+    The ``backend.place_buffers`` phase carries ``n`` (slots),
+    ``placed`` (leaves copied), ``hits`` (leaves found resident) and
+    ``bytes`` (bytes copied: resident leaves count nothing)."""
     import jax
-    import jax.numpy as jnp
+
+    store = RESIDENT_LEAVES
+    placed_dtype = (
+        ("float64" if "128" in str(dtype) else "float32")
+        if split_complex
+        else str(np.dtype(dtype))
+    )
+    if split_complex:
+        from tnc_tpu.ops.split_complex import split_array
+
+        def host(a):
+            return split_array(a, placed_dtype)
+
+    else:
+
+        def host(a):
+            if isinstance(a, jax.Array) and a.dtype == placed_dtype:
+                return a  # already on a device: moved, not fetched
+            return np.asarray(a, dtype=placed_dtype)
 
     with obs.phase("backend.place_buffers", n=len(arrays)) as osp:
+        transient = frozenset(transient)
+        out: list[Any] = [None] * len(arrays)
+        pending = []  # (slot, store key or None, host parts) to copy
+        for slot, a in enumerate(arrays):
+            key = None
+            if slot not in transient and not isinstance(a, jax.Array):
+                a = np.asarray(a)
+                key = store.key(a, placed_dtype, split_complex, device)
+                if key is not None:
+                    out[slot] = store.get(key)
+            if out[slot] is None:
+                pending.append((slot, key, host(a)))
+        # one transfer call for everything that has to move
+        buffers = jax.device_put([parts for _, _, parts in pending], device)
         nbytes = 0
-        out = []
-        if split_complex:
-            from tnc_tpu.ops.split_complex import split_array
-
-            part_dtype = "float64" if "128" in str(dtype) else "float32"
-            for a in arrays:
-                re, im = split_array(a, part_dtype)
-                nbytes += re.nbytes + im.nbytes
-                out.append(
-                    (
-                        jax.device_put(jnp.asarray(re), device),
-                        jax.device_put(jnp.asarray(im), device),
-                    )
-                )
-        else:
-            for a in arrays:
-                buf = jax.device_put(jnp.asarray(a, dtype=dtype), device)
-                nbytes += buf.nbytes
-                out.append(buf)
-        osp.add(bytes=nbytes)
+        for (slot, key, parts), buf in zip(pending, buffers):
+            size = sum(p.nbytes for p in parts) if split_complex else parts.nbytes
+            nbytes += size
+            out[slot] = buf if key is None else store.put(key, buf, size)
+        hits = len(arrays) - len(pending)
+        osp.add(placed=len(pending), hits=hits, bytes=nbytes)
+        obs.counter_add("resident_leaves.hit", hits)
+        obs.counter_add("resident_leaves.miss", len(pending))
         return out
 
 
@@ -794,14 +949,22 @@ class JaxBackend(Backend):
                 return combine_array(*result).reshape(shape)
             return np.asarray(result).reshape(shape)
 
-    def _device_buffers(self, arrays: Sequence[Any]) -> list[Any]:
-        return place_buffers(arrays, self.dtype, self.split_complex, self.device)
+    def _device_buffers(
+        self, arrays: Sequence[Any], transient: Iterable[int] = ()
+    ) -> list[Any]:
+        return place_buffers(
+            arrays, self.dtype, self.split_complex, self.device, transient
+        )
 
     def execute(self, program: ContractionProgram, arrays: Sequence[Any]) -> np.ndarray:
-        buffers = self._device_buffers(arrays)
-        return self._fetch(self._run(program, buffers), program.result_shape)
+        return self._fetch(self._run(program, arrays), program.result_shape)
 
-    def _run(self, program: ContractionProgram, buffers: list[Any]):
+    def _run(self, program: ContractionProgram, arrays: Sequence[Any]):
+        # the executable donates all its inputs when self.donate: none
+        # may be a resident leaf then (jit_program's donation rule)
+        buffers = self._device_buffers(
+            arrays, transient=range(len(arrays)) if self.donate else ()
+        )
         if obs.enabled() and obs.step_timing_enabled():
             # TNC_TPU_STEP_TIME: eager op-by-op execution, blocking on
             # each step's result — every step span carries a true
@@ -982,7 +1145,7 @@ class JaxBackend(Backend):
         (:mod:`tnc_tpu.tensornetwork.sweep`). Returns ``(B,) +
         result_shape``."""
         fn = self._compiled(program, batched=frozenset(batched))
-        buffers = self._device_buffers(arrays)
+        buffers = self._device_buffers(arrays, transient=batched)
         with obs.phase("backend.execute"):
             result = fn(buffers)
         return self._fetch(result, (-1,) + tuple(program.result_shape))
@@ -995,7 +1158,7 @@ class JaxBackend(Backend):
         ``program.result_legs`` order, not ``result_shape``/canonical
         order — reshape/permute host-side when leg semantics matter.
         """
-        return self._run(program, self._device_buffers(arrays))
+        return self._run(program, arrays)
 
     def bind_resident(self, program: ContractionProgram, arrays: Sequence[Any]):
         """Stage ``arrays`` to the device once and return a zero-transfer
@@ -1004,11 +1167,11 @@ class JaxBackend(Backend):
         (stored shape; a (real, imag) pair in split mode).
 
         Donation is disabled for the bound executable so the resident
-        inputs survive arbitrarily many calls — this is the steady-state
-        evaluation shape (gate tensors live in HBM, only the dispatch
-        recurs), the analogue of the reference's timed contraction region
-        which starts after data placement
-        (``benchmark/src/main.rs:355-405``).
+        inputs survive arbitrarily many calls. Since
+        :func:`place_buffers` keeps unchanged leaves resident for every
+        caller, what this still adds is the closure: no content look-up
+        and no executable look-up per call, for timing a bare dispatch.
+        Only ``bench.py`` needs that (ROADMAP C1).
         """
         fn = self._compiled(program, donate=False)
         buffers = self._device_buffers(arrays)

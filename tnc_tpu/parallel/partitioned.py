@@ -232,10 +232,16 @@ def _pair_program(ta: LeafTensor, tb: LeafTensor) -> tuple[ContractionProgram, L
     return program, result
 
 
-def _leaf_arrays(child: CompositeTensor) -> list[np.ndarray]:
+def _place_partition(child: CompositeTensor, dtype, split_complex: bool, device):
+    """One partition's leaves on its device, as buffers of their own
+    (all ``transient``): unsliced partition programs donate their inputs
+    (``jit_program``'s default), and a resident leaf is never donated."""
     from tnc_tpu.ops.program import flat_leaf_tensors
 
-    return [np.asarray(leaf.data.into_data()) for leaf in flat_leaf_tensors(child)]
+    arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(child)]
+    return place_buffers(
+        arrays, dtype, split_complex, device, transient=range(len(arrays))
+    )
 
 
 def _slice_partition(child: CompositeTensor, nested: ContractionPath, hbm_bytes: int):
@@ -371,8 +377,8 @@ def scatter_partitions(
                     )
                 )
                 buffers.append(
-                    place_buffers(
-                        _leaf_arrays(child), dtype, split_complex,
+                    _place_partition(
+                        child, dtype, split_complex,
                         devices[mapping.device(i)],
                     )
                 )
@@ -816,8 +822,8 @@ def _process_sharded_contraction(
         buffers = {}
         for i in mine:
             try:
-                buffers[i] = place_buffers(
-                    _leaf_arrays(children[i]), dtype, split_complex,
+                buffers[i] = _place_partition(
+                    children[i], dtype, split_complex,
                     local_devices[dev_slot[i]],
                 )
             except Exception as exc:  # noqa: BLE001 — name the site
@@ -1285,8 +1291,8 @@ def partitioned_sliced_executor(
         for sp in sps
     ]
     buffers = [
-        place_buffers(
-            _leaf_arrays(child), dtype, split_complex, devices[mapping.device(i)]
+        _place_partition(
+            child, dtype, split_complex, devices[mapping.device(i)]
         )
         for i, child in enumerate(children)
     ]

@@ -24,7 +24,7 @@ logger = logging.getLogger(__name__)
 from tnc_tpu import obs
 from tnc_tpu.contractionpath.contraction_path import ContractionPath
 from tnc_tpu.contractionpath.slicing import Slicing
-from tnc_tpu.ops.backends import _run_steps, named_jit
+from tnc_tpu.ops.backends import _run_steps, named_jit, place_buffers
 from tnc_tpu.resilience import faultinject as _faults
 from tnc_tpu.resilience import retry as _retry
 from tnc_tpu.ops.program import flat_leaf_tensors
@@ -310,7 +310,7 @@ def distributed_sliced_contraction(
     True
     """
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
 
     if mesh is None:
         mesh = make_mesh(n_devices, axis)
@@ -353,22 +353,12 @@ def distributed_sliced_contraction(
                     hp = cand
             osp.set(hoisted=hp is not None)
         with obs.span("spmd.place", n=len(leaves)):
-            if split_complex:
-                from tnc_tpu.ops.split_complex import (
-                    combine_array,
-                    split_array,
-                )
-
-                part_dtype = "float64" if "128" in str(dtype) else "float32"
-                arrays = []
-                for leaf in leaves:
-                    re, im = split_array(leaf.data.into_data(), part_dtype)
-                    arrays.append((jnp.asarray(re), jnp.asarray(im)))
-            else:
-                arrays = [
-                    jnp.asarray(leaf.data.into_data(), dtype=dtype)
-                    for leaf in leaves
-                ]
+            # committed and replicated over the mesh once per content:
+            # shard_map's in_specs=P() then finds every leaf in place
+            arrays = place_buffers(
+                [leaf.data.into_data() for leaf in leaves], dtype,
+                split_complex, NamedSharding(mesh, PartitionSpec()),
+            )
 
         def _dispatch():
             _faults.fault_point("spmd.dispatch")
@@ -385,6 +375,8 @@ def distributed_sliced_contraction(
             out = _retry.retry_call(_dispatch, label="spmd.dispatch")
         with obs.span("spmd.fetch"):
             if split_complex:
+                from tnc_tpu.ops.split_complex import combine_array
+
                 result = combine_array(*out)
             else:
                 result = np.asarray(out)

@@ -143,10 +143,6 @@ class BoundProgram:
     # materialized per backend environment from the content-addressed
     # store (see tnc_tpu.serve.reuse)
     reuse: Any = None  # ReuseBinding | None
-    # device-resident bitstring-invariant leaves, keyed by
-    # (dtype, device): staged once, reused by every threaded-jax
-    # dispatch — only the (B, n_det, 2) bras transfer per batch
-    _resident: dict = field(default_factory=dict, repr=False, compare=False)
 
     @property
     def result_shape(self) -> tuple[int, ...]:
@@ -297,33 +293,13 @@ class BoundProgram:
                         )
                 with obs.phase("backend.lookup"):
                     fn = _jit_threaded(self.program, self.batch_flags)
-                # gate leaves are bitstring-invariant: stage them to the
-                # device ONCE and reuse across dispatches (the jitted fn
-                # never donates); only the bras transfer per batch
-                res_key = (str(backend.dtype), backend.device)
-                resident = self._resident.get(res_key)
-                if resident is None:
-                    bra_set = set(self.bra_slots)
-                    resident = {
-                        s: buf
-                        for s, buf in enumerate(
-                            place_buffers(
-                                arrays, backend.dtype, False,
-                                backend.device,
-                            )
-                        )
-                        if s not in bra_set
-                    }
-                    self._resident[res_key] = resident
-                bra_dev = place_buffers(
-                    [buffers[s] for s in self.bra_slots],
-                    backend.dtype, False, backend.device,
+                # gate leaves are bitstring-invariant and stay resident
+                # behind place_buffers (the jitted fn never donates);
+                # only the bras transfer per batch
+                dev = place_buffers(
+                    buffers, backend.dtype, False, backend.device,
+                    transient=self.bra_slots,
                 )
-                bra_of = dict(zip(self.bra_slots, bra_dev))
-                dev = [
-                    bra_of[s] if s in bra_of else resident[s]
-                    for s in range(len(buffers))
-                ]
                 with obs.phase("backend.execute"):
                     res = fn(dev)
                 with obs.phase("backend.fetch"):

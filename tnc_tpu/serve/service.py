@@ -1678,6 +1678,10 @@ class ContractionService:
         "execute_s": "backend.execute",
         "fetch_s": "backend.fetch",
         "h2d_bytes": "backend.place_buffers.bytes",
+        # leaves copied host-to-device / found resident on the device:
+        # the hit share of place_buffers' store is hits over their sum
+        "leaves_placed": "backend.place_buffers.placed",
+        "leaf_hits": "backend.place_buffers.hits",
     }
 
     @classmethod
