@@ -61,15 +61,21 @@ def test_disabled_span_is_shared_noop(disabled_obs):
 
 def test_disabled_span_overhead(disabled_obs):
     """Disabled-path call cost — no profiler session, ``TNC_TPU_TRACE``
-    unset — against an absolute bound (under 1 µs a span: one bool
-    check and one ``TraceMe.is_enabled()``) and a no-op context-manager
-    baseline: the acceptance bound for leaving instrumentation in
-    production paths. Best-of-5 minima damp scheduler noise; the ratio
-    bound is generous (CI boxes are loaded) but catches any accidental
-    allocation or registry touch on the disabled path."""
+    unset: one bool check and one ``TraceMe.is_enabled()``, then the
+    shared no-op span. The acceptance bound for leaving instrumentation
+    in production paths, as a ratio against a no-op context manager
+    timed in the SAME loop (baseline and span back to back, round after
+    round, the best round's ratio taken): whatever else loads the
+    machine — five other xdist workers in the driver's run — slows both
+    sides of a round alike, where an absolute bound in microseconds read
+    the load. The ratio catches any accidental allocation or registry
+    touch on the disabled path; that it allocates nothing is also
+    asserted outright."""
     import jax  # noqa: F401 — loaded: the profiler sink is reachable
 
     assert not obs.profiler_recording()
+    # the disabled path hands out the one shared no-op span
+    assert obs.span("stage", steps=3) is obs.core.NULL_SPAN
 
     class Null:
         def __enter__(self):
@@ -81,30 +87,27 @@ def test_disabled_span_overhead(disabled_obs):
     null = Null()
     n = 20_000
 
-    def timed(fn):
-        best = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            fn()
-            best = min(best, time.perf_counter() - t0)
-        return best
-
     def run_baseline():
+        t0 = time.perf_counter()
         for _ in range(n):
             with null:
                 pass
+        return time.perf_counter() - t0
 
     def run_disabled():
+        t0 = time.perf_counter()
         for _ in range(n):
             with obs.span("stage", steps=3):
                 pass
+        return time.perf_counter() - t0
 
-    base = timed(run_baseline)
-    disabled = timed(run_disabled)
-    per_call = disabled / n
-    assert per_call < 1e-6, f"disabled span costs {per_call*1e9:.0f} ns/call"
-    assert disabled < max(base, 1e-9) * 25, (
-        f"disabled span {disabled:.4f}s vs no-op baseline {base:.4f}s"
+    rounds = [(run_baseline(), run_disabled()) for _ in range(9)]
+    ratio, base, disabled = min(
+        (d / max(b, 1e-9), b, d) for b, d in rounds
+    )
+    assert ratio < 10, (
+        f"disabled span {disabled:.4f}s vs no-op baseline {base:.4f}s "
+        f"in the same round: {ratio:.1f}x"
     )
 
 

@@ -339,9 +339,10 @@ def test_spmd_contraction_twice_places_nothing_again(split):
 
 
 @SPLIT
-def test_partitioned_leaves_stay_out_of_the_store(split, store):
-    """Partition programs donate their inputs: their leaves are placed
-    transient, twice the same answer and nothing stored."""
+def test_partitioned_leaves_are_placed_once(split, store):
+    """No partition program donates its inputs (PR 28): the leaves go
+    through the store, a second call copies nothing host to device and
+    gives the same answer, bit for bit."""
     from tnc_tpu.contractionpath.contraction_path import ContractionPath
     from tnc_tpu.parallel.partitioned import (
         distributed_partitioned_contraction,
@@ -366,10 +367,10 @@ def test_partitioned_leaves_stay_out_of_the_store(split, store):
 
     first, counts = placing(call)
     second, again = placing(call)
-    assert counts["hits"] == again["hits"] == 0
-    assert counts["placed"] == again["placed"] == len(ts)
+    assert counts["hits"] == 0 and counts["placed"] == len(ts)
+    assert again["hits"] == len(ts) and again["placed"] == 0
     np.testing.assert_array_equal(first, second)
-    assert len(store) == 0
+    assert 0 < len(store) <= len(ts)
 
 
 # -- what the service counts -------------------------------------------------

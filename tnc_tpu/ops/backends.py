@@ -390,6 +390,7 @@ def jit_program(
     batched: frozenset[int] | None = None,
     policy=None,
     interpret: bool = False,
+    role: str | None = None,
 ):
     """Program → jitted ``fn(buffers)`` with donated inputs; one traced
     function per (program, mode), one XLA executable per input placement.
@@ -414,7 +415,12 @@ def jit_program(
     mode only). Part of the cache key: two policies over the same
     program are different executables. ``interpret``: Pallas interpret
     mode for the policy's kernels, from the target device
-    (:func:`tnc_tpu.ops.split_complex.interpret_for`)."""
+    (:func:`tnc_tpu.ops.split_complex.interpret_for`).
+
+    ``role``: what the caller runs the program as; the module is then
+    ``jit_tnc_<role>`` (``partition_local``, ``fanin_pair``) and not
+    ``jit_tnc_program``, so a trace tells a distributed call's phases
+    apart. Part of the cache key: a name is baked into the executable."""
     import jax
 
     from tnc_tpu.ops.split_complex import complex_mult_key, dot_precision_key
@@ -436,6 +442,7 @@ def jit_program(
         batched,
         policy.signature() if policy is not None else None,
         interpret,
+        role,
     )
     with _PROGRAM_JIT_CACHE_LOCK:
         fn = _PROGRAM_JIT_CACHE.get(key)
@@ -488,9 +495,10 @@ def jit_program(
                     buffers[slot] = buf
                 return run_merged(buffers)
 
+        module = "tnc_program" if batched is None else "tnc_program_batched"
         jitted = named_jit(
             run,
-            "tnc_program" if batched is None else "tnc_program_batched",
+            module if role is None else f"tnc_{role}",
             donate_argnums=(0,) if donate else (),
         )
         n_steps = len(program.steps)
@@ -699,7 +707,12 @@ def place_buffers(
                 if key is not None:
                     out[slot] = store.get(key)
             if out[slot] is None:
-                pending.append((slot, key, host(a)))
+                parts = host(a)
+                if key is not None and parts is a:
+                    # the CPU backend's device_put may alias host memory:
+                    # a stored buffer must not follow the caller's edits
+                    parts = parts.copy()
+                pending.append((slot, key, parts))
         # one transfer call for everything that has to move
         buffers = jax.device_put([parts for _, _, parts in pending], device)
         nbytes = 0

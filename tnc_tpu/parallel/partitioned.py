@@ -51,6 +51,7 @@ from tnc_tpu.ops.program import (
     ContractionProgram,
     _pair_step,
     build_program,
+    step_flops,
 )
 from tnc_tpu.resilience import faultinject as _faults
 from tnc_tpu.resilience import retry as _retry
@@ -233,20 +234,53 @@ def _pair_program(ta: LeafTensor, tb: LeafTensor) -> tuple[ContractionProgram, L
 
 
 def _place_partition(child: CompositeTensor, dtype, split_complex: bool, device):
-    """One partition's leaves on its device, as buffers of their own
-    (all ``transient``): unsliced partition programs donate their inputs
-    (``jit_program``'s default), and a resident leaf is never donated."""
+    """One partition's leaves on its device, through the store of
+    resident leaves (:func:`~tnc_tpu.ops.backends.place_buffers`): a
+    call that finds them there copies nothing host to device. No local
+    program donates its inputs (gate leaves could back no intermediate
+    anyway), so a stored buffer is never consumed."""
     from tnc_tpu.ops.program import flat_leaf_tensors
 
     arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(child)]
-    return place_buffers(
-        arrays, dtype, split_complex, device, transient=range(len(arrays))
-    )
+    return place_buffers(arrays, dtype, split_complex, device)
 
 
-def _slice_partition(child: CompositeTensor, nested: ContractionPath, hbm_bytes: int):
+def _partition_program(
+    child: CompositeTensor, nested: ContractionPath, hbm_bytes: int
+) -> tuple[Any, ContractionProgram]:
+    """One partition's local path as ``(entry, program)``: ``entry`` is
+    what the local phase runs — the partition's
+    :class:`ContractionProgram`, or a
+    :class:`~tnc_tpu.ops.sliced.SlicedProgram` where that does not fit
+    ``hbm_bytes`` — and ``program`` the contraction program either way
+    (the fan-in's metadata comes from its result)."""
+    program = build_program(child, nested)
+    sp = _slice_partition(child, nested, program, hbm_bytes)
+    if sp is not None:
+        return sp, sp.program
+    return program, program
+
+
+def _entry_cmacs(entry: Any) -> float:
+    """Predicted complex multiply-adds of one partition's local phase
+    (all slices of a sliced partition)."""
+    from tnc_tpu.ops.sliced import SlicedProgram
+
+    if isinstance(entry, SlicedProgram):
+        return entry.slicing.num_slices * sum(
+            step_flops(st) for st in entry.program.steps
+        )
+    return sum(step_flops(st) for st in entry.steps)
+
+
+def _slice_partition(
+    child: CompositeTensor,
+    nested: ContractionPath,
+    program: ContractionProgram,
+    hbm_bytes: int,
+):
     """Slice one partition's local path until its program fits the HBM
-    budget. Returns a SlicedProgram (or None if the unsliced program
+    budget. Returns a SlicedProgram (or None if the unsliced ``program``
     already fits, or nothing local slicing can do).
 
     Uses slice-and-reconfigure (slicing interleaved with subtree
@@ -263,7 +297,6 @@ def _slice_partition(child: CompositeTensor, nested: ContractionPath, hbm_bytes:
     from tnc_tpu.ops.budget import fits_hbm, program_peak_bytes
     from tnc_tpu.ops.sliced import build_sliced_program
 
-    program = build_program(child, nested)
     if fits_hbm(program, hbm_bytes=hbm_bytes):
         return None
     if nested.nested:
@@ -337,10 +370,11 @@ def scatter_partitions(
     """Compile per-partition programs and place each partition's leaves on
     its device (``scatter_tensor_network``, ``communication.rs:125-195``).
 
-    With ``hbm_bytes`` set, any partition whose program exceeds the
-    per-device budget is sliced locally (sum over slice programs on its
-    own device) before the fan-in — composing partition parallelism with
-    slicing.
+    Any partition whose program exceeds the per-device budget
+    ``hbm_bytes`` (default: what the first device reports,
+    :func:`~tnc_tpu.ops.budget.device_hbm_bytes`) is sliced locally
+    (sum over slice programs on its own device) before the fan-in —
+    composing partition parallelism with slicing.
     """
     children = list(tn.tensors)
     k = len(children)
@@ -353,24 +387,21 @@ def scatter_partitions(
         raise ValueError(f"{k} partitions but only {len(devices)} devices")
 
     mapping = DeviceTensorMapping.for_path(k, contract_path.toplevel)
+    if hbm_bytes is None:
+        from tnc_tpu.ops.budget import device_hbm_bytes
+
+        hbm_bytes = device_hbm_bytes(devices[0])
 
     programs: list[Any] = []
     metas: list[LeafTensor] = []
     buffers: list[list[Any]] = []
-    with obs.span("partitioned.scatter", partitions=k):
+    with obs.phase("partitioned.scatter", partitions=k):
         for i, child in enumerate(children):
             try:
-                sp = None
-                if hbm_bytes is not None:
-                    sp = _slice_partition(
-                        child, contract_path.nested[i], hbm_bytes
-                    )
-                if sp is not None:
-                    programs.append(sp)
-                    program = sp.program
-                else:
-                    program = build_program(child, contract_path.nested[i])
-                    programs.append(program)
+                entry, program = _partition_program(
+                    child, contract_path.nested[i], hbm_bytes
+                )
+                programs.append(entry)
                 metas.append(
                     LeafTensor(
                         list(program.result_legs), list(program.result_shape)
@@ -395,7 +426,7 @@ def scatter_partitions(
                 mapping.device(i),
                 len(child),
                 len(program.steps),
-                ", sliced" if sp is not None else "",
+                ", sliced" if entry is not program else "",
             )
 
     comm = Communication(mapping, list(devices), programs, metas)
@@ -539,8 +570,10 @@ def local_contract_partitions(
                 hoist=hoist,
                 interpret=interpret,
             )
+        # its inputs are resident leaves (_place_partition): not donated
         return jit_program(
-            program, split_complex, precision, interpret=interpret
+            program, split_complex, precision, donate=False,
+            interpret=interpret, role="partition_local",
         )
 
     def run_job(i, fn, bufs):
@@ -561,13 +594,10 @@ def local_contract_partitions(
                 return fn(bufs)
 
             try:
-                # unsliced partition programs dispatch with donated
-                # inputs (jit_program default), so the donation guard
-                # blocks retries once a failed dispatch consumed them
+                # no local program donates its inputs, so a retry finds
+                # them as the failed dispatch left them
                 return _retry.default_policy().run(
-                    _attempt,
-                    label="partition.local",
-                    classify=_retry.donation_guarded_classify(bufs),
+                    _attempt, label="partition.local"
                 )
             except Exception as exc:  # noqa: BLE001 — annotate and re-raise
                 raise PartitionExecutionError(i, dev, exc) from exc
@@ -576,7 +606,14 @@ def local_contract_partitions(
         (i, compile_one(i, program), list(bufs))
         for i, (program, bufs) in enumerate(zip(comm.programs, buffers))
     ]
-    with obs.span("partitioned.local", partitions=len(jobs)):
+    cmacs = [_entry_cmacs(program) for program in comm.programs]
+    with obs.phase("partitioned.local", partitions=len(jobs)) as local_sp:
+        # the partitions' predicted work, always on: how far the slowest
+        # chip is from the mean says what the partitioner's balance costs
+        local_sp.add(
+            cmacs_max=max(cmacs, default=0.0),
+            cmacs_mean=sum(cmacs) / max(len(cmacs), 1),
+        )
         if len(jobs) > 1:
             from concurrent.futures import ThreadPoolExecutor
 
@@ -606,8 +643,6 @@ def plan_fanin_pairs(
     final_meta)``. Hoisting this out of the reduce loop keeps the
     per-level hot path free of planning work — a level's dispatches go
     back-to-back with no host-side program construction between them."""
-    from tnc_tpu.ops.program import step_flops
-
     pair_meta = list(metas)
     programs: list[ContractionProgram] = []
     moved: list[LeafTensor] = []
@@ -667,14 +702,15 @@ def intermediate_reduce(
         metas, flat
     )
     proc = _process_index()
-    with obs.span(
-        "partitioned.fanin", pairs=len(flat), levels=len(levels)
-    ) as fanin_sp:
+    with obs.phase("partitioned.fanin") as fanin_sp:
+        # counts, not attributes: a caller's collect_phases() reads them
+        # with nothing tracing
+        fanin_sp.add(pairs=len(flat), levels=len(levels))
         total_bytes = 0.0
         total_flops = 0.0
         pi = 0
         for li, level in enumerate(levels):
-            with obs.span(
+            with obs.phase(
                 "partitioned.fanin_level", level=li, pairs=len(level)
             ) as level_sp:
                 level_bytes = 0.0
@@ -694,7 +730,7 @@ def intermediate_reduce(
                         moved = jax.device_put(held[y], target)
                         fn = jit_program(
                             programs[pi], split_complex, precision,
-                            interpret=interpret,
+                            interpret=interpret, role="fanin_pair",
                         )
                         out = fn([held[x], moved])
                     except Exception as exc:  # noqa: BLE001 — name the site
@@ -706,12 +742,10 @@ def intermediate_reduce(
                     held[x] = out
                     held[y] = None
                     pi += 1
-                if obs.enabled():
-                    level_sp.add(bytes=level_bytes, flops=level_flops)
+                level_sp.add(bytes=level_bytes, flops=level_flops)
                 total_bytes += level_bytes
                 total_flops += level_flops
-        if obs.enabled():
-            fanin_sp.add(bytes=total_bytes, flops=total_flops)
+        fanin_sp.add(bytes=total_bytes, flops=total_flops)
     root = _fanin_survivor(len(held), flat) if flat else 0
     return held[root], final_meta if flat else comm.results_meta[root]
 
@@ -781,6 +815,10 @@ def _process_sharded_contraction(
     from tnc_tpu.ops.split_complex import interpret_for
 
     interpret = interpret_for(local_devices[0])
+    if hbm_bytes is None:
+        from tnc_tpu.ops.budget import device_hbm_bytes
+
+        hbm_bytes = device_hbm_bytes(local_devices[0])
 
     children = list(tn.tensors)
     k = len(children)
@@ -801,15 +839,10 @@ def _process_sharded_contraction(
         "partitioned.scatter", partitions=len(mine), process=me
     ):
         for i, child in enumerate(children):
-            sp = None
-            if hbm_bytes is not None:
-                sp = _slice_partition(child, contract_path.nested[i], hbm_bytes)
-            if sp is not None:
-                programs.append(sp)
-                program = sp.program
-            else:
-                program = build_program(child, contract_path.nested[i])
-                programs.append(program)
+            entry, program = _partition_program(
+                child, contract_path.nested[i], hbm_bytes
+            )
+            programs.append(entry)
             metas.append(
                 LeafTensor(
                     list(program.result_legs), list(program.result_shape)
@@ -936,7 +969,7 @@ def _process_sharded_contraction(
                         try:
                             fn = jit_program(
                                 pair_programs[pi], split_complex, precision,
-                                interpret=interpret,
+                                interpret=interpret, role="fanin_pair",
                             )
                             held[x] = fn([held.pop(x), moved])
                         except Exception as exc:  # noqa: BLE001
@@ -946,14 +979,10 @@ def _process_sharded_contraction(
                             ) from exc
                         level_flops += pair_flops[pi]
                     pi += 1
-                if obs.enabled():
-                    level_sp.add(bytes=level_bytes, flops=level_flops)
+                level_sp.add(bytes=level_bytes, flops=level_flops)
                 total_bytes += level_bytes
                 total_flops += level_flops
-        if obs.enabled():
-            fanin_sp.add(
-                bytes=total_bytes, flops=total_flops, cross_pairs=cross
-            )
+        fanin_sp.add(bytes=total_bytes, flops=total_flops, cross_pairs=cross)
 
     root_part = _fanin_survivor(k, flat) if flat else 0
     if not flat:
@@ -999,8 +1028,10 @@ def distributed_partitioned_contraction(
     children = partitions) and ``contract_path`` must carry a nested path
     per partition plus the toplevel communication schedule — the same
     contract as the reference's distributed pipeline (§3.2 of SURVEY.md).
-    ``hbm_bytes`` sets a per-device budget; partitions that exceed it are
-    locally sliced (partitioning × slicing composition).
+    ``hbm_bytes`` is the per-device budget (default: what the first
+    device reports, :func:`~tnc_tpu.ops.budget.device_hbm_bytes`);
+    partitions that exceed it are locally sliced (partitioning × slicing
+    composition).
     ``local_sliced_strategy``/``slice_batch``/``chunk_steps`` select the
     executor for those locally sliced partitions ('chunked' — the fast
     path on real TPUs — or 'loop', one dispatch per partition, fine on
@@ -1075,12 +1106,16 @@ def distributed_partitioned_contraction(
         comm, contract_path.toplevel, results, split_complex, precision
     )
 
-    if split_complex:
-        from tnc_tpu.ops.split_complex import combine_array
+    # dispatch is asynchronous: here the host waits for the local phase,
+    # the chip-to-chip moves and the pair contractions to end
+    with obs.phase("partitioned.fetch") as fetch_sp:
+        if split_complex:
+            from tnc_tpu.ops.split_complex import combine_array
 
-        data = combine_array(*final)
-    else:
-        data = np.asarray(final)
+            data = combine_array(*final)
+        else:
+            data = np.asarray(final)
+        fetch_sp.add(bytes=float(data.nbytes))
     # device buffers live in stored (merged) shape; restore leg granularity
     data = data.reshape(tuple(meta.bond_dims))
     return LeafTensor(list(meta.legs), list(meta.bond_dims), TensorData.matrix(data))
@@ -1379,6 +1414,7 @@ def partitioned_sliced_executor(
                     pair_fn = jit_program(
                         pair_programs[pi], split_complex, precision,
                         donate=False, interpret=interpret,
+                        role="fanin_pair",
                     )
                     level_bytes += _buffer_nbytes(held[y])
                     level_flops += pair_flops[pi]
