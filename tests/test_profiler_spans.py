@@ -358,6 +358,15 @@ def test_path_runs_programs_named_by_role(path_trace):
     assert not closures & trace.modules, sorted(closures & trace.modules)
 
 
+@pytest.mark.parametrize("path_trace", ["chunked"], indirect=True)
+def test_residual_span_says_how_the_rows_ran(path_trace):
+    _, trace, _ = path_trace
+    (residual,) = trace.named("sliced.residual")
+    assert residual[4]["rows"] == "loop"
+    assert residual[4]["batch"] == 4
+    assert residual[4]["chunks"] >= 2
+
+
 @pytest.mark.parametrize("path_trace", ["serve"], indirect=True)
 def test_service_stats_total_the_dispatch_boundaries(path_trace):
     _, trace, out = path_trace
@@ -422,7 +431,7 @@ def _lowered(program_kind: str):
     if program_kind == "tnc_prelude":
         return prelude.lower(pins)
     assert program_kind == "tnc_residual_c00"
-    chunks, chunk_fns = _compiled_plan(hp.residual, 4, 2, True, "float32")
+    chunks, chunk_fns, _ = _compiled_plan(hp.residual, 4, 2, True, "float32")
     assert len(chunks) > 1  # the first is not the accumulating last one
     cached = iter(jax.eval_shape(prelude, pins))
     inputs = [

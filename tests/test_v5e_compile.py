@@ -236,15 +236,17 @@ def test_northstar_chunk_compiles_within_hbm(
 ):
     """The first chunk of the chunked sliced executor — the one that
     holds the budget model's peak step — at its real shapes and slice
-    batch, against 16 GB."""
+    batch, against 16 GB. Its rows run one after another: the compiled
+    program holds no ``dot_general`` with a batch dimension."""
     from tnc_tpu.ops.budget import program_peak_bytes
     from tnc_tpu.ops.chunked import _compiled_plan, _prelude_fn
 
     sp, hp, shapes, batch = northstar
     residual = hp.residual
-    chunks, chunk_fns = _compiled_plan(
+    chunks, chunk_fns, row_modes = _compiled_plan(
         residual, batch, 64, True, "float32", interpret=False
     )
+    assert row_modes[0] == "loop"
     assert program_peak_bytes(residual.program).peak_step < len(
         chunks[0].steps
     )
@@ -266,7 +268,9 @@ def test_northstar_chunk_compiles_within_hbm(
     idx = jax.ShapeDtypeStruct(
         (batch, len(sp.slicing.dims)), jnp.int32, sharding=one_chip
     )
-    compiled = chunk_fns[0].lower(ins, idx).compile()
+    lowered = chunk_fns[0].lower(ins, idx)
+    assert "batching_dims = [0]" not in lowered.as_text()
+    compiled = lowered.compile()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
 
 

@@ -868,14 +868,14 @@ class JaxBackend(Backend):
         hoist: bool = True,
     ):
         """``sliced_strategy``: 'chunked' (default) splits the program
-        into slice-batched chunks (K small compiles, batched matmuls,
-        HBM-budget-clamped batch — see :mod:`tnc_tpu.ops.chunked`);
-        'loop' compiles the whole slice loop into one on-device
-        ``fori_loop`` program. Measured on the v5e (north-star program):
-        the straight-line chunked code runs the same steps ~150× faster
-        than the while-loop body — XLA pessimizes loop bodies — so
-        'loop' is only worth it when dispatch latency dominates (very
-        small per-slice programs).
+        into chunks and dispatches ``slice_batch`` slices at a time (K
+        small compiles; the host loop carries checkpoints, retries and
+        slice ranges — see :mod:`tnc_tpu.ops.chunked`); 'loop' compiles
+        the whole slice loop into one on-device ``fori_loop`` program.
+        Both run a slice's steps on unbatched operands inside an
+        on-device loop: on the v5e the loop body takes 28 ms a
+        Sycamore-53 slice where the chunks' former ``vmap`` over the
+        batch took 38.5 (``PERF.md`` §6, PR 25 and PR 29).
 
         ``hoist`` (default True): execute the slice-invariant stem once
         per call and loop only the residual program (see
@@ -1037,10 +1037,10 @@ class JaxBackend(Backend):
                     "slice_range and max_slices are exclusive"
                 )
             if self.sliced_strategy == "chunked" and sp.slicing.num_slices > 1:
-                # keep the fast path: on real TPUs the chunked executor
-                # is the tuned strategy (~150x per slice vs the loop
-                # program, docs/running_on_tpu.md) — a range shard must
-                # not silently demote every serving host to the loop
+                # a range shard runs under the backend's own strategy:
+                # the chunked executor takes any range with the programs
+                # it has, where the loop strategies compile a program
+                # per range (PERF.md §6, PR 29: the same pace a slice)
                 from tnc_tpu.ops.chunked import execute_sliced_batched_jax
 
                 return execute_sliced_batched_jax(

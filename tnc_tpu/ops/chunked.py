@@ -1,23 +1,32 @@
-"""Chunked + slice-batched execution of sliced contraction programs.
+"""Chunked execution of sliced contraction programs, a batch of slices
+a dispatch.
 
 The whole-path-in-one-``fori_loop`` executor (:mod:`tnc_tpu.ops.sliced`)
-compiles one XLA program containing every step; on very large networks
-(Sycamore-53 class) the TPU compiler struggles with a 250-step body. This
-module trades one big compile for K small ones:
+compiles one XLA program containing every step and runs every slice in
+one dispatch. This module keeps the host in the loop, for what the host
+is needed for — checkpoints, retries, the batch-halving rung on a
+resource error, slice ranges:
 
 - the program is **split into chunks** of at most ``chunk_steps`` steps,
   each compiled separately (compile cost scales with the chunk, not the
   whole program);
-- slices are processed in **batches of B** via ``jax.vmap`` over each
-  chunk: every matmul gains a leading batch axis, so narrow per-slice
-  matmuls become batched matmuls that keep the MXU busy, and host
-  dispatch overhead is divided by B;
-- batch results are summed on device and accumulated across batches.
+- slices are dispatched in **batches of B**: a chunk program is given
+  B rows of slice indices and runs them **one after another** in a
+  ``lax.scan``, every step on unbatched operands — the stored shapes and
+  macro-transposes :mod:`tnc_tpu.ops.program` planned, the step sequence
+  the slice loops' bodies run. B is the granularity of dispatches,
+  checkpoints and retries; it does not multiply a step's live memory;
+- the rows' results are summed on device and accumulated across batches.
 
-Memory: a batch keeps B copies of each live intermediate, so B must be
-chosen such that B x (peak live bytes of a chunk boundary) fits in HBM —
-slicing deeper (smaller per-slice peak) and batching wider is the
-TPU-friendly operating point.
+Until PR 29 the rows ran under ``jax.vmap``, so that "narrow per-slice
+matmuls become batched matmuls". On the v5e the steps are bound by
+memory, not by the MXU (0.2 % of its peak), and the batch axis cost a
+relayout of the whole batch's result wherever one operand was a hoisted
+constant: 38.5 ms a slice against the loop body's 28 (``PERF.md`` §6,
+PR 25 and PR 29).
+
+Memory: one slice's intermediates are live inside a chunk; a chunk
+boundary stacks B rows of the slots alive across it.
 
 Per-step contraction kernels are shared with the other executors
 (``backends.apply_step`` / ``split_complex.apply_step_split``); compiled
@@ -113,24 +122,25 @@ def split_program(
     return chunks
 
 
-def _run_chunk(xp, chunk: ProgramChunk, state: dict[int, Any]) -> None:
-    for step in chunk.steps:
+def _apply_steps(
+    xp, steps: Sequence[PairStep], state: dict[int, Any]
+) -> None:
+    for step in steps:
         state[step.lhs] = apply_step(xp, state[step.lhs], state[step.rhs], step)
         del state[step.rhs]
 
 
-def _run_chunk_split(
-    xp, chunk: ProgramChunk, state: dict[int, Any], precision, policy=None,
-    interpret: bool = False,
+def _apply_steps_split(
+    xp, steps: Sequence[PairStep], state: dict[int, Any], precision,
+    policy=None, interpret: bool = False,
 ) -> None:
-    """``policy``: a per-chunk :class:`~tnc_tpu.ops.split_complex.
-    KernelPolicy` (spans indexed relative to the chunk) — small
+    """``policy``: a :class:`~tnc_tpu.ops.split_complex.KernelPolicy`
+    planned over ``steps`` (spans indexed relative to them) — small
     consecutive residual steps fuse into single Pallas chain dispatches
     and eligible steps promote; ``None`` runs every step under the env
     mode."""
     from tnc_tpu.ops.split_complex import apply_step_split, run_chain_split
 
-    steps = chunk.steps
     chain_end = {s: e for s, e in policy.chains} if policy is not None else {}
     i = 0
     while i < len(steps):
@@ -160,7 +170,7 @@ def _run_chunk_split(
         i += 1
 
 
-# compiled plan cache: key -> (chunks, chunk_fns).
+# compiled plan cache: key -> (chunks, chunk_fns, row_modes).
 # Locked: the distributed local phase runs one chunked runner per
 # partition from a thread pool, so lookups/evictions race otherwise.
 _PLAN_CACHE: "OrderedDict[tuple, tuple]" = OrderedDict()
@@ -237,14 +247,19 @@ def _compiled_plan(
     precision: str | None,
     interpret: bool = False,
 ):
-    """``(chunks, chunk_fns)`` for one sliced program: the program split
-    into chunks and one jitted, slice-batched function per chunk (the
-    last folds the batch sum into the Kahan accumulator). Built from
-    the program alone — no array is placed — so the chunk functions can
-    also be lowered on ``jax.ShapeDtypeStruct``s for a described
-    device. Cached by everything a trace bakes in."""
+    """``(chunks, chunk_fns, row_modes)`` for one sliced program: the
+    program split into chunks and one jitted function per chunk, which
+    runs the rows of the ``idx`` it is given one after another in a
+    ``lax.scan`` (the last folds the rows' sum into the Kahan
+    accumulator). ``row_modes[ci]`` says how chunk ``ci`` runs them:
+    ``"loop"``, or ``"once"`` for a chunk no sliced leg reaches. No
+    slice bound is static: one set of programs serves every range.
+    Built from the program alone — no array is placed — so the chunk
+    functions can also be lowered on ``jax.ShapeDtypeStruct``s for a
+    described device. Cached by everything a trace bakes in."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     from tnc_tpu.ops.backends import lanemix_env
     from tnc_tpu.ops.split_complex import complex_mult_key, dot_precision_key
@@ -272,154 +287,170 @@ def _compiled_plan(
     chunks = split_program(sp.program, chunk_steps)
     num_inputs = sp.program.num_inputs
 
-    # kernel promotion ladder per chunk (split mode): chain spans and
-    # per-step modes planned over each chunk's step subsequence — a
-    # chain cannot cross a chunk boundary (the boundary is a dispatch
-    # anyway). Cached with the plan; the cache key carries
-    # complex_mult_key so forced/auto plans never collide.
-    if split_complex:
+    def planned(steps):
+        """``steps`` with their kernel promotion ladder (split mode):
+        chain spans and per-step modes planned over this subsequence — a
+        chain cannot cross a chunk boundary (the boundary is a dispatch
+        anyway). Cached with the plan; the cache key carries
+        complex_mult_key so forced/auto plans never collide."""
+        steps = tuple(steps)
+        if not split_complex or not steps:
+            return steps, None
         from tnc_tpu.ops.split_complex import plan_kernel_steps
 
-        chunk_policies = [plan_kernel_steps(c.steps) for c in chunks]
-    else:
-        chunk_policies = [None] * len(chunks)
+        return steps, plan_kernel_steps(steps)
 
-    # which slots carry a batch axis (sliced leaves + anything computed
-    # from a batched slot)
-    batched: set[int] = {
-        slot for slot, info in enumerate(sp.slot_slices) if info
-    }
-    batched_after_chunk: list[set[int]] = []
-    current = set(batched)
-    for chunk in chunks:
-        for step in chunk.steps:
-            if step.lhs in current or step.rhs in current:
-                current.add(step.lhs)
-        batched_after_chunk.append(set(current))
+    def run_steps(planned_steps, state):
+        """Steps on unbatched operands: the stored shapes and
+        macro-transposes ``ops/program.py`` planned, nothing added."""
+        steps, policy = planned_steps
+        if split_complex:
+            _apply_steps_split(
+                jnp, steps, state, precision, policy, interpret
+            )
+        else:
+            _apply_steps(jnp, steps, state)
 
-    # Dispatch-count discipline (host calls dominate the steady state on
-    # fast backends): each sliced leaf is gathered
-    # INSIDE its consuming chunk's jit (full buffer unbatched + the idx
-    # rows vmapped), and the last chunk folds the batch-sum/accumulate.
-    # One dispatch per chunk per batch — no separate gather or reduce.
     result_shape = sp.program.stored_result_shape
     result_slot = sp.program.result_slot
+
+    def chunk_program(chunk, leaf_in, row_in, once, rows, row_out, last):
+        """The traced function of one chunk. ``once``: the steps no
+        sliced leg reaches, run once a dispatch on the whole slots
+        (only an unhoisted program has them). ``rows``: the steps of
+        one slice, run for each ``idx`` row in turn — the step sequence
+        the SPMD loop body runs. ``leaf_in`` slots enter as full sliced
+        leaves and are indexed per row, ``row_in`` slots enter stacked
+        (an earlier chunk's ``row_out``), every other slot is whole and
+        closed over by the loop."""
+        looped = bool(rows)
+        once, rows = planned(once), planned(rows)
+
+        def enter(ins):
+            whole = dict(zip(chunk.in_slots, ins))
+            run_steps(once, whole)
+            return whole
+
+        def one_row(whole, idx1, row_vals):
+            state = dict(whole)
+            state.update(zip(row_in, row_vals))
+            for slot in leaf_in:  # an array, or its (real, imag) pair
+                state[slot] = jax.tree.map(
+                    lambda part, _info=sp.slot_slices[slot]: index_buffer(
+                        jnp, part, _info, idx1
+                    ),
+                    state[slot],
+                )
+            run_steps(rows, state)
+            return state
+
+        def scan_rows(body, init, whole, idx):
+            xs = (idx, tuple(whole[slot] for slot in row_in))
+            return lax.scan(
+                lambda carry, x: body(carry, one_row(whole, *x)), init, xs
+            )
+
+        def chunk_fn(ins, idx):
+            whole = enter(ins)
+            if looped:
+                # per-slice outputs stacked a row at a time, for the
+                # next chunk's rows
+                _, ys = scan_rows(
+                    lambda _, state: (None, tuple(state[s] for s in row_out)),
+                    None, whole, idx,
+                )
+                whole.update(zip(row_out, ys))
+            return tuple(whole[s] for s in chunk.out_slots)
+
+        def last_fn(ins, idx, acc):
+            # the only slot alive after the final chunk is the result:
+            # the rows' sum and the compensated accumulate fold into the
+            # same dispatch. The accumulator is a Kahan (sum, comp) pair
+            # per part: thousands of batch contributions cancel to far
+            # below the individual terms, where plain f32 accumulation
+            # loses the 1e-5 parity target.
+            def stored(out):
+                return jax.tree.map(lambda x: x.reshape(result_shape), out)
+
+            whole = enter(ins)
+            if looped:
+                # rows added one by one, in row order
+                zero = jax.tree.map(
+                    jnp.zeros_like,
+                    (acc[0][0], acc[1][0]) if split_complex else acc[0],
+                )
+                total, _ = scan_rows(
+                    lambda total, state: (
+                        jax.tree.map(
+                            jnp.add, total, stored(state[result_slot])
+                        ),
+                        None,
+                    ),
+                    zero, whole, idx,
+                )
+            else:  # slice-independent result: b identical terms
+                b = idx.shape[0]
+                total = jax.tree.map(
+                    lambda x: x * b, stored(whole[result_slot])
+                )
+            if split_complex:
+                (sr, cr), (si, ci_) = acc
+                sr, cr = kahan_add(sr, cr, total[0])
+                si, ci_ = kahan_add(si, ci_, total[1])
+                return ((sr, cr), (si, ci_))
+            return kahan_add(acc[0], acc[1], total)
+
+        return last_fn if last else chunk_fn
+
+    # which slots hold a per-slice value: the sliced leaves and whatever
+    # is computed from one
+    per_slice: set[int] = {
+        slot for slot, info in enumerate(sp.slot_slices) if info
+    }
     last_ci = len(chunks) - 1
     chunk_fns = []
+    row_modes = []
     written_before: set[int] = set()
     for ci, chunk in enumerate(chunks):
-        pre_batched = batched if ci == 0 else batched_after_chunk[ci - 1]
         # a sliced-leaf slot read here for the first time enters as the
-        # FULL buffer and is sliced per-batch-row inside the vmap; a slot
-        # id below num_inputs that an earlier chunk already wrote holds
-        # an intermediate (slots are reused as result holders)
-        leaf_in = {
+        # FULL buffer; a slot id below num_inputs that an earlier chunk
+        # already wrote holds an intermediate (slots are reused as
+        # result holders)
+        leaf_in = tuple(
             slot
             for slot in chunk.in_slots
             if slot < num_inputs
             and sp.slot_slices[slot]
             and slot not in written_before
-        }
+        )
         written_before.update(step.lhs for step in chunk.steps)
-        in_axes_spec = []
-        for slot in chunk.in_slots:
-            if slot in leaf_in:
-                ax = None
+        row_in = tuple(
+            slot
+            for slot in chunk.in_slots
+            if slot in per_slice and slot not in leaf_in
+        )
+        once, rows = [], []
+        for step in chunk.steps:
+            if step.lhs in per_slice or step.rhs in per_slice:
+                per_slice.add(step.lhs)
+                rows.append(step)
             else:
-                ax = 0 if slot in pre_batched else None
-            in_axes_spec.append((ax, ax) if split_complex else ax)
-        post_batched = batched_after_chunk[ci]
-        out_axes_spec = []
-        for slot in chunk.out_slots:
-            ax = 0 if slot in post_batched else None
-            out_axes_spec.append((ax, ax) if split_complex else ax)
-
-        def single(
-            ins, idx1, _chunk=chunk, _leaf_in=leaf_in,
-            _policy=chunk_policies[ci],
-        ):
-            state = {}
-            for slot, val in zip(_chunk.in_slots, ins):
-                if slot in _leaf_in:
-                    info = sp.slot_slices[slot]
-                    if split_complex:
-                        state[slot] = (
-                            index_buffer(jnp, val[0], info, idx1),
-                            index_buffer(jnp, val[1], info, idx1),
-                        )
-                    else:
-                        state[slot] = index_buffer(jnp, val, info, idx1)
-                else:
-                    state[slot] = val
-            if split_complex:
-                _run_chunk_split(
-                    jnp, _chunk, state, precision, _policy, interpret
-                )
-            else:
-                _run_chunk(jnp, _chunk, state)
-            return tuple(state[s] for s in _chunk.out_slots)
-
-        def _has_axis(spec):
-            return any(
-                (s is not None)
-                if not isinstance(s, tuple)
-                else any(x is not None for x in s)
-                for s in spec
+                once.append(step)
+        row_out = tuple(s for s in chunk.out_slots if s in per_slice)
+        fn = chunk_program(
+            chunk, leaf_in, row_in, once, rows, row_out, ci == last_ci
+        )
+        chunk_fns.append(
+            named_jit(
+                fn,
+                "tnc_residual_last"
+                if ci == last_ci
+                else f"tnc_residual_c{ci:02d}",
             )
+        )
+        row_modes.append("loop" if rows else "once")
 
-        is_batched_chunk = bool(leaf_in) or _has_axis(in_axes_spec)
-        if is_batched_chunk:
-            vmapped = jax.vmap(
-                single,
-                in_axes=(tuple(in_axes_spec), 0),
-                out_axes=tuple(out_axes_spec),
-            )
-        else:
-            # chunk touches no sliced data: identical for every slice,
-            # run it unbatched (its outputs are unbatched too)
-            def vmapped(ins, idx, _single=single):
-                return _single(ins, None)
-
-        if ci == last_ci:
-            # the only slot alive after the final chunk is the result:
-            # fold the batch-sum + compensated accumulate into the same
-            # dispatch. The accumulator is a Kahan (sum, comp) pair per
-            # part: thousands of batch contributions cancel to far below
-            # the individual terms, where plain f32 accumulation loses
-            # the 1e-5 parity target.
-            out_pos = chunk.out_slots.index(result_slot)
-            res_batched = (
-                result_slot in batched_after_chunk[ci] and is_batched_chunk
-            )
-
-            def last_fn(
-                ins, idx, acc, _vmapped=vmapped, _pos=out_pos, _rb=res_batched
-            ):
-                out = _vmapped(ins, idx)[_pos]
-                b = idx.shape[0]
-                if split_complex:
-                    if _rb:
-                        re = jnp.sum(out[0], axis=0)
-                        im = jnp.sum(out[1], axis=0)
-                    else:  # slice-independent result: b identical terms
-                        re, im = out[0] * b, out[1] * b
-                    (sr, cr), (si, ci_) = acc
-                    sr, cr = kahan_add(sr, cr, re.reshape(result_shape))
-                    si, ci_ = kahan_add(si, ci_, im.reshape(result_shape))
-                    return ((sr, cr), (si, ci_))
-                s = jnp.sum(out, axis=0) if _rb else out * b
-                return kahan_add(acc[0], acc[1], s.reshape(result_shape))
-
-            fn = named_jit(last_fn, "tnc_residual_last")
-        else:
-
-            def chunk_fn(ins, idx, _v=vmapped):
-                return _v(ins, idx)
-
-            fn = named_jit(chunk_fn, f"tnc_residual_c{ci:02d}")
-        chunk_fns.append(fn)
-
-    plan = (chunks, chunk_fns)
+    plan = (chunks, chunk_fns, tuple(row_modes))
     with _PLAN_CACHE_LOCK:
         _PLAN_CACHE[key] = plan
         while len(_PLAN_CACHE) > _PLAN_CACHE_MAX:
@@ -652,7 +683,7 @@ def run_sliced_chunked_placed(
             start0 = max(0, min(start0, num))
 
     with obs.phase("backend.lookup"):
-        chunks, chunk_fns = _compiled_plan(
+        chunks, chunk_fns, row_modes = _compiled_plan(
             sp, batch, chunk_steps, split_complex, precision, interpret
         )
 
@@ -713,7 +744,7 @@ def run_sliced_chunked_placed(
     sync = _retry.sync_dispatch()
     with obs.span(
         "sliced.residual", executor="chunked", batch=batch,
-        chunks=len(chunks),
+        chunks=len(chunks), rows="loop" if "loop" in row_modes else "once",
     ) as osp:
         start = start0
         dispatches = 0
@@ -721,9 +752,9 @@ def run_sliced_chunked_placed(
             b = min(batch, num - start)
             idx = place(all_indices[start : start + b])
 
-            # leaf in_slots receive the FULL buffers; each chunk's jit does
-            # its own per-row gather and the last one folds the reduction —
-            # exactly one dispatch per chunk per batch
+            # leaf in_slots receive the FULL buffers; each chunk's jit
+            # indexes them row by row and the last one folds the
+            # reduction — exactly one dispatch per chunk per batch
             def _one_batch(_idx=idx, _acc=acc, _start=start, _b=b):
                 _faults.fault_point("chunked.batch", start=_start, batch=_b)
                 last_ci = len(chunks) - 1
@@ -761,13 +792,15 @@ def run_sliced_chunked_placed(
                     )
                     obs.counter_add("resilience.degrade.batch_shrink")
                     obs.gauge_set("resilience.degrade.batch", batch)
-                    chunks, chunk_fns = _compiled_plan(
+                    chunks, chunk_fns, row_modes = _compiled_plan(
                         sp, batch, chunk_steps, split_complex, precision,
                         interpret,
                     )
                     continue
                 raise
             dispatches += len(chunks)
+            for mode in row_modes:
+                obs.counter_add("chunked.rows", mode=mode)
             start += b
             if mgr is not None:
                 mgr.maybe_save(
