@@ -36,11 +36,10 @@ Env knobs:
   BENCH_REPS (3), BENCH_PEAK_FLOPS (per device),
   BENCH_PIPELINE_CALLS (32; small configs — dispatches enqueued per
     timed region, blocked once: steady-state per-eval time),
-  BENCH_EXEC chunked|loop, BENCH_BATCH (8), BENCH_PROBE_SLICES (64),
+  BENCH_BATCH (8), BENCH_PROBE_SLICES (64),
   BENCH_HOIST (1; slice-invariant stem hoisting — prelude once, residual
     per slice), BENCH_HOIST_AB (1; probe-subset A/B hoisted vs naive
     when the stem is non-trivial),
-  BENCH_LOOP_UNROLL (1; loop strategy only — unrolled-scan slice loop),
   BENCH_FULL_SECONDS (900; run all slices if projected under this),
   BENCH_TRACE =1 to capture a profiler trace (off otherwise),
   BENCH_FORCE_CPU=1 (run on the CPU platform; without it no accelerator
@@ -180,14 +179,6 @@ def _plan_cache():
         os.path.join(
             os.path.dirname(os.path.abspath(__file__)), ".cache", "plans"
         )
-    )
-
-
-def _current_exec() -> str:
-    """Resolved sliced-executor strategy: BENCH_EXEC env, else the
-    config marker, else chunked."""
-    return os.environ.get("BENCH_EXEC") or _tuned_default(
-        "exec", "chunked", ("chunked", "loop")
     )
 
 
@@ -416,7 +407,6 @@ def bench_sycamore_amplitude():
             },
         )
 
-    strategy = _current_exec()
     # complex-multiply lowering: `gauss` is the single tuned per-step
     # default (3 dots via the Gauss identity; the parity ladder pins
     # it), and unforced ("auto") the kernel promotion ladder
@@ -445,15 +435,13 @@ def bench_sycamore_amplitude():
     hoist_on = os.environ.get("BENCH_HOIST", "1") != "0"
     backend = JaxBackend(
         dtype="complex64",
-        sliced_strategy=strategy,
         slice_batch=_env_int("BENCH_BATCH", 8),
         chunk_steps=_env_int("BENCH_CHUNK_STEPS", 48),
         precision=precision,
-        loop_unroll=_env_int("BENCH_LOOP_UNROLL", 1),
         hoist=hoist_on,
     )
     log(
-        f"[bench] executor: {strategy} "
+        f"[bench] executor: chunked "
         f"(complex_mult={complex_mult}, precision={precision}, "
         f"hoist={hoist_on})"
     )

@@ -25,7 +25,7 @@ def main():
 
     from tnc_tpu.ops import chunked
     from tnc_tpu.ops.program import flat_leaf_tensors
-    from tnc_tpu.ops.sliced import build_sliced_program, _slice_indices, index_buffer
+    from tnc_tpu.ops.sliced import build_sliced_program, index_buffer, slice_indices
     from tnc_tpu.ops.split_complex import apply_step_split, run_steps_split, split_array
 
     tn, replace, slicing, _ = load_plan()
@@ -39,7 +39,7 @@ def main():
     print(f"device: {dev.platform} ({dev.device_kind}) gran={gran}", flush=True)
 
     arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(tn)]
-    indices = _slice_indices(sp.slicing, 0)
+    indices = slice_indices(sp.slicing.dims, 0)
     buffers = []
     for arr, info in zip(arrays, sp.slot_slices):
         sl = index_buffer(np, np.asarray(arr), info, indices)

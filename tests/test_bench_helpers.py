@@ -18,21 +18,21 @@ import bench  # noqa: E402
 def test_tuned_default_missing_marker(tmp_path):
     assert (
         bench._tuned_default(
-            "exec", "chunked", ("chunked", "loop"),
+            "precision", "float32", ("float32", "high"),
             marker_path=str(tmp_path / "nope.json"),
         )
-        == "chunked"
+        == "float32"
     )
 
 
 def test_tuned_default_reads_marker_and_validates(tmp_path):
     marker = tmp_path / "best_config.json"
-    marker.write_text(json.dumps({"exec": "loop", "complex_mult": "quux"}))
+    marker.write_text(json.dumps({"precision": "high", "complex_mult": "quux"}))
     assert (
         bench._tuned_default(
-            "exec", "chunked", ("chunked", "loop"), marker_path=str(marker)
+            "precision", "float32", ("float32", "high"), marker_path=str(marker)
         )
-        == "loop"
+        == "high"
     )
     # unknown values never escape the allowed set
     assert (
@@ -45,9 +45,9 @@ def test_tuned_default_reads_marker_and_validates(tmp_path):
     marker.write_text("not json{")
     assert (
         bench._tuned_default(
-            "exec", "chunked", ("chunked", "loop"), marker_path=str(marker)
+            "precision", "float32", ("float32", "high"), marker_path=str(marker)
         )
-        == "chunked"
+        == "float32"
     )
 
 

@@ -5,8 +5,9 @@ Held here, on the CPU: the sum over any slice range equals the numpy
 oracle's; one set of programs serves every range; a checkpointed run
 resumes bit-identically; the `chunked.rows` counter and the
 `sliced.residual` span say how the rows ran; and the lowered
-`jit_tnc_residual_*` programs hold exactly the loop body's
-`dot_general`s and `transpose`s (`make_jax_sliced_fn(...).jitted`).
+`jit_tnc_residual_*` programs hold exactly the `dot_general`s and
+`transpose`s a slice of the SPMD loop's body (`jit_tnc_spmd_slices` on a
+mesh of one device): both build a slice from `ops.sliced.slice_body`.
 """
 
 import collections
@@ -25,9 +26,10 @@ from tnc_tpu.ops.chunked import (
 from tnc_tpu.ops.hoist import hoist_sliced_program
 from tnc_tpu.ops.sliced import (
     build_sliced_program,
-    make_jax_sliced_fn,
+    slice_indices,
     sliced_partials_numpy,
 )
+from tnc_tpu.parallel.sliced_parallel import _make_spmd_fn, make_mesh
 from tnc_tpu.resilience import faultinject as fi
 
 
@@ -181,6 +183,78 @@ def test_rows_counter_and_span_attribute(sycamore20, registry, hoist, modes):
     }
 
 
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("host", [True, False])
+def test_slice_range_on_a_one_slice_program(split, host):
+    """An unsliced program is its slice 0: a range that holds it gives
+    the value, one that does not gives zeros (stored shape on device)."""
+    from tnc_tpu.contractionpath.contraction_path import ContractionPath
+    from tnc_tpu.contractionpath.slicing import Slicing
+    from tnc_tpu.ops.backends import JaxBackend
+    from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
+    from tnc_tpu.tensornetwork.tensordata import TensorData
+
+    rng = np.random.default_rng(5)
+    mats = [
+        rng.standard_normal((4, 4)) + 1j * rng.standard_normal((4, 4))
+        for _ in range(3)
+    ]
+    legs = [[0, 1], [1, 2], [2, 3]]
+    tn = CompositeTensor([
+        LeafTensor(l, [4, 4], TensorData.matrix(m)) for l, m in zip(legs, mats)
+    ])
+    sp = build_sliced_program(
+        tn, ContractionPath.simple([(0, 1), (0, 2)]), Slicing((), ())
+    )
+    assert sp.slicing.num_slices == 1
+    backend = JaxBackend(dtype="complex128", split_complex=split, donate=False)
+
+    def run(slice_range):
+        out = backend.execute_sliced(
+            sp, mats, host=host, slice_range=slice_range
+        )
+        if host:
+            assert out.shape == tuple(sp.program.result_shape)
+            return np.asarray(out)
+        if split:
+            out = np.asarray(out[0]) + 1j * np.asarray(out[1])
+        assert out.shape == tuple(sp.program.stored_result_shape)
+        return np.asarray(out).reshape(sp.program.result_shape)
+
+    want = run(None)
+    assert np.abs(want).max() > 0
+    for holds in ((0, 1), (0, 5), (-2, 1)):
+        np.testing.assert_allclose(run(holds), want, rtol=1e-12)
+    for misses in ((1, 3), (0, 0), (-3, 0)):
+        assert not run(misses).any(), misses
+    with pytest.raises(ValueError, match="exclusive"):
+        backend.execute_sliced(sp, mats, max_slices=1, slice_range=(0, 1))
+
+
+@pytest.mark.parametrize("dims", [(2, 3, 4), (5,), (2, 2, 2, 2), (3, 1, 2)])
+def test_slice_id_rule_agrees_with_itself(dims):
+    """One rule from a slice id to its leg indices, whatever the id is:
+    a Python int (host loops), a numpy vector (the chunked executor's
+    index table) or a traced scalar (the on-device loops); last leg
+    fastest, as ``np.unravel_index`` counts."""
+    import jax
+    import jax.numpy as jnp
+
+    num = int(np.prod(dims))
+    ids = np.arange(num)
+    table = np.stack(slice_indices(dims, ids), axis=1)
+    assert table.shape == (num, len(dims))
+    np.testing.assert_array_equal(
+        table, np.stack(np.unravel_index(ids, dims), axis=1)
+    )
+    traced = jax.jit(lambda s: jnp.stack(slice_indices(dims, s)))
+    for s in range(num):
+        ints = slice_indices(dims, s)
+        assert all(isinstance(i, int) for i in ints)
+        assert ints == table[s].tolist()
+        assert np.asarray(traced(jnp.int32(s))).tolist() == ints
+
+
 # -- the lowered programs -------------------------------------------------
 
 _DOT = re.compile(
@@ -211,9 +285,9 @@ def _rank(tensor_type):
 @pytest.mark.parametrize("chunk_steps", [8, 16, 64])
 def test_residual_programs_lower_to_the_loop_body(sycamore20, chunk_steps):
     """Every step of a ``jit_tnc_residual_*`` program is the unbatched
-    step the slice loop's body runs: the same ``dot_general``s on the
-    same operand shapes, none with a batch dimension, and as many
-    ``transpose``s a slice."""
+    step the SPMD slice loop's body runs (the two slice cells' programs):
+    the same ``dot_general``s on the same operand shapes, none with a
+    batch dimension, and as many ``transpose``s a slice."""
     import jax
     import jax.numpy as jnp
 
@@ -227,9 +301,9 @@ def test_residual_programs_lower_to_the_loop_body(sycamore20, chunk_steps):
     prelude = _prelude_fn(hp, True, "float32")
     pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
     prelude_text = prelude.lower(pins).as_text()
-    loop_text = make_jax_sliced_fn(
-        sp, split_complex=True, precision="float32", hoist=True
-    ).jitted.lower(full).as_text()
+    loop_text = _make_spmd_fn(
+        sp, make_mesh(1), "slices", "complex64", True, "float32", hoist=True
+    ).lower(*full).as_text()
     # the loop program traces the prelude before its loop
     body_dots = _dots(loop_text) - _dots(prelude_text)
     body_transposes = _transposes(loop_text) - _transposes(prelude_text)

@@ -1,8 +1,8 @@
 """Slice-invariant stem hoisting (`tnc_tpu.ops.hoist`).
 
 Parity discipline: the *unhoisted numpy oracle* is law. Every hoisted
-executor — numpy, on-device loop (complex + split), chunked, SPMD on the
-virtual mesh — must reproduce it; the hoist pass must degrade to a no-op
+executor — numpy, chunked, the SPMD loop on the virtual mesh (one device
+and two, complex + split) — must reproduce it; the hoist pass must degrade to a no-op
 when every input touches a sliced leg; and the planner's hoist-aware
 flop accounting must stay consistent with the naive totals.
 """
@@ -26,7 +26,6 @@ from tnc_tpu.ops.sliced import (
     build_sliced_program,
     execute_sliced_numpy,
     execute_sliced_numpy_parallel,
-    make_jax_sliced_fn,
     sliced_partials_numpy,
 )
 from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
@@ -136,36 +135,6 @@ def test_run_prelude_passthrough_on_noop():
     assert len(res) == hp.residual.program.num_inputs
 
 
-@pytest.mark.parametrize("unroll", [1, 4])
-def test_jax_loop_parity_complex(unroll):
-    sp, arrays = _sliced(6)
-    import jax.numpy as jnp
-
-    naive = execute_sliced_numpy(sp, arrays)
-    fn = make_jax_sliced_fn(sp, unroll=unroll, hoist=True)
-    bufs = [jnp.asarray(a, dtype="complex128") for a in arrays]
-    got = np.asarray(fn(bufs)).reshape(sp.program.result_shape)
-    np.testing.assert_allclose(got, naive, rtol=1e-10, atol=1e-10)
-
-
-def test_jax_loop_parity_split_complex():
-    sp, arrays = _sliced(7)
-    import jax.numpy as jnp
-
-    from tnc_tpu.ops.split_complex import combine_array, split_array
-
-    naive = execute_sliced_numpy(sp, arrays)
-    fn = make_jax_sliced_fn(sp, split_complex=True, hoist=True)
-    pairs = [
-        tuple(map(jnp.asarray, split_array(a, "float64"))) for a in arrays
-    ]
-    re, im = fn(pairs)
-    got = combine_array(np.asarray(re), np.asarray(im)).reshape(
-        sp.program.result_shape
-    )
-    np.testing.assert_allclose(got, naive, rtol=1e-10, atol=1e-10)
-
-
 @pytest.mark.parametrize("split", [False, True])
 def test_chunked_parity(split):
     from tnc_tpu.ops.chunked import execute_sliced_batched_jax
@@ -184,8 +153,10 @@ def test_chunked_parity(split):
     np.testing.assert_allclose(got, naive, rtol=1e-10, atol=1e-10)
 
 
+# one device: the whole slice loop in one program, hoisted and not
+@pytest.mark.parametrize("n_devices,hoist", [(2, True), (1, True), (1, False)])
 @pytest.mark.parametrize("split", [False, True])
-def test_spmd_parity_on_virtual_devices(split):
+def test_spmd_parity_on_virtual_devices(split, n_devices, hoist):
     from tnc_tpu.parallel.sliced_parallel import (
         distributed_sliced_contraction,
     )
@@ -199,10 +170,10 @@ def test_spmd_parity_on_virtual_devices(split):
         tn,
         path,
         slicing,
-        n_devices=2,
+        n_devices=n_devices,
         dtype="complex128",
         split_complex=split,
-        hoist=True,
+        hoist=hoist,
     )
     got = out.data.into_data().reshape(sp.program.result_shape)
     np.testing.assert_allclose(got, naive, rtol=1e-10, atol=1e-10)
@@ -214,7 +185,7 @@ def test_jax_backend_default_hoist_parity():
     sp, arrays = _sliced(10, legs=(3,), dims=(4,))
     want = NumpyBackend().execute_sliced(sp, arrays)
     backend = JaxBackend(
-        dtype="complex128", split_complex=False, sliced_strategy="chunked"
+        dtype="complex128", split_complex=False
     )
     assert backend.hoist
     got = np.asarray(backend.execute_sliced(sp, arrays))

@@ -93,8 +93,9 @@ def test_complex_mult_modes_match_oracle(mode, monkeypatch):
     assert err < 5e-6
 
 
-@pytest.mark.parametrize("strategy", ["chunked", "loop"])
-def test_sliced_executors_with_kahan_match_oracle(strategy, monkeypatch):
+# batch 1: every slice goes through the compensated fold on its own
+@pytest.mark.parametrize("slice_batch", [4, 1])
+def test_sliced_executors_with_kahan_match_oracle(slice_batch, monkeypatch):
     from tnc_tpu.builders.random_circuit import random_circuit
     from tnc_tpu.builders.connectivity import ConnectivityLayout
     from tnc_tpu.contractionpath.contraction_path import ContractionPath
@@ -133,8 +134,7 @@ def test_sliced_executors_with_kahan_match_oracle(strategy, monkeypatch):
         dtype="complex64",
         split_complex=True,
         precision="float32",
-        sliced_strategy=strategy,
-        slice_batch=4,
+        slice_batch=slice_batch,
         chunk_steps=8,
     )
     got = np.asarray(backend.execute_sliced(sp, arrays))

@@ -442,7 +442,7 @@ def test_chunked_jax_run_emits_prelude_and_residual_spans(enabled_obs):
     sp, arrays = _ring_sliced_program()
     want = NumpyBackend().execute_sliced(sp, arrays)
     got = JaxBackend(
-        dtype="complex64", sliced_strategy="chunked"
+        dtype="complex64"
     ).execute_sliced(sp, arrays, hoist=True)
     assert np.allclose(got, want, atol=1e-4)
     names = {r.name for r in enabled_obs.span_records()}

@@ -396,7 +396,7 @@ def _lowered(program_kind: str):
     from tnc_tpu.ops.chunked import _compiled_plan, _prelude_fn
     from tnc_tpu.ops.hoist import hoist_sliced_program
     from tnc_tpu.ops.program import build_program
-    from tnc_tpu.ops.sliced import build_sliced_program, make_jax_sliced_fn
+    from tnc_tpu.ops.sliced import build_sliced_program
     from tnc_tpu.parallel.sliced_parallel import _make_spmd_fn, make_mesh
 
     def pair(*shape):
@@ -414,10 +414,6 @@ def _lowered(program_kind: str):
         )
         return fn.jitted.lower([pair(2, 4, 4)] + full[1:])
     sp = build_sliced_program(tn, path, Slicing((3, 4), (4, 4)))
-    if program_kind == "tnc_slice_loop":
-        return make_jax_sliced_fn(
-            sp, split_complex=True, hoist=True
-        ).jitted.lower(full)
     if program_kind == "tnc_spmd_slices":
         fn = _make_spmd_fn(
             sp, make_mesh(4), "slices", "complex64", True, "float32",
@@ -430,21 +426,28 @@ def _lowered(program_kind: str):
     pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
     if program_kind == "tnc_prelude":
         return prelude.lower(pins)
-    assert program_kind == "tnc_residual_c00"
     chunks, chunk_fns, _ = _compiled_plan(hp.residual, 4, 2, True, "float32")
     assert len(chunks) > 1  # the first is not the accumulating last one
     cached = iter(jax.eval_shape(prelude, pins))
-    inputs = [
+    state = dict(enumerate(
         full[ref] if kind == "leaf" else next(cached)
         for kind, ref in hp.residual_sources
-    ]
-    ins = tuple(inputs[slot] for slot in chunks[0].in_slots)
-    return chunk_fns[0].lower(ins, jax.ShapeDtypeStruct((4, 2), jnp.int32))
+    ))
+    idx = jax.ShapeDtypeStruct((4, 2), jnp.int32)
+    for chunk, fn in zip(chunks[:-1], chunk_fns):
+        ins = tuple(state[slot] for slot in chunk.in_slots)
+        if program_kind == "tnc_residual_c00":
+            return fn.lower(ins, idx)
+        state.update(zip(chunk.out_slots, jax.eval_shape(fn, ins, idx)))
+    assert program_kind == "tnc_residual_last"
+    ins = tuple(state[slot] for slot in chunks[-1].in_slots)
+    acc = (pair(*hp.residual.program.stored_result_shape),) * 2
+    return chunk_fns[-1].lower(ins, idx, acc)
 
 
 @pytest.mark.parametrize("program_kind", [
     "tnc_program", "tnc_program_batched", "tnc_prelude", "tnc_residual_c00",
-    "tnc_slice_loop", "tnc_spmd_slices",
+    "tnc_residual_last", "tnc_spmd_slices",
 ])
 def test_lowered_module_name_and_bucket_scopes(program_kind):
     text = _lowered(program_kind).as_text(debug_info=True)

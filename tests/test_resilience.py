@@ -483,7 +483,7 @@ def test_full_ladder_replans_and_returns_correct_amplitude(
     ring, path, sp, arrays = _ring_sliced_program(dims=(2,), slice_dims=(4,))
     oracle = execute_sliced_numpy(sp, arrays)
     backend = JaxBackend(
-        dtype="complex64", sliced_strategy="chunked", slice_batch=2,
+        dtype="complex64", slice_batch=2,
         split_complex=False,
     )
     # exhaust the batch-shrink rung (2 -> 1 -> raise), then the replan
@@ -500,12 +500,31 @@ def test_full_ladder_replans_and_returns_correct_amplitude(
     assert c[("resilience.ladder.replans", ())] == 1.0
 
 
+def test_ladder_spent_reraises_the_resource_error(enabled_obs, fast_retry):
+    """Both rungs spent (every batch size of every re-sliced program
+    fails): the resource error reaches the caller, after exactly
+    ``max_replans`` re-slicings and no other executor."""
+    from tnc_tpu.ops.backends import JaxBackend
+
+    ring, path, sp, _ = _ring_sliced_program(dims=(2,), slice_dims=(4,))
+    backend = JaxBackend(dtype="complex64", slice_batch=2, split_complex=False)
+    # a re-sliced program may come back unsliced: it then runs whole
+    with fi.faults("chunked.batch=oom*-1;backend.dispatch=oom*-1"):
+        with pytest.raises(fi.InjectedOOM):
+            execute_sliced_resilient(
+                ring, path, sp.slicing, backend=backend, max_replans=1
+            )
+    c = enabled_obs.counters()
+    assert c[("resilience.ladder.replans", ())] == 1.0
+    assert not obs.counters_by_prefix("resilience.ladder.fallback")
+
+
 def test_ladder_reraises_fatal_untouched(fast_retry):
     from tnc_tpu.ops.backends import JaxBackend
 
     ring, path, sp, _ = _ring_sliced_program(dims=(2,), slice_dims=(4,))
     backend = JaxBackend(
-        dtype="complex64", sliced_strategy="chunked", slice_batch=2,
+        dtype="complex64", slice_batch=2,
         split_complex=False,
     )
     with fi.faults("chunked.batch=fatal*99"):

@@ -1213,14 +1213,13 @@ class TestSliceRangeSharding:
     def test_jax_chunked_strategy_serves_range_partials(
         self, tmp_path, enabled_obs
     ):
-        """The chunked executor (the tuned TPU strategy) honors
-        ``slice_range`` — a range shard must not silently demote every
-        serving host to the loop program. Partials sum to the whole and
-        the chunked residual span proves which executor ran."""
+        """The chunked executor honors ``slice_range`` with the
+        programs it has: partials sum to the whole and the chunked
+        residual span proves which executor ran."""
         bound = self._sliced_bound(tmp_path)
         num = bound.sliced.slicing.num_slices
         det = [bound.template.request_bits("10101010")]
-        backend = JaxBackend(sliced_strategy="chunked", donate=False)
+        backend = JaxBackend(donate=False)
         full = np.asarray(bound.amplitudes_det(det, backend))
         lo = np.asarray(
             bound.amplitudes_det(det, backend, slice_range=(0, num // 2))

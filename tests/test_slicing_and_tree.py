@@ -330,12 +330,15 @@ def test_jax_backend_chunked_strategy():
     sp = build_sliced_program(tn, rp, slicing)
     arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(tn)]
 
-    loop = JaxBackend(dtype="complex64", sliced_strategy="loop")
-    chunked = JaxBackend(
-        dtype="complex64", sliced_strategy="chunked", slice_batch=1,
-        chunk_steps=8,
+    # the on-device loop (the SPMD entry on one device) and the host
+    # loop build their slices from one body: they agree
+    from tnc_tpu.parallel.sliced_parallel import distributed_sliced_contraction
+
+    loop = distributed_sliced_contraction(
+        tn, rp, slicing, n_devices=1, dtype="complex64"
     )
-    a = complex(np.asarray(loop.execute_sliced(sp, arrays)).reshape(-1)[0])
+    chunked = JaxBackend(dtype="complex64", slice_batch=1, chunk_steps=8)
+    a = complex(loop.data.into_data().reshape(-1)[0])
     b = complex(np.asarray(chunked.execute_sliced(sp, arrays)).reshape(-1)[0])
     assert a == pytest.approx(b, rel=1e-4, abs=1e-7)
 
@@ -369,14 +372,15 @@ def test_chunked_zero_step_sliced_program():
         )
 
 
-def test_loop_unroll_scan_matches_oracle():
-    """The unrolled-scan slice loop (loop_unroll > 1) must match the
-    oracle for unroll factors that divide the slice count and ones that
-    leave a masked remainder group."""
+def test_one_device_slice_loop_matches_oracle():
+    """The whole slice loop in one program (the SPMD entry on a mesh of
+    one device) must match the oracle, complex and split, hoisted and
+    not."""
     from tnc_tpu.contractionpath.slicing import find_slicing
-    from tnc_tpu.ops.backends import JaxBackend, NumpyBackend
+    from tnc_tpu.ops.backends import NumpyBackend
     from tnc_tpu.ops.program import flat_leaf_tensors
     from tnc_tpu.ops.sliced import build_sliced_program
+    from tnc_tpu.parallel.sliced_parallel import distributed_sliced_contraction
 
     tn = _sycamore_network(qubits=12, depth=6, seed=3)
     res = Greedy(OptMethod.GREEDY).find_path(tn)
@@ -384,26 +388,21 @@ def test_loop_unroll_scan_matches_oracle():
     slicing = find_slicing(
         list(tn.tensors), rp.toplevel, max(64.0, res.size / 32)
     )
-    # 4+ slices: unroll=3 leaves a masked remainder group, unroll=4 divides
     assert slicing.num_slices >= 4
     sp = build_sliced_program(tn, rp, slicing)
     arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(tn)]
     want = complex(
         np.asarray(NumpyBackend().execute_sliced(sp, arrays)).reshape(-1)[0]
     )
-    for unroll in (3, 4):  # 3 leaves a remainder group for pow-2 counts
+    for hoist in (False, True):
         for split in (False, True):
-            b = JaxBackend(
-                dtype="complex64",
-                split_complex=split,
-                sliced_strategy="loop",
-                loop_unroll=unroll,
+            out = distributed_sliced_contraction(
+                tn, rp, slicing, n_devices=1, dtype="complex64",
+                split_complex=split, hoist=hoist,
             )
-            got = complex(
-                np.asarray(b.execute_sliced(sp, arrays)).reshape(-1)[0]
-            )
+            got = complex(out.data.into_data().reshape(-1)[0])
             assert got == pytest.approx(want, rel=1e-4, abs=1e-7), (
-                unroll,
+                hoist,
                 split,
             )
 
@@ -411,7 +410,7 @@ def test_loop_unroll_scan_matches_oracle():
 def test_execute_sliced_host_false_device_resident():
     """host=False (the benchmark-timing contract: no device→host
     transfer inside timed regions) returns the device accumulator in
-    stored shape for every backend/strategy, equal to the host result."""
+    stored shape for every backend, equal to the host result."""
     import jax
 
     from tnc_tpu.ops.backends import JaxBackend, NumpyBackend
@@ -434,23 +433,21 @@ def test_execute_sliced_host_false_device_resident():
     out_np = NumpyBackend().execute_sliced(sp, arrays, host=False)
     assert out_np.shape == tuple(stored)
 
-    for strategy in ("chunked", "loop"):
-        for split in (False, True):
-            backend = JaxBackend(
-                dtype="complex64",
-                split_complex=split,
-                sliced_strategy=strategy,
-                slice_batch=1,
-                chunk_steps=8,
-            )
-            dev = backend.execute_sliced(sp, arrays, host=False)
-            if split:
-                assert isinstance(dev, tuple) and len(dev) == 2
-                got = np.asarray(dev[0]) + 1j * np.asarray(dev[1])
-            else:
-                assert isinstance(dev, jax.Array)
-                got = np.asarray(dev)
-            assert got.shape == tuple(stored), (strategy, split)
-            assert complex(got.reshape(-1)[0]) == pytest.approx(
-                want, rel=1e-4, abs=1e-7
-            ), (strategy, split)
+    for split in (False, True):
+        backend = JaxBackend(
+            dtype="complex64",
+            split_complex=split,
+            slice_batch=1,
+            chunk_steps=8,
+        )
+        dev = backend.execute_sliced(sp, arrays, host=False)
+        if split:
+            assert isinstance(dev, tuple) and len(dev) == 2
+            got = np.asarray(dev[0]) + 1j * np.asarray(dev[1])
+        else:
+            assert isinstance(dev, jax.Array)
+            got = np.asarray(dev)
+        assert got.shape == tuple(stored), split
+        assert complex(got.reshape(-1)[0]) == pytest.approx(
+            want, rel=1e-4, abs=1e-7
+        ), split

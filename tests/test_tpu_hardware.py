@@ -153,11 +153,9 @@ def test_sliced_execution_parity(device):
     sp = build_sliced_program(tn, replace, slicing)
     arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(tn)]
     want = execute_sliced_numpy(sp, arrays, dtype=np.complex128)
-    for strategy in ("loop", "chunked"):
-        backend = JaxBackend(dtype="complex64", sliced_strategy=strategy)
-        got = np.asarray(backend.execute_sliced(sp, arrays))
-        denom = max(float(np.max(np.abs(want))), 1e-30)
-        assert float(np.max(np.abs(got - want))) / denom <= 1e-5, strategy
+    got = np.asarray(JaxBackend(dtype="complex64").execute_sliced(sp, arrays))
+    denom = max(float(np.max(np.abs(want))), 1e-30)
+    assert float(np.max(np.abs(got - want))) / denom <= 1e-5
 
 
 @requires_tpu_env
@@ -338,7 +336,6 @@ def test_naive_mult_kahan_bench_arithmetic_parity(device):
             dtype="complex64",
             split_complex=True,
             precision="float32",
-            sliced_strategy="chunked",
             slice_batch=4,
             chunk_steps=16,
         )

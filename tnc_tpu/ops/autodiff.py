@@ -152,10 +152,9 @@ def sliced_contraction_value_and_grad(
     from jax import lax
 
     from tnc_tpu.ops.sliced import (
-        _slice_indices,
         build_sliced_program,
-        index_buffer,
         kahan_add,
+        program_slice_fn,
     )
 
     sp = build_sliced_program(tn, contract_path, slicing)
@@ -177,6 +176,7 @@ def sliced_contraction_value_and_grad(
     dim_of = dict(zip(program.result_legs, program.result_shape))
     canonical_shape = tuple(dim_of[leg] for leg in program.canonical_legs)
     num = sp.slicing.num_slices
+    one_slice = program_slice_fn(jnp, sp)
 
     def forward(diff_arrays):
         buffers = list(arrays)
@@ -185,12 +185,7 @@ def sliced_contraction_value_and_grad(
 
         @jax.checkpoint
         def contribution(s):
-            indices = _slice_indices(sp.slicing, s)
-            sliced = [
-                index_buffer(jnp, arr, info, indices)
-                for arr, info in zip(buffers, sp.slot_slices)
-            ]
-            return _run_steps(jnp, program, list(sliced))
+            return one_slice(buffers, s)
 
         def body(s, carry):
             return kahan_add(carry[0], carry[1], contribution(s))

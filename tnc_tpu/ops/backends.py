@@ -155,13 +155,20 @@ def apply_step(xp, a: Any, b: Any, step) -> Any:
     return out.reshape(step.out_store)
 
 
+def apply_steps(xp, steps, state) -> None:
+    """The one walker of complex-dtype steps: run ``steps`` in order
+    over ``state`` (a list or dict, slot -> buffer), in place; a
+    consumed slot is left ``None`` (freed eagerly)."""
+    for step in steps:
+        state[step.lhs] = apply_step(xp, state[step.lhs], state[step.rhs], step)
+        state[step.rhs] = None
+
+
 def _run_steps(xp, program: ContractionProgram, buffers: list[Any]) -> Any:
     """Execute all steps; returns the result in **stored** (merged) shape —
     callers reshape to ``program.result_shape`` on the host, so the jit
     output never materializes a high-rank tile-padded array."""
-    for step in program.steps:
-        buffers[step.lhs] = apply_step(xp, buffers[step.lhs], buffers[step.rhs], step)
-        buffers[step.rhs] = None  # free eagerly
+    apply_steps(xp, program.steps, buffers)
     return buffers[program.result_slot]
 
 
@@ -861,21 +868,15 @@ class JaxBackend(Backend):
         device=None,
         split_complex: bool | None = None,
         precision: str | None = "float32",
-        sliced_strategy: str = "chunked",
         slice_batch: int = 8,
         chunk_steps: int = 64,
-        loop_unroll: int = 1,
         hoist: bool = True,
     ):
-        """``sliced_strategy``: 'chunked' (default) splits the program
-        into chunks and dispatches ``slice_batch`` slices at a time (K
-        small compiles; the host loop carries checkpoints, retries and
-        slice ranges — see :mod:`tnc_tpu.ops.chunked`); 'loop' compiles
-        the whole slice loop into one on-device ``fori_loop`` program.
-        Both run a slice's steps on unbatched operands inside an
-        on-device loop: on the v5e the loop body takes 28 ms a
-        Sycamore-53 slice where the chunks' former ``vmap`` over the
-        batch took 38.5 (``PERF.md`` §6, PR 25 and PR 29).
+        """A sliced program runs through the chunked executor
+        (:mod:`tnc_tpu.ops.chunked`): the program split into chunks of
+        ``chunk_steps`` steps (K small compiles), ``slice_batch`` slices
+        a dispatch, the host loop carrying checkpoints, retries and
+        slice ranges.
 
         ``hoist`` (default True): execute the slice-invariant stem once
         per call and loop only the residual program (see
@@ -899,14 +900,9 @@ class JaxBackend(Backend):
         # read from the process
         self.interpret = interpret_for(target)
         self.precision = precision
-        if sliced_strategy not in ("loop", "chunked"):
-            raise ValueError(f"unknown sliced_strategy {sliced_strategy!r}")
-        self.sliced_strategy = sliced_strategy
         self.slice_batch = slice_batch
         self.chunk_steps = chunk_steps
-        self.loop_unroll = loop_unroll
         self.hoist = hoist
-        self._cache: dict[tuple, Any] = {}
         self._policy_cache: dict[tuple, Any] = {}
 
     def kernel_policy(self, program: ContractionProgram):
@@ -1011,7 +1007,8 @@ class JaxBackend(Backend):
         hoist: bool | None = None,
         slice_range: tuple[int, int] | None = None,
     ):
-        """Run a sliced program; the slice loop executes on device.
+        """Run a sliced program on the device, through the chunked
+        executor (:mod:`tnc_tpu.ops.chunked`).
         ``max_slices`` caps the loop (partial sum — benchmark subsets).
         ``host=False`` keeps the result on device in stored shape (a
         (real, imag) pair in split mode) — no device→host transfer, the
@@ -1019,130 +1016,45 @@ class JaxBackend(Backend):
         ``hoist`` overrides the backend default (slice-invariant stem
         executed once, residual looped — :mod:`tnc_tpu.ops.hoist`).
         ``slice_range=(lo, hi)`` sums only that contiguous slice shard
-        on device (the multi-host serving partial) — under the
-        backend's own sliced strategy: chunked runs the range through
-        the chunked executor, the loop strategies compile a range-bound
-        loop program."""
-
-        from tnc_tpu.ops.sliced import make_jax_sliced_fn
-
+        (the multi-host serving partial), with the programs every other
+        range runs. An unsliced program is its slice 0: a range that
+        does not hold it sums nothing."""
         if hoist is None:
             hoist = self.hoist
-        obs.counter_add(
-            "backend.execute_sliced_calls", strategy=self.sliced_strategy
-        )
-        if slice_range is not None:
-            if max_slices is not None:
-                raise ValueError(
-                    "slice_range and max_slices are exclusive"
-                )
-            if self.sliced_strategy == "chunked" and sp.slicing.num_slices > 1:
-                # a range shard runs under the backend's own strategy:
-                # the chunked executor takes any range with the programs
-                # it has, where the loop strategies compile a program
-                # per range (PERF.md §6, PR 29: the same pace a slice)
-                from tnc_tpu.ops.chunked import execute_sliced_batched_jax
-
-                return execute_sliced_batched_jax(
-                    sp,
-                    arrays,
-                    batch=self.slice_batch,
-                    chunk_steps=self.chunk_steps,
-                    split_complex=self.split_complex,
-                    precision=self.precision,
-                    dtype=self.dtype,
-                    device=self.device,
-                    host=host,
-                    hoist=hoist,
-                    slice_range=tuple(slice_range),
-                    interpret=self.interpret,
-                )
-            from tnc_tpu.ops.split_complex import (
-                complex_mult_key,
-                dot_precision_key,
-            )
-
-            key = (
-                "sliced_range", sp.signature(), str(self.dtype),
-                self.split_complex, tuple(slice_range), hoist,
-                lanemix_env(),
-                complex_mult_key() if self.split_complex else None,
-                dot_precision_key() if self.split_complex else None,
-            )
-            with obs.phase("backend.lookup"):
-                fn = self._cache.get(key)
-                if fn is None:
-                    fn = make_jax_sliced_fn(
-                        sp,
-                        split_complex=self.split_complex,
-                        precision=self.precision,
-                        hoist=hoist,
-                        slice_range=tuple(slice_range),
-                        interpret=self.interpret,
-                    )
-                    self._cache[key] = fn
-            buffers = self._device_buffers(arrays)
-            with obs.phase("backend.execute"):
-                result = fn(buffers)
-            if not host:
-                return result
-            return self._fetch(result, sp.program.result_shape)
+        obs.counter_add("backend.execute_sliced_calls")
+        if slice_range is not None and max_slices is not None:
+            raise ValueError("slice_range and max_slices are exclusive")
         if sp.slicing.num_slices == 1:
+            if slice_range is not None and not (
+                slice_range[0] <= 0 < slice_range[1]
+            ):
+                if host:
+                    return np.zeros(sp.program.result_shape, dtype=self.dtype)
+                stored = np.zeros(
+                    sp.program.stored_result_shape, dtype=self.dtype
+                )
+                return self._device_buffers([stored], transient=(0,))[0]
             if not host:  # device-resident, stored shape — no D2H
                 return self.execute_on_device(sp.program, arrays)
             return self.execute(sp.program, arrays)
 
-        if self.sliced_strategy == "chunked":
-            from tnc_tpu.ops.chunked import execute_sliced_batched_jax
+        from tnc_tpu.ops.chunked import execute_sliced_batched_jax
 
-            return execute_sliced_batched_jax(
-                sp,
-                arrays,
-                batch=self.slice_batch,
-                chunk_steps=self.chunk_steps,
-                split_complex=self.split_complex,
-                precision=self.precision,
-                dtype=self.dtype,
-                device=self.device,
-                max_slices=max_slices,
-                host=host,
-                hoist=hoist,
-                interpret=self.interpret,
-            )
-
-        from tnc_tpu.ops.split_complex import complex_mult_key, dot_precision_key
-
-        key = (
-            "sliced",
-            sp.signature(),
-            str(self.dtype),
-            self.split_complex,
-            max_slices,
-            self.loop_unroll,
-            hoist,
-            lanemix_env(),
-            complex_mult_key() if self.split_complex else None,
-            dot_precision_key() if self.split_complex else None,
+        return execute_sliced_batched_jax(
+            sp,
+            arrays,
+            batch=self.slice_batch,
+            chunk_steps=self.chunk_steps,
+            split_complex=self.split_complex,
+            precision=self.precision,
+            dtype=self.dtype,
+            device=self.device,
+            max_slices=max_slices,
+            host=host,
+            hoist=hoist,
+            slice_range=None if slice_range is None else tuple(slice_range),
+            interpret=self.interpret,
         )
-        with obs.phase("backend.lookup"):
-            fn = self._cache.get(key)
-            if fn is None:
-                fn = make_jax_sliced_fn(
-                    sp,
-                    split_complex=self.split_complex,
-                    precision=self.precision,
-                    num_slices=max_slices,
-                    unroll=self.loop_unroll,
-                    hoist=hoist,
-                    interpret=self.interpret,
-                )
-                self._cache[key] = fn
-        buffers = self._device_buffers(arrays)
-        with obs.phase("backend.execute"):
-            result = fn(buffers)
-        if not host:
-            return result
-        return self._fetch(result, sp.program.result_shape)
 
     def execute_batched(
         self,

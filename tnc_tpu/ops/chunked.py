@@ -1,37 +1,27 @@
 """Chunked execution of sliced contraction programs, a batch of slices
 a dispatch.
 
-The whole-path-in-one-``fori_loop`` executor (:mod:`tnc_tpu.ops.sliced`)
-compiles one XLA program containing every step and runs every slice in
-one dispatch. This module keeps the host in the loop, for what the host
-is needed for — checkpoints, retries, the batch-halving rung on a
-resource error, slice ranges:
+The host stays in the loop, for what the host is needed for —
+checkpoints, retries, the batch-halving rung on a resource error, slice
+ranges:
 
 - the program is **split into chunks** of at most ``chunk_steps`` steps,
   each compiled separately (compile cost scales with the chunk, not the
   whole program);
 - slices are dispatched in **batches of B**: a chunk program is given
   B rows of slice indices and runs them **one after another** in a
-  ``lax.scan``, every step on unbatched operands — the stored shapes and
-  macro-transposes :mod:`tnc_tpu.ops.program` planned, the step sequence
-  the slice loops' bodies run. B is the granularity of dispatches,
-  checkpoints and retries; it does not multiply a step's live memory;
+  ``lax.scan``, each row through the per-slice body of
+  :func:`tnc_tpu.ops.sliced.slice_body` — the body the SPMD loop of
+  :mod:`tnc_tpu.parallel.sliced_parallel` runs. B is the granularity of
+  dispatches, checkpoints and retries; it does not multiply a step's
+  live memory;
 - the rows' results are summed on device and accumulated across batches.
-
-Until PR 29 the rows ran under ``jax.vmap``, so that "narrow per-slice
-matmuls become batched matmuls". On the v5e the steps are bound by
-memory, not by the MXU (0.2 % of its peak), and the batch axis cost a
-relayout of the whole batch's result wherever one operand was a hoisted
-constant: 38.5 ms a slice against the loop body's 28 (``PERF.md`` §6,
-PR 25 and PR 29).
 
 Memory: one slice's intermediates are live inside a chunk; a chunk
 boundary stacks B rows of the slots alive across it.
 
-Per-step contraction kernels are shared with the other executors
-(``backends.apply_step`` / ``split_complex.apply_step_split``); compiled
-chunk functions are cached by program signature so repeated executions
-(benchmark reps, amplitude sweeps) compile nothing.
+Compiled chunk functions are cached by program signature so repeated
+executions (benchmark reps, amplitude sweeps) compile nothing.
 """
 
 from __future__ import annotations
@@ -46,14 +36,20 @@ import logging
 import numpy as np
 
 from tnc_tpu import obs
-from tnc_tpu.ops.backends import apply_step, named_jit, place_buffers
+from tnc_tpu.ops.backends import named_jit, place_buffers
 from tnc_tpu.ops.program import (
     ContractionProgram,
     PairStep,
     steps_bytes,
     steps_flops,
 )
-from tnc_tpu.ops.sliced import SlicedProgram, index_buffer, kahan_add
+from tnc_tpu.ops.sliced import (
+    SlicedProgram,
+    index_buffer,
+    kahan_add,
+    slice_body,
+    slice_indices,
+)
 from tnc_tpu.resilience import checkpoint as _ckpt
 from tnc_tpu.resilience import faultinject as _faults
 from tnc_tpu.resilience import retry as _retry
@@ -120,54 +116,6 @@ def split_program(
         )
         chunks.append(ProgramChunk(steps[a:b], tuple(read_here), outs))
     return chunks
-
-
-def _apply_steps(
-    xp, steps: Sequence[PairStep], state: dict[int, Any]
-) -> None:
-    for step in steps:
-        state[step.lhs] = apply_step(xp, state[step.lhs], state[step.rhs], step)
-        del state[step.rhs]
-
-
-def _apply_steps_split(
-    xp, steps: Sequence[PairStep], state: dict[int, Any], precision,
-    policy=None, interpret: bool = False,
-) -> None:
-    """``policy``: a :class:`~tnc_tpu.ops.split_complex.KernelPolicy`
-    planned over ``steps`` (spans indexed relative to them) — small
-    consecutive residual steps fuse into single Pallas chain dispatches
-    and eligible steps promote; ``None`` runs every step under the env
-    mode."""
-    from tnc_tpu.ops.split_complex import apply_step_split, run_chain_split
-
-    chain_end = {s: e for s, e in policy.chains} if policy is not None else {}
-    i = 0
-    while i < len(steps):
-        end = chain_end.get(i)
-        if end is not None:
-            group = steps[i:end]
-            run_chain_split(
-                xp, group, state, precision,
-                precision_mode=policy.precision_mode(i),
-                interpret=interpret,
-            )
-            for st in group:
-                if state.get(st.rhs) is None:  # consumed by the chain
-                    state.pop(st.rhs, None)
-            i = end
-            continue
-        step = steps[i]
-        state[step.lhs] = apply_step_split(
-            xp, state[step.lhs], state[step.rhs], step, precision,
-            mode=policy.modes[i] if policy is not None else None,
-            precision_mode=(
-                policy.precision_mode(i) if policy is not None else None
-            ),
-            interpret=interpret,
-        )
-        del state[step.rhs]
-        i += 1
 
 
 # compiled plan cache: key -> (chunks, chunk_fns, row_modes).
@@ -287,29 +235,22 @@ def _compiled_plan(
     chunks = split_program(sp.program, chunk_steps)
     num_inputs = sp.program.num_inputs
 
-    def planned(steps):
-        """``steps`` with their kernel promotion ladder (split mode):
-        chain spans and per-step modes planned over this subsequence — a
+    def body_of(steps, slots):
+        """The per-slice body over ``steps``, with their kernel
+        promotion ladder (split mode) planned over this subsequence — a
         chain cannot cross a chunk boundary (the boundary is a dispatch
         anyway). Cached with the plan; the cache key carries
         complex_mult_key so forced/auto plans never collide."""
         steps = tuple(steps)
-        if not split_complex or not steps:
-            return steps, None
-        from tnc_tpu.ops.split_complex import plan_kernel_steps
+        policy = None
+        if split_complex and steps:
+            from tnc_tpu.ops.split_complex import plan_kernel_steps
 
-        return steps, plan_kernel_steps(steps)
-
-    def run_steps(planned_steps, state):
-        """Steps on unbatched operands: the stored shapes and
-        macro-transposes ``ops/program.py`` planned, nothing added."""
-        steps, policy = planned_steps
-        if split_complex:
-            _apply_steps_split(
-                jnp, steps, state, precision, policy, interpret
-            )
-        else:
-            _apply_steps(jnp, steps, state)
+            policy = plan_kernel_steps(steps)
+        return slice_body(
+            jnp, steps, sp.slot_slices, slots, split_complex, precision,
+            policy, interpret,
+        )
 
     result_shape = sp.program.stored_result_shape
     result_slot = sp.program.result_slot
@@ -324,25 +265,15 @@ def _compiled_plan(
         (an earlier chunk's ``row_out``), every other slot is whole and
         closed over by the loop."""
         looped = bool(rows)
-        once, rows = planned(once), planned(rows)
+        run_once, run_row = body_of(once, ()), body_of(rows, leaf_in)
 
         def enter(ins):
-            whole = dict(zip(chunk.in_slots, ins))
-            run_steps(once, whole)
-            return whole
+            return run_once(dict(zip(chunk.in_slots, ins)), None)
 
         def one_row(whole, idx1, row_vals):
             state = dict(whole)
             state.update(zip(row_in, row_vals))
-            for slot in leaf_in:  # an array, or its (real, imag) pair
-                state[slot] = jax.tree.map(
-                    lambda part, _info=sp.slot_slices[slot]: index_buffer(
-                        jnp, part, _info, idx1
-                    ),
-                    state[slot],
-                )
-            run_steps(rows, state)
-            return state
+            return run_row(state, idx1)
 
         def scan_rows(body, init, whole, idx):
             xs = (idx, tuple(whole[slot] for slot in row_in))
@@ -688,12 +619,9 @@ def run_sliced_chunked_placed(
         )
 
     # per-slot slice indices, shape [num, n_sliced_legs]
-    dims = sp.slicing.dims
-    all_indices = np.zeros((num, len(dims)), dtype=np.int32)
-    s = np.arange(num)
-    for pos in range(len(dims) - 1, -1, -1):
-        all_indices[:, pos] = s % dims[pos]
-        s //= dims[pos]
+    all_indices = np.stack(
+        slice_indices(sp.slicing.dims, np.arange(num)), axis=1
+    ).astype(np.int32)
 
     import jax
 

@@ -11,8 +11,7 @@ splits the program into:
   inputs, executed exactly once; and
 - a **per-slice residual** — a standard :class:`SlicedProgram` whose
   extra input slots are the prelude's cached intermediates, so every
-  existing sliced executor (numpy oracle, on-device loop, chunked,
-  SPMD) runs it unchanged.
+  sliced executor (numpy oracle, chunked, SPMD) runs it unchanged.
 
 The marking pass is linear in the step count. Replace-path semantics
 guarantee each intermediate value is consumed by exactly one step, so

@@ -6,21 +6,25 @@ with indexing instructions describing, for each input, which axes are
 fixed per slice. Execution sums the program's result over all slice index
 combinations.
 
-TPU mapping: all slices share one compiled program; the JAX backend runs
-the *entire* slice loop on device as a ``lax.fori_loop`` whose body
-indexes the (resident-in-HBM) full inputs, runs the contraction steps,
-and accumulates — no host round-trips between slices.
+This module owns what **one slice** is: :func:`slice_indices` (slice id
+to leg indices), :func:`index_buffer` (pin a leaf's sliced axes) and
+:func:`slice_body` (pin a state's sliced leaves to one row of indices
+and run steps on the unbatched operands; :func:`program_slice_fn` is
+the body over a whole program, by slice id). Every executor builds its
+slices from them — the host loop of :mod:`tnc_tpu.ops.chunked`, the
+on-device loop of :mod:`tnc_tpu.parallel.sliced_parallel`, the
+partitioned executor, the gradient loop and the numpy oracle below —
+so a change to how a slice runs is made once.
 
 Slice-invariant stem hoisting (``hoist=True``): steps whose operands
 depend on no sliced leg are bit-identical across slices. The hoist pass
 (:mod:`tnc_tpu.ops.hoist`) splits the program into an invariant
 **prelude** executed once and a per-slice **residual** program whose
-extra input slots are the prelude's cached intermediates; on device the
-prelude runs before the ``fori_loop``/``scan`` and its outputs stay
-resident in HBM as loop constants. Execution cost drops from
-``num_slices * total_flops`` to ``invariant_flops + num_slices *
-residual_flops``; the slicing planner scores candidate slice sets with
-the same formula (:mod:`tnc_tpu.contractionpath.slicing`).
+extra input slots are the prelude's cached intermediates. Execution
+cost drops from ``num_slices * total_flops`` to ``invariant_flops +
+num_slices * residual_flops``; the slicing planner scores candidate
+slice sets with the same formula
+(:mod:`tnc_tpu.contractionpath.slicing`).
 """
 
 from __future__ import annotations
@@ -40,7 +44,8 @@ from tnc_tpu.ops.program import (
     steps_bytes,
     steps_flops,
 )
-from tnc_tpu.ops.backends import _run_steps, named_jit, run_steps_timed
+from tnc_tpu.ops.backends import apply_steps, run_steps_timed
+from tnc_tpu.ops.split_complex import apply_steps_split
 from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
 
 
@@ -152,11 +157,29 @@ def kahan_add(s, c, x):
     return t, y - (t - s)
 
 
+def slice_indices(dims: Sequence[int], s):
+    """Leg indices of slice id ``s``, one per sliced leg: the
+    mixed-radix digits of ``s`` over ``dims``, last leg fastest. The
+    one rule; ``s`` may be a Python int (host loops), a numpy vector of
+    ids (the chunked executor's index table: each entry is then a
+    vector) or a traced scalar (the on-device loops).
+
+    >>> slice_indices((2, 3), 5), slice_indices((2, 3), 2)
+    ([1, 2], [0, 2])
+    """
+    idx = []
+    for d in reversed(dims):
+        idx.append(s % d)
+        s = s // d
+    idx.reverse()
+    return idx
+
+
 def index_buffer(xp, arr, info, indices):
     """Pin ``arr``'s sliced axes to the given slice ``indices``.
 
     ``info`` is the slot's ``slot_slices`` entry: ((axis, slice_pos), …)
-    ordered by axis. Shared by the on-device loop and chunked executors.
+    ordered by axis.
     """
     view = arr
     offset = 0
@@ -166,14 +189,61 @@ def index_buffer(xp, arr, info, indices):
     return view
 
 
-def _slice_indices(slicing: Slicing, s: int) -> list[int]:
-    """Mixed-radix decomposition of flat slice id ``s``."""
-    idx = []
-    for d in reversed(slicing.dims):
-        idx.append(s % d)
-        s //= d
-    idx.reverse()
-    return idx
+def slice_body(
+    xp,
+    steps,
+    slot_slices,
+    slots: Sequence[int] | None = None,
+    split_complex: bool = False,
+    precision: str | None = None,
+    policy=None,
+    interpret: bool = False,
+):
+    """``body(state, indices) -> state``: what running one slice means.
+
+    ``state`` (a list or dict, slot -> buffer; a (real, imag) pair per
+    slot in split mode) holds full sliced leaves in ``slots`` (default:
+    every slot of ``slot_slices``, for a whole program; a chunk names
+    the leaves that enter it whole). The body pins them to the row
+    ``indices`` (:func:`slice_indices` of the slice id), then runs
+    ``steps`` on the unbatched operands — the stored shapes and
+    macro-transposes :mod:`tnc_tpu.ops.program` planned, nothing added
+    — under ``policy`` (the kernel ladder planned over exactly these
+    ``steps``; split mode). ``state`` is mutated and returned; the
+    caller reads the slots it wants. With ``slots=()`` nothing is
+    pinned: the steps run once on whole slots."""
+    if slots is None:
+        slots = range(len(slot_slices))
+    pinned = tuple((slot, slot_slices[slot]) for slot in slots)
+
+    def pin(buf, info, indices):
+        if isinstance(buf, tuple):
+            return tuple(index_buffer(xp, part, info, indices) for part in buf)
+        return index_buffer(xp, buf, info, indices)
+
+    def body(state, indices):
+        for slot, info in pinned:
+            state[slot] = pin(state[slot], info, indices)
+        if split_complex:
+            apply_steps_split(xp, steps, state, precision, policy, interpret)
+        else:
+            apply_steps(xp, steps, state)
+        return state
+
+    return body
+
+
+def program_slice_fn(xp, sp: SlicedProgram, **body_options):
+    """``fn(full_buffers, s) -> slice s's result`` (stored shape) of a
+    whole sliced program: :func:`slice_body` over all its steps, fed
+    :func:`slice_indices` of the id. ``full_buffers`` is not mutated."""
+    body = slice_body(xp, sp.program.steps, sp.slot_slices, **body_options)
+    dims, result_slot = sp.slicing.dims, sp.program.result_slot
+
+    def fn(full_buffers, s):
+        return body(list(full_buffers), slice_indices(dims, s))[result_slot]
+
+    return fn
 
 
 def execute_sliced_numpy(
@@ -242,6 +312,7 @@ def execute_sliced_numpy(
                     )
             sp = hp.residual
     acc = np.zeros(sp.program.stored_result_shape, dtype=dtype)
+    one_slice = program_slice_fn(np, sp)
     num = sp.slicing.num_slices
     if max_slices is not None:
         num = min(num, max_slices)
@@ -274,12 +345,7 @@ def execute_sliced_numpy(
         with obs.span("sliced.range", lo=lo, hi=hi):
             for s in range(start, hi):
                 _faults.fault_point("sliced.slice", s=s)
-                indices = _slice_indices(sp.slicing, s)
-                buffers = [
-                    index_buffer(np, arr, info, indices)
-                    for arr, info in zip(full, sp.slot_slices)
-                ]
-                acc = acc + _run_steps(np, sp.program, buffers)
+                acc = acc + one_slice(full, s)
                 if mgr is not None:
                     mgr.maybe_save(s + 1, lambda _a=acc: [_a])
                 if on_slice is not None and s + 1 < hi and on_slice(s + 1):
@@ -309,21 +375,20 @@ def execute_sliced_numpy(
     # on by default for the synchronous oracle under tracing — the
     # richest CPU-side calibration sample (obs.calibrate)
     step_timed = obs.enabled() and (step_spans is None or step_spans)
+    pin_only = slice_body(np, (), sp.slot_slices)
+    dims = sp.slicing.dims
     item_bytes = float(np.dtype(dtype).itemsize)
     with obs.span("sliced.residual", executor="numpy") as osp:
         for s in range(start, num):
             _faults.fault_point("sliced.slice", s=s)
-            indices = _slice_indices(sp.slicing, s)
-            buffers = [
-                index_buffer(np, arr, info, indices)
-                for arr, info in zip(full, sp.slot_slices)
-            ]
             if step_timed:
+                # a span per step: pin only (no steps), then the timed walk
                 contrib = run_steps_timed(
-                    np, sp.program, buffers, item_bytes
+                    np, sp.program, pin_only(list(full), slice_indices(dims, s)),
+                    item_bytes,
                 )
             else:
-                contrib = _run_steps(np, sp.program, buffers)
+                contrib = one_slice(full, s)
             acc = acc + contrib
             if mgr is not None:
                 mgr.maybe_save(s + 1, lambda _a=acc: [_a])
@@ -350,20 +415,12 @@ def _par_init(blob):
     import pickle
     import zlib
 
-    _PAR_STATE["sp"], _PAR_STATE["arrays"] = pickle.loads(
-        zlib.decompress(blob)
-    )
+    sp, _PAR_STATE["arrays"] = pickle.loads(zlib.decompress(blob))
+    _PAR_STATE["one_slice"] = program_slice_fn(np, sp)
 
 
 def _par_slice(s: int):
-    sp = _PAR_STATE["sp"]
-    full = _PAR_STATE["arrays"]
-    indices = _slice_indices(sp.slicing, s)
-    buffers = [
-        index_buffer(np, arr, info, indices)
-        for arr, info in zip(full, sp.slot_slices)
-    ]
-    return np.asarray(_run_steps(np, sp.program, buffers))
+    return np.asarray(_PAR_STATE["one_slice"](_PAR_STATE["arrays"], s))
 
 
 def sliced_partials_numpy(
@@ -419,14 +476,8 @@ def sliced_partials_numpy(
         except Exception:  # pool/pickle failure: the serial oracle is law
             parts = None
     if parts is None:
-        parts = []
-        for s in ids:
-            indices = _slice_indices(sp.slicing, s)
-            buffers = [
-                index_buffer(np, arr, info, indices)
-                for arr, info in zip(full, sp.slot_slices)
-            ]
-            parts.append(np.asarray(_run_steps(np, sp.program, buffers)))
+        one_slice = program_slice_fn(np, sp)
+        parts = [np.asarray(one_slice(full, s)) for s in ids]
     shape = (len(ids),) + tuple(sp.program.result_shape)
     return np.stack(parts).reshape(shape)
 
@@ -450,197 +501,3 @@ def execute_sliced_numpy_parallel(
         hoist=hoist,
     )
     return np.sum(parts, axis=0, dtype=dtype)
-
-
-def make_jax_sliced_fn(
-    sp: SlicedProgram,
-    split_complex: bool = False,
-    precision: str | None = None,
-    num_slices: int | None = None,
-    unroll: int = 1,
-    hoist: bool = False,
-    slice_range: tuple[int, int] | None = None,
-    interpret: bool = False,
-):
-    """Build a jittable ``fn(full_buffers) -> result`` running the whole
-    slice loop on device. In split mode, buffers and result are
-    (real, imag) pairs of float arrays. ``num_slices`` caps the loop
-    (partial sum over the first slices — benchmark subset mode).
-
-    ``unroll > 1`` switches ``fori_loop`` for ``lax.scan(..., unroll=)``:
-    XLA pessimizes while-loop bodies (~150× on the v5e north-star,
-    measured in an earlier round), and an unrolled scan presents straight-line
-    step groups instead — zero host dispatches per slice, chunked-class
-    code inside the loop (scan handles any ``num % unroll`` remainder
-    natively). Compile time grows with the unroll factor.
-
-    ``hoist=True`` traces the slice-invariant prelude *before* the loop
-    (:mod:`tnc_tpu.ops.hoist`): its outputs become loop constants — XLA
-    keeps them resident in HBM — and only the residual steps run per
-    iteration.
-    """
-    import jax.numpy as jnp
-    from jax import lax
-
-    hp = None
-    if hoist:
-        from tnc_tpu.ops.hoist import hoist_sliced_program
-
-        cand = hoist_sliced_program(sp)
-        if not cand.is_noop:
-            hp = cand
-    loop_sp = hp.residual if hp is not None else sp
-
-    dims = sp.slicing.dims
-    lo = 0
-    num = sp.slicing.num_slices
-    if slice_range is not None:
-        # contiguous shard [lo, hi) — the multi-host serving shape
-        if num_slices is not None:
-            raise ValueError("slice_range and num_slices are exclusive")
-        lo = max(0, int(slice_range[0]))
-        num = min(int(slice_range[1]), num)
-    elif num_slices is not None:
-        num = max(1, min(num, num_slices))
-    unroll = max(1, min(unroll, max(num - lo, 1)))
-
-    def decompose(s):
-        idx = []
-        for d in reversed(dims):
-            idx.append(s % d)
-            s = s // d
-        idx.reverse()
-        return idx
-
-    if split_complex:
-        from tnc_tpu.ops.split_complex import plan_kernels, run_steps_split
-
-        # the kernel promotion ladder over the per-slice loop body:
-        # residual chains fuse into single Pallas dispatches, eligible
-        # steps promote (the compiled-fn caches key on complex_mult_key,
-        # so forced/auto traces never collide)
-        loop_policy = plan_kernels(loop_sp.program)
-
-        def one_slice(loop_buffers, s):
-            indices = decompose(s)
-            buffers = [
-                (
-                    index_buffer(jnp, re, info, indices),
-                    index_buffer(jnp, im, info, indices),
-                )
-                for (re, im), info in zip(loop_buffers, loop_sp.slot_slices)
-            ]
-            return run_steps_split(
-                jnp, loop_sp.program, buffers, precision, policy=loop_policy,
-                interpret=interpret,
-            )
-
-        def add(acc, contrib):
-            (sr, cr), (si, ci) = acc
-            sr, cr = kahan_add(sr, cr, contrib[0])
-            si, ci = kahan_add(si, ci, contrib[1])
-            return ((sr, cr), (si, ci))
-
-        def zeros(full_buffers):
-            dtype = full_buffers[0][0].dtype
-
-            def z():
-                return jnp.zeros(sp.program.stored_result_shape, dtype=dtype)
-
-            return ((z(), z()), (z(), z()))
-
-        def finish(acc):
-            (sr, cr), (si, ci) = acc
-            return (sr + cr, si + ci)
-
-    else:
-
-        def one_slice(loop_buffers, s):
-            buffers = [
-                index_buffer(jnp, arr, info, decompose(s))
-                for arr, info in zip(loop_buffers, loop_sp.slot_slices)
-            ]
-            return _run_steps(jnp, loop_sp.program, list(buffers))
-
-        def add(acc, contrib):
-            return kahan_add(acc[0], acc[1], contrib)
-
-        def zeros(full_buffers):
-            def z():
-                return jnp.zeros(
-                    sp.program.stored_result_shape, dtype=full_buffers[0].dtype
-                )
-
-            return (z(), z())
-
-        def finish(acc):
-            return acc[0] + acc[1]
-
-    def prepare(full_buffers):
-        """Original buffers → loop buffers (prelude traced pre-loop)."""
-        if hp is None:
-            return full_buffers
-        from tnc_tpu.ops.hoist import run_prelude
-
-        return run_prelude(
-            jnp, hp, list(full_buffers), split_complex, precision, interpret
-        )
-
-    if unroll <= 1:
-
-        def fn(full_buffers):
-            loop_buffers = prepare(full_buffers)
-
-            def body(s, acc):
-                return add(acc, one_slice(loop_buffers, s))
-
-            return finish(lax.fori_loop(lo, num, body, zeros(full_buffers)))
-
-    else:
-
-        def fn(full_buffers):
-            loop_buffers = prepare(full_buffers)
-
-            def body(acc, s):
-                return add(acc, one_slice(loop_buffers, s)), None
-
-            acc, _ = lax.scan(
-                body, zeros(full_buffers), jnp.arange(lo, num), unroll=unroll
-            )
-            return finish(acc)
-
-    jitted = named_jit(fn, "tnc_slice_loop")
-    hoisted = hp is not None
-    # prelude + loop live inside ONE jitted dispatch here, so a single
-    # span covers both; its flop counter is the hoisted total (prelude
-    # once + residual per slice)
-    total_flops = (num - lo) * steps_flops(loop_sp.program.steps)
-    total_elem_bytes = (num - lo) * steps_bytes(loop_sp.program.steps, 1.0)
-    if hp is not None:
-        pre = [ps.step for ps in hp.prelude_steps]
-        total_flops += steps_flops(pre)
-        total_elem_bytes += steps_bytes(pre, 1.0)
-
-    def run(full_buffers, _jitted=jitted):
-        if not obs.enabled():
-            return _jitted(full_buffers)
-        first = full_buffers[0]
-        item = (
-            2.0 * first[0].dtype.itemsize
-            if isinstance(first, tuple)
-            else float(first.dtype.itemsize)
-        )
-        with obs.span(
-            "sliced.loop", hoisted=hoisted, executor="loop"
-        ) as osp:
-            out = _jitted(full_buffers)
-            osp.add(
-                slices=num,
-                flops=total_flops,
-                bytes=total_elem_bytes * item,
-            )
-            return out
-
-    # the bare jax.jit, for lowering on ShapeDtypeStructs
-    run.jitted = jitted
-    return run

@@ -24,11 +24,11 @@ import logging
 import math
 import os
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Sequence
 
 import numpy as np
 
-from tnc_tpu.ops.program import ContractionProgram
+from tnc_tpu.ops.program import ContractionProgram, PairStep
 
 logger = logging.getLogger(__name__)
 
@@ -1086,21 +1086,22 @@ def run_chain_split(xp, steps, buffers, precision=None, precision_mode="",
     return out
 
 
-def run_steps_split(
+def apply_steps_split(
     xp,
-    program: ContractionProgram,
-    buffers: list[tuple[Any, Any] | None],
+    steps: Sequence[PairStep],
+    state,
     precision=None,
     policy: KernelPolicy | None = None,
     interpret: bool = False,
-):
-    """Split-complex analogue of ``backends._run_steps``; ``buffers`` are
-    (real, imag) pairs and the result is a pair in **stored** shape
-    (callers reshape to ``result_shape`` on the host). ``policy`` (a
-    :class:`KernelPolicy`) promotes steps per the kernel ladder; None
-    runs every step under the env mode (``gauss`` default).
+) -> None:
+    """The one walker of split-complex steps: run ``steps`` in order
+    over ``state`` (a list or dict, slot -> (real, imag) pair), in
+    place; a consumed slot is left ``None``. ``policy`` (a
+    :class:`KernelPolicy` planned over exactly these ``steps``, spans
+    indexed relative to them) fuses chains into single Pallas
+    dispatches and promotes steps per the kernel ladder; None runs
+    every step under the env mode (``gauss`` default).
     ``interpret``: see :func:`apply_step_split`."""
-    steps = program.steps
     chain_end = (
         {s: e for s, e in policy.chains} if policy is not None else {}
     )
@@ -1109,21 +1110,38 @@ def run_steps_split(
         end = chain_end.get(i)
         if end is not None:
             run_chain_split(
-                xp, steps[i:end], buffers, precision,
+                xp, steps[i:end], state, precision,
                 precision_mode=policy.precision_mode(i),
                 interpret=interpret,
             )
             i = end
             continue
         step = steps[i]
-        buffers[step.lhs] = apply_step_split(
-            xp, buffers[step.lhs], buffers[step.rhs], step, precision,
+        state[step.lhs] = apply_step_split(
+            xp, state[step.lhs], state[step.rhs], step, precision,
             mode=policy.modes[i] if policy is not None else None,
             precision_mode=(
                 policy.precision_mode(i) if policy is not None else None
             ),
             interpret=interpret,
         )
-        buffers[step.rhs] = None
+        state[step.rhs] = None
         i += 1
+
+
+def run_steps_split(
+    xp,
+    program: ContractionProgram,
+    buffers: list[tuple[Any, Any] | None],
+    precision=None,
+    policy: KernelPolicy | None = None,
+    interpret: bool = False,
+):
+    """Split-complex analogue of ``backends._run_steps``: a whole
+    program through :func:`apply_steps_split`; the result is a
+    (real, imag) pair in **stored** shape (callers reshape to
+    ``result_shape`` on the host)."""
+    apply_steps_split(
+        xp, program.steps, buffers, precision, policy, interpret
+    )
     return buffers[program.result_slot]

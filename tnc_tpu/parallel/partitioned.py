@@ -452,13 +452,10 @@ def local_contract_partitions(
     ``hoist=True`` runs each sliced partition's slice-invariant stem
     once before its slice loop (:mod:`tnc_tpu.ops.hoist`).
 
-    Sliced partitions run through the chunked executor by default (the
-    on-device ``fori_loop`` was measured ~150× slower on a real TPU in
-    an earlier round); each partition's buffers are committed to its
-    device, so the per-partition chunk dispatches execute there and the
-    k local phases still overlap. ``sliced_strategy="loop"`` keeps the
-    single-dispatch loop program (fewer host round-trips — the virtual
-    CPU mesh doesn't pessimize loop bodies).
+    Sliced partitions run through the chunked executor by default;
+    each partition's buffers are committed to its device, so the
+    per-partition chunk dispatches execute there and the k local phases
+    still overlap.
 
     First-run XLA compiles are driven from a thread pool: k distinct
     partition programs would otherwise compile back-to-back on the main
@@ -475,14 +472,14 @@ def local_contract_partitions(
     machinery; the sub-mesh shrinks to the largest size dividing the
     partition's slice count).
     """
-    if sliced_strategy not in ("chunked", "loop", "mesh"):
+    if sliced_strategy not in ("chunked", "mesh"):
         raise ValueError(
             f"unknown sliced_strategy {sliced_strategy!r}; "
-            "expected 'chunked', 'loop', or 'mesh'"
+            "expected 'chunked' or 'mesh'"
         )
     logger.debug("local phase: %d partition programs", len(comm.programs))
     from tnc_tpu.ops.chunked import run_sliced_chunked_placed
-    from tnc_tpu.ops.sliced import SlicedProgram, make_jax_sliced_fn
+    from tnc_tpu.ops.sliced import SlicedProgram
     from tnc_tpu.ops.split_complex import interpret_for
 
     interpret = interpret_for(comm.devices[0])
@@ -515,7 +512,7 @@ def local_contract_partitions(
         submesh = Mesh(_np.asarray(sub[:n]), ("slices",))
         fn = _spmd_fn_cached(
             program, submesh, "slices", dtype, split_complex, precision,
-            1, max_slices, hoist,
+            max_slices, hoist,
         )
 
         def run(bufs, _fn=fn, _own=own):
@@ -543,33 +540,24 @@ def local_contract_partitions(
         if isinstance(program, SlicedProgram):
             if sliced_strategy == "mesh":
                 return _mesh_fn(i, program)
-            if sliced_strategy == "chunked":
-                dev = comm.devices[comm.mapping.device(i)]
+            dev = comm.devices[comm.mapping.device(i)]
 
-                def run(bufs, _sp=program, _dev=dev):
-                    return run_sliced_chunked_placed(
-                        _sp,
-                        bufs,
-                        batch=slice_batch,
-                        chunk_steps=chunk_steps,
-                        split_complex=split_complex,
-                        precision=precision,
-                        dtype=dtype,
-                        device=_dev,
-                        max_slices=max_slices,
-                        hoist=hoist,
-                        interpret=interpret,
-                    )
+            def run(bufs, _sp=program, _dev=dev):
+                return run_sliced_chunked_placed(
+                    _sp,
+                    bufs,
+                    batch=slice_batch,
+                    chunk_steps=chunk_steps,
+                    split_complex=split_complex,
+                    precision=precision,
+                    dtype=dtype,
+                    device=_dev,
+                    max_slices=max_slices,
+                    hoist=hoist,
+                    interpret=interpret,
+                )
 
-                return run
-            return make_jax_sliced_fn(
-                program,
-                split_complex=split_complex,
-                precision=precision,
-                num_slices=max_slices,
-                hoist=hoist,
-                interpret=interpret,
-            )
+            return run
         # its inputs are resident leaves (_place_partition): not donated
         return jit_program(
             program, split_complex, precision, donate=False,
@@ -1033,9 +1021,9 @@ def distributed_partitioned_contraction(
     partitions that exceed it are locally sliced (partitioning × slicing
     composition).
     ``local_sliced_strategy``/``slice_batch``/``chunk_steps`` select the
-    executor for those locally sliced partitions ('chunked' — the fast
-    path on real TPUs — or 'loop', one dispatch per partition, fine on
-    virtual CPU meshes); ``hoist=True`` additionally runs each sliced
+    executor for those locally sliced partitions ('chunked', or 'mesh'
+    when devices beyond the partition count are to share their slices:
+    see :func:`local_contract_partitions`); ``hoist=True`` additionally runs each sliced
     partition's slice-invariant stem once (:mod:`tnc_tpu.ops.hoist`).
 
     ``communication_scheme`` (a :class:`~tnc_tpu.contractionpath.
@@ -1276,18 +1264,13 @@ def partitioned_sliced_executor(
     import jax
     import jax.numpy as jnp
 
-    from tnc_tpu.ops.backends import _run_steps
     from tnc_tpu.ops.budget import device_hbm_bytes
     from tnc_tpu.ops.sliced import (
-        _slice_indices,
         build_sliced_program,
-        index_buffer,
+        slice_body,
+        slice_indices,
     )
-    from tnc_tpu.ops.split_complex import (
-        interpret_for,
-        plan_kernels,
-        run_steps_split,
-    )
+    from tnc_tpu.ops.split_complex import interpret_for, plan_kernels
 
     if devices is None:
         devices = jax.devices()
@@ -1333,26 +1316,17 @@ def partitioned_sliced_executor(
     ]
 
     def make_local_fn(sp):
-        def fn(bufs, indices):
-            if split_complex:
-                sliced = [
-                    (
-                        index_buffer(jnp, re, info, indices),
-                        index_buffer(jnp, im, info, indices),
-                    )
-                    for (re, im), info in zip(bufs, sp.slot_slices)
-                ]
-                return run_steps_split(
-                    jnp, sp.program, sliced, precision,
-                    policy=plan_kernels(sp.program), interpret=interpret,
-                )
-            sliced = [
-                index_buffer(jnp, arr, info, indices)
-                for arr, info in zip(bufs, sp.slot_slices)
+        body = slice_body(
+            jnp, sp.program.steps, sp.slot_slices,
+            split_complex=split_complex, precision=precision,
+            policy=plan_kernels(sp.program) if split_complex else None,
+            interpret=interpret,
+        )
+        return jax.jit(
+            lambda bufs, indices: body(list(bufs), indices)[
+                sp.program.result_slot
             ]
-            return _run_steps(jnp, sp.program, list(sliced))
-
-        return jax.jit(fn)
+        )
 
     local_fns = [make_local_fn(sp) for sp in sps]
 
@@ -1430,7 +1404,7 @@ def partitioned_sliced_executor(
         for s in range(num):
             # host (uncommitted) indices: each jit transfers them to its
             # own partition's device
-            indices = np.asarray(_slice_indices(slicing, s), dtype=np.int32)
+            indices = np.asarray(slice_indices(slicing.dims, s), dtype=np.int32)
             held = [
                 fn(bufs, indices) for fn, bufs in zip(local_fns, buffers)
             ]  # async: all devices work concurrently

@@ -24,11 +24,15 @@ logger = logging.getLogger(__name__)
 from tnc_tpu import obs
 from tnc_tpu.contractionpath.contraction_path import ContractionPath
 from tnc_tpu.contractionpath.slicing import Slicing
-from tnc_tpu.ops.backends import _run_steps, named_jit, place_buffers
+from tnc_tpu.ops.backends import named_jit, place_buffers
 from tnc_tpu.resilience import faultinject as _faults
 from tnc_tpu.resilience import retry as _retry
 from tnc_tpu.ops.program import flat_leaf_tensors
-from tnc_tpu.ops.sliced import SlicedProgram, build_sliced_program
+from tnc_tpu.ops.sliced import (
+    SlicedProgram,
+    build_sliced_program,
+    program_slice_fn,
+)
 from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
 from tnc_tpu.tensornetwork.tensordata import TensorData
 
@@ -69,17 +73,14 @@ def _make_spmd_fn(
     dtype,
     split_complex: bool,
     precision: str | None = "float32",
-    unroll: int = 1,
     max_slices: int | None = None,
     hoist: bool = False,
 ):
     """fn(full_buffers) replicated over the mesh; each device sums its
-    slice chunk, then one psum over the mesh axis.
-
-    ``unroll > 1`` runs each device's chunk as ``lax.scan(unroll=)``
-    over its slice ids instead of a ``fori_loop`` — on real TPUs XLA
-    pessimizes while-loop bodies (~150×, measured in an earlier round),
-    and the unrolled scan presents straight-line step groups.
+    slice chunk in a ``fori_loop`` over the per-slice body
+    (:func:`tnc_tpu.ops.sliced.slice_body`), then one psum over the
+    mesh axis. On a mesh of one device this is the whole slice loop in
+    one program.
 
     ``max_slices`` probe subsets: each device's chunk shrinks to
     ``ceil(max_slices / n_devices)`` and device ``d`` covers slice ids
@@ -115,72 +116,26 @@ def _make_spmd_fn(
             hp = cand
     loop_sp = hp.residual if hp is not None else sp
 
-    from tnc_tpu.ops.split_complex import interpret_for
+    from tnc_tpu.ops.split_complex import interpret_for, plan_kernels
 
     # Pallas interpret mode follows the mesh's devices, not the process
     interpret = interpret_for(mesh.devices.flat[0])
-    dims = sp.slicing.dims
+    one_slice = program_slice_fn(
+        jnp, loop_sp, split_complex=split_complex, precision=precision,
+        # the kernel ladder, planned over the whole residual
+        policy=plan_kernels(loop_sp.program) if split_complex else None,
+        interpret=interpret,
+    )
     part_dtype = "float64" if "128" in str(dtype) else "float32"
 
-    def decompose(s):
-        idx = []
-        for d in reversed(dims):
-            idx.append(s % d)
-            s = s // d
-        idx.reverse()
-        return idx
-
-    def index_buffer(arr, info, indices):
-        view = arr
-        offset = 0
-        for ax, pos in info:
-            view = jnp.take(view, indices[pos], axis=ax - offset)
-            offset += 1
-        return view
-
-    if split_complex:
-        from tnc_tpu.ops.split_complex import plan_kernels, run_steps_split
-
-        loop_policy = plan_kernels(loop_sp.program)  # kernel ladder
-
-        def one_slice(loop_buffers, s):
-            indices = decompose(s)
-            buffers = [
-                (
-                    index_buffer(re, info, indices),
-                    index_buffer(im, info, indices),
-                )
-                for (re, im), info in zip(loop_buffers, loop_sp.slot_slices)
-            ]
-            return run_steps_split(
-                jnp, loop_sp.program, buffers, precision, policy=loop_policy,
-                interpret=interpret,
+    def zeros():
+        def z():
+            return jnp.zeros(
+                sp.program.stored_result_shape,
+                dtype=part_dtype if split_complex else dtype,
             )
 
-        def add(acc, contrib):
-            return acc[0] + contrib[0], acc[1] + contrib[1]
-
-        def zeros():
-            return (
-                jnp.zeros(sp.program.stored_result_shape, dtype=part_dtype),
-                jnp.zeros(sp.program.stored_result_shape, dtype=part_dtype),
-            )
-
-    else:
-
-        def one_slice(loop_buffers, s):
-            indices = decompose(s)
-            buffers = [
-                index_buffer(arr, info, indices)
-                for arr, info in zip(loop_buffers, loop_sp.slot_slices)
-            ]
-            return _run_steps(jnp, loop_sp.program, list(buffers))
-
-        def add(acc, contrib):
-            return acc + contrib
-
-        def zeros():
-            return jnp.zeros(sp.program.stored_result_shape, dtype=dtype)
+        return (z(), z()) if split_complex else z()
 
     def device_fn(*full_buffers):
         my = lax.axis_index(axis)
@@ -195,20 +150,13 @@ def _make_spmd_fn(
             )
         else:
             loop_buffers = full_buffers
-        if unroll > 1:
 
-            def body(acc, k):
-                return add(acc, one_slice(loop_buffers, my * chunk + k)), None
-
-            partial, _ = lax.scan(
-                body, zeros(), jnp.arange(chunk), unroll=min(unroll, chunk)
+        def add_slice(k, acc):
+            return jax.tree.map(
+                jnp.add, acc, one_slice(loop_buffers, my * chunk + k)
             )
-        else:
 
-            def body(k, acc):
-                return add(acc, one_slice(loop_buffers, my * chunk + k))
-
-            partial = lax.fori_loop(0, chunk, body, zeros())
+        partial = lax.fori_loop(0, chunk, add_slice, zeros())
         return lax.psum(partial, axis)
 
     in_specs = tuple(P() for _ in range(sp.program.num_inputs))  # replicated
@@ -229,7 +177,7 @@ _SPMD_FN_CACHE: dict = {}
 _SPMD_FN_CACHE_MAX = 64
 
 
-def _spmd_fn_cached(sp, mesh, axis, dtype, split_complex, precision, unroll,
+def _spmd_fn_cached(sp, mesh, axis, dtype, split_complex, precision,
                     max_slices, hoist=False):
     from tnc_tpu.ops.split_complex import complex_mult_key, dot_precision_key
 
@@ -237,7 +185,7 @@ def _spmd_fn_cached(sp, mesh, axis, dtype, split_complex, precision, unroll,
     chunk = _effective_chunk(sp.slicing.num_slices, n_devices, max_slices)
     key = (
         sp.signature(), tuple(mesh.devices.flat), axis, str(dtype),
-        split_complex, precision, unroll, chunk, hoist,
+        split_complex, precision, chunk, hoist,
         # the split trace bakes in the kernel policy/env mode — a stale
         # fn under a flipped TNC_TPU_COMPLEX_MULT (or a flipped
         # TNC_TPU_DOT_PRECISION rung) would silently run the wrong
@@ -250,8 +198,8 @@ def _spmd_fn_cached(sp, mesh, axis, dtype, split_complex, precision, unroll,
                     "spmd_fn_cache.miss")
     if fn is None:
         fn = _make_spmd_fn(
-            sp, mesh, axis, dtype, split_complex, precision, unroll,
-            max_slices, hoist,
+            sp, mesh, axis, dtype, split_complex, precision, max_slices,
+            hoist,
         )
         _SPMD_FN_CACHE[key] = fn
         while len(_SPMD_FN_CACHE) > _SPMD_FN_CACHE_MAX:
@@ -269,7 +217,6 @@ def distributed_sliced_contraction(
     axis: str = "slices",
     split_complex: bool | None = None,
     precision: str | None = "float32",
-    unroll: int = 1,
     max_slices: int | None = None,
     hoist: bool = False,
 ) -> LeafTensor:
@@ -337,8 +284,8 @@ def distributed_sliced_contraction(
                 split_complex,
             )
             fn = _spmd_fn_cached(
-                sp, mesh, axis, dtype, split_complex, precision, unroll,
-                max_slices, hoist,
+                sp, mesh, axis, dtype, split_complex, precision, max_slices,
+                hoist,
             )
             # the SAME effective-hoist decision _make_spmd_fn takes (the
             # pass is lru-cached, so this re-derivation is a dict hit),

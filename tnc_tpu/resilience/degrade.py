@@ -11,10 +11,6 @@ cheapest to most invasive:
 2. **Finer slicing** — handled here: re-plan through the existing
    planner hook (:func:`~tnc_tpu.contractionpath.slicing.slice_and_reconfigure`)
    at a 4× smaller element target, rebuild the sliced program, re-run.
-3. **Chunked host-loop fallback** — if the backend was using the
-   single-dispatch on-device loop (``sliced_strategy="loop"``), fall
-   back to the chunked host-loop executor at batch 1, the
-   smallest-footprint executor in the stack.
 
 Every rung is visible through obs (``resilience.ladder.*`` counters and
 gauges, plus the warning log), so a production run that survived an OOM
@@ -109,29 +105,6 @@ def execute_sliced_resilient(
                 if classify_exception(exc) is not FailureClass.RESOURCE:
                     raise
                 if replans >= max_replans:
-                    if getattr(backend, "sliced_strategy", None) == "loop":
-                        # final rung: chunked host loop, batch 1 — the
-                        # smallest-footprint executor available
-                        logger.warning(
-                            "degradation ladder: falling back to the "
-                            "chunked host-loop executor at batch 1"
-                        )
-                        obs.counter_add("resilience.ladder.fallback_chunked")
-                        fb = JaxBackend(
-                            dtype=backend.dtype,
-                            device=backend.device,
-                            split_complex=backend.split_complex,
-                            precision=backend.precision,
-                            sliced_strategy="chunked",
-                            slice_batch=1,
-                            chunk_steps=backend.chunk_steps,
-                            hoist=backend.hoist,
-                        )
-                        out = fb.execute_sliced(
-                            sp, arrays, max_slices=max_slices, host=host
-                        )
-                        osp.set(replans=replans, fallback="chunked")
-                        return out, sp.slicing
                     raise
                 # rung 2: re-slice finer through the planner hook
                 replans += 1
