@@ -283,11 +283,16 @@ def kernel_mode_mix(hp) -> dict:
     chunked executor plans per chunk) and the hoisted prelude steps."""
     from collections import Counter
 
-    from tnc_tpu.ops.split_complex import auto_step_mode, plan_kernels
+    from tnc_tpu.ops.split_complex import (
+        auto_step_mode,
+        plan_kernels,
+        resolved_step_mode,
+    )
 
     policy = plan_kernels(hp.residual.program)
     prelude = Counter(
-        auto_step_mode(ps.step) or "gauss" for ps in hp.prelude_steps
+        auto_step_mode(ps.step) or resolved_step_mode(ps.step)
+        for ps in hp.prelude_steps
     )
     return {
         "residual": dict(Counter(policy.modes)),

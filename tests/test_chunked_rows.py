@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 
 from tnc_tpu import obs
-from tnc_tpu.obs.core import MetricsRegistry
 from tnc_tpu.ops.chunked import (
     _compiled_plan,
     _prelude_fn,
@@ -31,40 +30,6 @@ from tnc_tpu.ops.sliced import (
 )
 from tnc_tpu.parallel.sliced_parallel import _make_spmd_fn, make_mesh
 from tnc_tpu.resilience import faultinject as fi
-
-
-@pytest.fixture(scope="module")
-def sycamore20():
-    """A 20-qubit depth-8 Sycamore-layout amplitude network sliced to
-    2^10 elements: 256 slices, a hoisted prelude of 7 steps and a
-    residual of 36."""
-    from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
-    from tnc_tpu.contractionpath.contraction_path import ContractionPath
-    from tnc_tpu.contractionpath.paths import Greedy, OptMethod
-    from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
-    from tnc_tpu.ops.program import flat_leaf_tensors
-    from tnc_tpu.tensornetwork.simplify import simplify_network
-
-    raw, _ = sycamore_circuit(
-        20, 8, np.random.default_rng(42)
-    ).into_amplitude_network("0" * 20)
-    tn = simplify_network(raw)
-    result = Greedy(OptMethod.GREEDY).find_path(tn)
-    pairs, slicing = slice_and_reconfigure(
-        list(tn.tensors), result.ssa_path.toplevel, 2.0**10
-    )
-    sp = build_sliced_program(tn, ContractionPath.simple(pairs), slicing)
-    assert slicing.num_slices == 256
-    assert not hoist_sliced_program(sp).is_noop
-    arrays = [leaf.data.into_data() for leaf in flat_leaf_tensors(tn)]
-    return sp, arrays
-
-
-@pytest.fixture
-def registry():
-    obs.configure(enabled=True, registry=MetricsRegistry())
-    yield obs.get_registry()
-    obs.configure(enabled=False, registry=MetricsRegistry())
 
 
 def _oracle(sp, arrays, lo, hi):
@@ -287,7 +252,8 @@ def test_residual_programs_lower_to_the_loop_body(sycamore20, chunk_steps):
     """Every step of a ``jit_tnc_residual_*`` program is the unbatched
     step the SPMD slice loop's body runs (the two slice cells' programs):
     the same ``dot_general``s on the same operand shapes, none with a
-    batch dimension, and as many ``transpose``s a slice."""
+    batch dimension, and as many ``transpose``s a slice, but for the
+    values a chunk hands to the next."""
     import jax
     import jax.numpy as jnp
 
@@ -307,7 +273,8 @@ def test_residual_programs_lower_to_the_loop_body(sycamore20, chunk_steps):
     # the loop program traces the prelude before its loop
     body_dots = _dots(loop_text) - _dots(prelude_text)
     body_transposes = _transposes(loop_text) - _transposes(prelude_text)
-    assert sum(body_dots.values()) >= 3 * len(hp.residual.program.steps)
+    # one real dot a step in the block form, three under gauss
+    assert sum(body_dots.values()) >= len(hp.residual.program.steps)
 
     chunks, chunk_fns, row_modes = _compiled_plan(
         hp.residual, 8, chunk_steps, True, "float32"
@@ -336,8 +303,16 @@ def test_residual_programs_lower_to_the_loop_body(sycamore20, chunk_steps):
         assert "stablehlo.while" in text
         dots += _dots(text)
         transposes += _transposes(text)
-    assert dots == body_dots
-    assert transposes == body_transposes
+    # a value a block step carries to the next is one array: permuted
+    # once, never cut. Handed to the next chunk it leaves as a pair (a
+    # dot a half where it outweighs its operands) and each plane is
+    # permuted on its own
+    handed = sum(len(chunk.out_slots) for chunk in chunks[:-1])
+    assert sum((body_dots - dots).values()) <= handed
+    assert sum((dots - body_dots).values()) <= 2 * handed
+    assert 0 <= transposes - body_transposes <= handed
+    if len(chunks) == 1:
+        assert dots == body_dots and transposes == body_transposes
     top = max(_rank(t) for ops, _ in body_dots for t in ops.split(", "))
     assert all(
         _rank(t) <= top for ops, _ in dots for t in ops.split(", ")

@@ -97,12 +97,12 @@ def test_random_network_sliced_consistency(seed):
     assert float(np.max(np.abs(ga - wa))) / denom < 1e-10, seed
 
 
-@pytest.mark.parametrize("mode", ["gauss", "naive"])
+@pytest.mark.parametrize("mode", ["gauss", "naive", "block"])
 @pytest.mark.parametrize("seed", range(4))
 def test_random_network_split_complex_mult_modes(seed, mode, monkeypatch):
-    """Fuzz both complex-multiply lowerings (split-complex f32) against
-    the complex128 oracle on random networks — the naive 4-dot mode is
-    the benchmark default."""
+    """Fuzz the complex-multiply lowerings (split-complex f32: three
+    dots, four, and the one dot of the block form) against the
+    complex128 oracle on random networks."""
     from tnc_tpu.ops.backends import JaxBackend
     from tnc_tpu.ops.program import build_program, flat_leaf_tensors
 

@@ -140,14 +140,17 @@ def test_forced_modes_are_uniform(monkeypatch):
 
 
 def test_env_override_forces(monkeypatch):
-    from tnc_tpu.ops.split_complex import plan_kernels
+    from tnc_tpu.ops.split_complex import default_step_mode, plan_kernels
 
     program, _ = _program()
     monkeypatch.setenv("TNC_TPU_COMPLEX_MULT", "naive")
     assert set(plan_kernels(program).modes) == {"naive"}
     monkeypatch.setenv("TNC_TPU_COMPLEX_MULT", "auto")
     policy = plan_kernels(program)
-    assert "gauss" in policy.modes  # the ladder's base mode
+    # the ladder's base: each step as its shape decides
+    assert policy.modes == tuple(
+        default_step_mode(st) for st in program.steps
+    )
 
 
 def _stem_program(shared=8, free=7, seed=3, scale=32.0):

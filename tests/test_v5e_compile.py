@@ -73,6 +73,28 @@ def _on(sharding, tree):
     )
 
 
+def _residual_inputs(hp, shapes, sharding):
+    """The residual program's inputs as the executors hand them over:
+    whole leaves (sliced legs and all) and the prelude's cached
+    intermediates, (real, imag) pairs on ``sharding``."""
+    from tnc_tpu.ops.chunked import _prelude_fn
+
+    full = _pair_specs(shapes, sharding)
+    pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
+    cached = iter(
+        _on(
+            sharding,
+            jax.eval_shape(
+                _prelude_fn(hp, True, "float32", interpret=False), pins
+            ),
+        )
+    )
+    return [
+        full[ref] if kind == "leaf" else next(cached)
+        for kind, ref in hp.residual_sources
+    ]
+
+
 def _total_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return int(
@@ -99,17 +121,14 @@ def chain_bearing():
     return program, shapes
 
 
-@pytest.fixture(scope="module")
-def northstar():
-    """Sycamore-53 m=14 at the 2^29 slice target, planned by the
-    cheapest planner that reaches the target (the tests are of shapes
-    at the target, not of plan quality): the hoisted split, the leaf
-    shapes and the budget-clamped slice batch the executor would run."""
+def _sycamore53_hoisted(target: float):
+    """Sycamore-53 m=14 sliced to ``target`` elements, planned by the
+    cheapest planner that reaches it (the tests are of shapes at the
+    target, not of plan quality): ``(sp, hp, leaf shapes)``."""
     from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
     from tnc_tpu.contractionpath.contraction_path import ContractionPath
     from tnc_tpu.contractionpath.paths import Greedy, OptMethod
     from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
-    from tnc_tpu.ops.budget import clamp_slice_batch
     from tnc_tpu.ops.hoist import hoist_sliced_program
     from tnc_tpu.ops.program import flat_leaf_tensors
     from tnc_tpu.ops.sliced import build_sliced_program
@@ -121,16 +140,27 @@ def northstar():
     tn = simplify_network(raw)
     result = Greedy(OptMethod.GREEDY).find_path(tn)
     pairs, slicing = slice_and_reconfigure(
-        list(tn.tensors), result.ssa_path.toplevel, 2.0**29
+        list(tn.tensors), result.ssa_path.toplevel, target
     )
     sp = build_sliced_program(tn, ContractionPath.simple(pairs), slicing)
     hp = hoist_sliced_program(sp)
     assert not hp.is_noop
     shapes = [leaf.data.into_data().shape for leaf in flat_leaf_tensors(tn)]
+    return sp, hp, shapes
+
+
+@pytest.fixture(scope="module")
+def northstar():
+    """Sycamore-53 m=14 at the 2^29 slice target: the hoisted split, the
+    leaf shapes and the budget-clamped slice batch the executor would
+    run."""
+    from tnc_tpu.ops.budget import clamp_slice_batch
+
+    sp, hp, shapes = _sycamore53_hoisted(2.0**29)
     batch = clamp_slice_batch(
         hp.residual.program, 8, hbm_bytes=V5E_HBM_BYTES
     )
-    while slicing.num_slices % batch:
+    while sp.slicing.num_slices % batch:
         batch -= 1
     return sp, hp, shapes, batch
 
@@ -239,7 +269,7 @@ def test_northstar_chunk_compiles_within_hbm(
     batch, against 16 GB. Its rows run one after another: the compiled
     program holds no ``dot_general`` with a batch dimension."""
     from tnc_tpu.ops.budget import program_peak_bytes
-    from tnc_tpu.ops.chunked import _compiled_plan, _prelude_fn
+    from tnc_tpu.ops.chunked import _compiled_plan
 
     sp, hp, shapes, batch = northstar
     residual = hp.residual
@@ -250,20 +280,7 @@ def test_northstar_chunk_compiles_within_hbm(
     assert program_peak_bytes(residual.program).peak_step < len(
         chunks[0].steps
     )
-    full = _pair_specs(shapes, one_chip)
-    pins = tuple(full[orig] for _, orig in hp.prelude_inputs)
-    cached = iter(
-        _on(
-            one_chip,
-            jax.eval_shape(
-                _prelude_fn(hp, True, "float32", interpret=False), pins
-            ),
-        )
-    )
-    inputs = [
-        full[ref] if kind == "leaf" else next(cached)
-        for kind, ref in hp.residual_sources
-    ]
+    inputs = _residual_inputs(hp, shapes, one_chip)
     ins = tuple(inputs[slot] for slot in chunks[0].in_slots)
     idx = jax.ShapeDtypeStruct(
         (batch, len(sp.slicing.dims)), jnp.int32, sharding=one_chip
@@ -272,6 +289,38 @@ def test_northstar_chunk_compiles_within_hbm(
     assert "batching_dims = [0]" not in lowered.as_text()
     compiled = lowered.compile()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_block_rule_moves_fewer_bytes_than_gauss(one_chip):
+    """Why the rule: one slice of a Sycamore-53 m=14 residual (Greedy,
+    sliced to 2^25: contractions of 2 to 64 on all but a few steps)
+    compiles under the default rule, and XLA counts fewer bytes accessed
+    for it than for the same program with every step forced to
+    ``gauss`` (a compile fact: counts, not speeds)."""
+    from tnc_tpu.ops.sliced import program_slice_fn
+    from tnc_tpu.ops.split_complex import plan_kernels
+
+    _, hp, shapes = _sycamore53_hoisted(2.0**25)
+    program = hp.residual.program
+    ruled = plan_kernels(program)
+    assert set(ruled.modes) <= {"block", "gauss"}
+    assert ruled.modes.count("block") > 0.9 * len(program.steps)
+    inputs = _residual_inputs(hp, shapes, one_chip)
+    slice_id = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def bytes_accessed(policy):
+        one_slice = program_slice_fn(
+            jnp, hp.residual, split_complex=True, precision="float32",
+            policy=policy, interpret=False,
+        )
+        cost = jax.jit(one_slice).lower(inputs, slice_id).compile(
+        ).cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        return float(cost["bytes accessed"])
+
+    rule = bytes_accessed(ruled)
+    gauss = bytes_accessed(plan_kernels(program, force="gauss"))
+    assert rule < 0.95 * gauss, (rule, gauss)
 
 
 def test_fused_complex_dot_compiles(one_chip):

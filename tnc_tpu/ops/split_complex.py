@@ -2,14 +2,27 @@
 
 The TPU's MXU is a real-arithmetic systolic array, and this stack exposes
 no complex dtypes at all — so the TPU path represents every tensor as two
-float32 arrays and lowers each pairwise contraction to **three** real
-matmuls via the Gauss/Karatsuba identity (25% fewer flops than the naive
-four):
+float32 arrays and lowers each pairwise contraction to real matmuls. The
+lowering is read from the step's shape (:func:`default_step_mode`):
 
-    k1 = (ar + ai) @ br
-    k2 = ar @ (bi - br)
-    k3 = ai @ (br + bi)
-    real = k1 - k3,  imag = k1 + k2
+- a step whose contraction is short (``2k <= 128``: all but a few steps
+  of a network of extent-2 legs) is bound by memory, and runs as **one**
+  real dot with the contraction twice as long — the ``block`` form, the
+  streamed operand's planes joined along ``k`` against the small
+  operand's 2 x 2 block::
+
+      [ar; ai]^T (2k x m) @ [[br, bi], [-bi, br]] (2k x 2n) = [re, im]
+
+  The large operand is read once and the result written once; nothing
+  is added or subtracted outside the dot.
+- a longer contraction is bound by the MXU, and runs as **three** real
+  matmuls via the Gauss/Karatsuba identity (25% fewer flops than the
+  naive four)::
+
+      k1 = (ar + ai) @ br
+      k2 = ar @ (bi - br)
+      k3 = ai @ (br + bi)
+      real = k1 - k3,  imag = k1 + k2
 
 This is the "split real/imag representation" contingency the survey
 flagged for TPU complex support (SURVEY.md §7 hard parts), promoted to
@@ -36,8 +49,15 @@ logger = logging.getLogger(__name__)
 #: per-step mode — chained steps run ``naive`` arithmetic inside one
 #: fused multi-step dispatch (see :class:`KernelPolicy`).
 KERNEL_MODES = (
-    "naive", "gauss", "fused", "fused_transpose", "strassen", "chain", "auto",
+    "naive", "gauss", "block", "fused", "fused_transpose", "strassen",
+    "chain", "auto",
 )
+
+#: longest joined contraction (``2k``) a step runs in the ``block``
+#: form: one pass of the MXU's 128-deep tile, where the fourth multiply
+#: rides in padding the three-dot form wastes. Above it a step is bound
+#: by the MXU and gauss's three multiplies beat four.
+BLOCK_MAX_CONTRACT = 128
 
 #: real-multiply credit of each kernel mode relative to the naive
 #: 4-dot complex lowering (the unit every flop count in the stack
@@ -47,6 +67,7 @@ KERNEL_MODES = (
 #: across kernel modes (effective-flop crediting).
 EFFECTIVE_FLOP_FACTOR = {
     "naive": 1.0,
+    "block": 1.0,  # naive arithmetic in one real dot
     "fused": 1.0,  # naive arithmetic, fewer HBM passes
     "fused_transpose": 1.0,  # naive arithmetic, no transpose HBM pass
     "gauss": 0.75,
@@ -74,21 +95,28 @@ DOT_PRECISION_MODES = ("highest", "high")
 HIGH_PRECISION_STEP_REL = 2.0 ** -18
 
 
-def complex_mult_env() -> str:
-    """The per-step complex-multiply base mode, read at *trace* time
-    (so compiled executables must be keyed by it, like
-    ``backends.lanemix_env``). **``gauss`` is the single tuned
-    default** — everywhere: here, in ``bench.py``'s seeding, and as
-    the :class:`KernelPolicy` base mode (the parity ladder pins it).
-    Setting ``TNC_TPU_COMPLEX_MULT`` is a *forcing override* for A/B
-    runs — it pins every step to one mode and disables the per-step
-    promotion ladder (see :func:`plan_kernels`):
+def complex_mult_forced() -> str | None:
+    """The ``TNC_TPU_COMPLEX_MULT`` forcing override, read at *trace*
+    time (so compiled executables must be keyed by it, like
+    ``backends.lanemix_env``), or ``None`` when the knob is unset or
+    ``auto``: the step's shape then decides (:func:`default_step_mode`)
+    and the promotion ladder may promote (see :func:`plan_kernels`).
+    Set, it pins every step to one mode for A/B runs and disables the
+    ladder:
 
-    - ``gauss`` (default): 3 real dots via the Gauss/Karatsuba identity —
+    - ``block``: 1 real dot with the contraction twice as long — the
+      streamed operand's planes joined along ``k`` against the other
+      operand's 2 x 2 block ``[[br, bi], [-bi, br]]``. The large
+      operand is read once, the result written once, nothing is added
+      outside the dot; the small operand is expanded to four times its
+      size. The arithmetic of ``naive`` (rr-ii, ri+ir), accumulated
+      inside the dot. What an unforced step of ``2k <= 128`` runs.
+    - ``gauss``: 3 real dots via the Gauss/Karatsuba identity —
       25% fewer MXU flops, but the pre-dot operand sums (ar+ai, bi-br,
-      br+bi) are extra full-operand HBM passes AND mix magnitudes, so
-      rounding error is relative to the *larger* mixed intermediate
-      (the classic Karatsuba instability).
+      br+bi) and the result combines are extra full-operand HBM passes
+      AND mix magnitudes, so rounding error is relative to the *larger*
+      mixed intermediate (the classic Karatsuba instability). What an
+      unforced step of ``2k > 128`` runs.
     - ``naive``: 4 real dots (rr-ii, ri+ir) — each dot's error is
       relative to its own product magnitude (the half-digit-tighter
       rung of the parity ladder).
@@ -105,28 +133,34 @@ def complex_mult_env() -> str:
       :func:`tnc_tpu.ops.program.chain_groups` execute as ONE fused
       multi-step Pallas dispatch (naive arithmetic); ungrouped steps
       run ``gauss``.
-    - ``auto``: the explicit spelling of the unforced default — the
-      cost-model-driven promotion ladder.
+    - ``auto``: the explicit spelling of the unforced default, so NOT
+      a forced mode.
     """
-    return os.environ.get("TNC_TPU_COMPLEX_MULT", "gauss")
-
-
-def complex_mult_forced() -> str | None:
-    """The forcing override, or ``None`` when the env knob is unset
-    (the promotion ladder decides per step). ``auto`` explicitly
-    requests the ladder, so it is NOT a forced mode."""
     mode = os.environ.get("TNC_TPU_COMPLEX_MULT")
     if mode is None or mode == "auto":
         return None
     return mode
 
 
+def default_step_mode(step) -> str:
+    """The lowering of a step nothing promoted, read from its shape:
+    ``block`` where the joined contraction fits one pass of the MXU
+    (``2k <=`` :data:`BLOCK_MAX_CONTRACT` — the step is bound by
+    memory, and one dot moves the fewest bytes), ``gauss`` above (bound
+    by the MXU: three multiplies beat four). The one rule under every
+    executor: :func:`plan_kernel_steps`' fall-through and
+    :func:`apply_step_split` without a policy."""
+    from tnc_tpu.ops.program import step_dims
+
+    _, k, _ = step_dims(step)
+    return "block" if 2 * k <= BLOCK_MAX_CONTRACT else "gauss"
+
+
 def complex_mult_key() -> str:
     """Trace-time *cache-key* form of the env knob: the forced mode, or
-    ``auto`` when unset. Distinct from :func:`complex_mult_env` because
-    an unset env lets the promotion ladder promote steps (prelude stem
-    GEMMs → strassen), so it must NOT share compiled executables with
-    an explicitly forced ``gauss``."""
+    ``auto`` when unset. An unset env lets the step's shape and the
+    promotion ladder decide (prelude stem GEMMs → strassen), so it must
+    NOT share compiled executables with an explicitly forced mode."""
     return os.environ.get("TNC_TPU_COMPLEX_MULT", "auto")
 
 
@@ -164,7 +198,7 @@ def auto_step_mode(step) -> str | None:
     :class:`KernelPolicy` plan (the hoisted prelude, whose stem GEMMs
     are exactly the Strassen regime): ``strassen`` when the step clears
     the crossover and no forcing override is set; ``None`` defers to
-    the env default.
+    the forcing override, then :func:`default_step_mode`.
 
     Eligibility-gated only — unlike the full ladder this does NOT
     consult ``_strassen_saving_s``: the prelude executes inside traced
@@ -182,12 +216,14 @@ def auto_step_mode(step) -> str | None:
 def resolved_step_mode(step, mode: str | None = None) -> str:
     """The arithmetic :func:`apply_step_split` actually runs for a
     requested mode — the env/policy name folded through the per-step
-    fallbacks (``strassen`` below the crossover → gauss; ``chain`` /
-    ``auto`` outside a policy → gauss; unknown → gauss). The flop-
-    crediting rule (:data:`EFFECTIVE_FLOP_FACTOR`) must be looked up
-    on THIS name, never the raw request."""
+    fallbacks (none → the forcing override, else the step's shape
+    decides: :func:`default_step_mode`; ``strassen`` below the
+    crossover → gauss; ``chain`` / ``auto`` outside a policy → gauss;
+    unknown → gauss). The flop-crediting rule
+    (:data:`EFFECTIVE_FLOP_FACTOR`) must be looked up on THIS name,
+    never the raw request."""
     if mode is None:
-        mode = complex_mult_env()
+        mode = complex_mult_forced() or default_step_mode(step)
     if mode == "strassen":
         return "strassen" if _strassen_step_eligible(step) else "gauss"
     if mode == "fused_transpose":
@@ -197,7 +233,7 @@ def resolved_step_mode(step, mode: str | None = None) -> str:
             if fused_transpose_ineligible_reason(step) is None
             else "naive"
         )
-    if mode in ("naive", "fused"):
+    if mode in ("naive", "fused", "block"):
         return mode
     return "gauss"
 
@@ -310,13 +346,14 @@ def _strassen_step(xp, ar, ai, br, bi, step, precision):
 
 def apply_step_split(
     xp, apair, bpair, step, precision=None, mode=None, precision_mode=None,
-    interpret: bool = False,
+    interpret: bool = False, carry: bool = False,
 ):
     """Split-complex analogue of ``backends.apply_step``: one pairwise
     contraction of (real, imag) pairs. The single source of truth
     shared by every split-mode executor. ``mode`` overrides the global
     env mode for this step — the :class:`KernelPolicy` hook; ``None``
-    falls back to :func:`complex_mult_env` (``gauss``).
+    falls back to the forcing override (:func:`complex_mult_forced`),
+    then to the step's shape (:func:`default_step_mode`).
     ``precision_mode`` is the policy's per-step dot-precision rung
     (``high``/``highest``; empty defers to the
     ``TNC_TPU_DOT_PRECISION`` override, then the backend
@@ -324,6 +361,13 @@ def apply_step_split(
     ``fused`` / ``fused_transpose`` modes in interpret mode — decided by
     the caller from the device it targets (:func:`interpret_for`), never
     from the process.
+
+    ``carry`` is the walker's (:func:`apply_steps_split`), for a result
+    its next reader is a later step of the same walk: a ``block`` step
+    then hands its result back as ONE array with the plane axis leading,
+    ``(2,) + stored``, so the value stays whole from the dot that made
+    it to the dot that reads it. An operand may be in that form whatever
+    ``carry`` says; without it a pair goes out.
 
     Off the host oracle the step's ops are traced under
     ``jax.named_scope("tnc.<bucket>")`` (:func:`step_bucket`: stem /
@@ -337,15 +381,31 @@ def apply_step_split(
         scope = jax.named_scope("tnc." + step_bucket(step))
     with scope:
         return _apply_step_split(
-            xp, apair, bpair, step, precision, mode, precision_mode, interpret
+            xp, apair, bpair, step, precision, mode, precision_mode,
+            interpret, carry,
         )
 
 
+def _as_pair(value):
+    """A value of the walker as its (real, imag) pair: a pair as it is,
+    a carried ``(2,) + stored`` array cut into its planes."""
+    return value if isinstance(value, tuple) else (value[0], value[1])
+
+
 def _apply_step_split(
-    xp, apair, bpair, step, precision, mode, precision_mode, interpret
+    xp, apair, bpair, step, precision, mode, precision_mode, interpret,
+    carry=False,
 ):
     from tnc_tpu.ops.backends import _prep_operand
 
+    resolved = mode or complex_mult_forced() or default_step_mode(step)
+    if resolved == "block" and xp is not np:
+        _note_step_lowering("block")
+        return _block_step(
+            apair, bpair, step,
+            _resolve_step_precision(precision, precision_mode), carry,
+        )
+    apair, bpair = _as_pair(apair), _as_pair(bpair)
     if mode == "fused_transpose" and xp is not np:
         # the fused transpose-dot consumes the RAW stored views — it
         # must run BEFORE _prep_operand materializes the macro
@@ -357,6 +417,7 @@ def _apply_step_split(
             interpret,
         )
         if out is not None:
+            _note_step_lowering("fused_transpose")
             return out
         mode = "naive"
 
@@ -373,7 +434,7 @@ def _apply_step_split(
         xp, bpair[1], step.b_view, step.b_perm, step.b_dot, step.b_ops
     )
     if mode is None:
-        mode = complex_mult_env()
+        mode = resolved
     if mode == "strassen" and not _strassen_step_eligible(step):
         mode = "gauss"  # forced-strassen steps below the crossover
     if xp is np:
@@ -391,8 +452,9 @@ def _apply_step_split(
             ar, ai, br, bi = br.T, bi.T, ar, ai
         else:
             ar, ai = ar.T, ai.T
-        if mode in ("naive", "fused", "fused_transpose"):
-            # the fused kernels run naive arithmetic on host oracles
+        if mode in ("naive", "block", "fused", "fused_transpose"):
+            # the block form and the fused kernels run naive arithmetic
+            # on host oracles
             re = ar @ br - ai @ bi
             im = ar @ bi + ai @ br
         else:
@@ -404,6 +466,7 @@ def _apply_step_split(
 
     prec = _resolve_step_precision(precision, precision_mode)
     if mode == "strassen":
+        _note_step_lowering("strassen")
         return _strassen_step(jnp, ar, ai, br, bi, step, prec)
     ca = (0,) if step.a_cfirst else (len(step.a_dot) - 1,)
     cb = (0,) if step.b_cfirst else (len(step.b_dot) - 1,)
@@ -416,16 +479,112 @@ def _apply_step_split(
     if mode == "fused":
         out = _try_fused_step(ar, ai, br, bi, step, prec, interpret)
         if out is not None:
+            _note_step_lowering("fused")
             return out
         mode = "naive"  # routed away by eligibility: same arithmetic
     if mode == "naive":
+        _note_step_lowering("naive")
         re = dot(ar, br) - dot(ai, bi)
         im = dot(ar, bi) + dot(ai, br)
         return re.reshape(step.out_store), im.reshape(step.out_store)
+    _note_step_lowering("gauss")
     k1 = dot(ar + ai, br)
     k2 = dot(ar, bi - br)
     k3 = dot(ai, br + bi)
     return (k1 - k3).reshape(step.out_store), (k1 + k2).reshape(step.out_store)
+
+
+def _block_step(a, b, step, precision, carry):
+    """One step as ONE real dot (device path), stored operands in: each
+    a (real, imag) pair or a carried ``(2,) + stored`` array; the result
+    is carried if ``carry``, else a pair.
+
+    The operand with the larger free extent is streamed: its planes are
+    joined along the contracted axis (``k -> 2k``) — carried and
+    contract-dim-leading, that is a reshape of its two major axes. The
+    other is expanded to its 2 x 2 block ``[[er, ei], [-ei, er]]`` along
+    (contracted axis, a new free axis of 2 that leads its free dims), so
+    with ``s = sr + i si`` the dot's two halves along the new axis are
+    ``sr·er - si·ei`` and ``sr·ei + si·er``. Where the expanded operand
+    comes first in the dot (under ``swap`` as the plan has it: the larger
+    free run supplies the minor dims) that axis is the major-most of the
+    result: the result IS the carried value and the halves are never cut
+    apart.
+
+    A result that has to leave as a pair is written as one: where it is
+    larger than the streamed operand, each half by a dot of its own
+    (the streamed operand read twice, no pass over the result to cut
+    it); else the one dot, and the halves cut."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from tnc_tpu.ops.backends import _prep_operand
+    from tnc_tpu.ops.program import step_dims
+
+    def prep(value, view, perm, dot_shape, ops):
+        def one(plane):
+            return _prep_operand(jnp, plane, view, perm, dot_shape, ops)
+
+        if isinstance(value, tuple):
+            return one(value[0]), one(value[1])
+        return jax.vmap(one)(value)  # the plane axis rides in front
+
+    a = prep(a, step.a_view, step.a_perm, step.a_dot, step.a_ops)
+    b = prep(b, step.b_view, step.b_perm, step.b_dot, step.b_ops)
+    m, k, n = step_dims(step)
+    # ties expand the operand the dot takes first: the plane axis leads
+    expand_a = m < n or (m == n and not step.swap)
+    sides = (
+        (a, step.a_cfirst, len(step.a_dot)),
+        (b, step.b_cfirst, len(step.b_dot)),
+    )
+    (e, e_cfirst, rank_e), (s, s_cfirst, rank_s) = (
+        sides if expand_a else sides[::-1]
+    )
+    ks = 0 if s_cfirst else rank_s - 1
+    if s_cfirst and not isinstance(s, tuple):
+        joined = s.reshape((2 * s.shape[1],) + s.shape[2:])
+    else:
+        joined = jnp.concatenate(_as_pair(s), axis=ks)
+    er, ei = _as_pair(e)
+    ke = 0 if e_cfirst else rank_e - 1
+    halves = [
+        jnp.concatenate([er, -ei], axis=ke),
+        jnp.concatenate([ei, er], axis=ke),
+    ]
+    expanded_first = expand_a != step.swap
+
+    def dot(expanded, ke):
+        if expanded_first:
+            return lax.dot_general(
+                expanded, joined, (((ke,), (ks,)), ((), ())),
+                precision=precision,
+            )
+        return lax.dot_general(
+            joined, expanded, (((ks,), (ke,)), ((), ())), precision=precision
+        )
+
+    if not carry and min(m, n) > k:  # the result outweighs the streamed
+        return tuple(dot(h, ke).reshape(step.out_store) for h in halves)
+    out = dot(
+        jnp.stack(halves, axis=1 if e_cfirst else 0),
+        ke if e_cfirst else ke + 1,
+    )
+    if not expanded_first:
+        out = jnp.moveaxis(out, rank_s - 1, 0)
+    out = out.reshape((2,) + tuple(step.out_store))
+    return out if carry else _as_pair(out)
+
+
+def _note_step_lowering(mode: str) -> None:
+    """Count the lowering one step was traced under
+    (``ops.step_lowering{mode=...}``: once a step a trace, the arithmetic
+    that runs after every fallback) — what a record quotes to say how
+    far a program ran in the ``block`` form."""
+    from tnc_tpu import obs
+
+    obs.counter_add("ops.step_lowering", mode=mode)
 
 
 def _strassen_step_eligible(step) -> bool:
@@ -624,7 +783,7 @@ def _try_fused_transpose_step(apair, bpair, step, precision, interpret=False):
 class KernelPolicy:
     """Per-step kernel choice for one compiled program.
 
-    ``modes[i]`` is the lowering of step ``i`` (``naive`` / ``gauss`` /
+    ``modes[i]`` is the lowering of step ``i`` (``naive`` / ``gauss`` / ``block`` /
     ``fused`` / ``fused_transpose`` / ``strassen``); ``chains`` are
     ``(start, end)`` step spans that execute as ONE fused multi-step
     Pallas dispatch
@@ -776,7 +935,7 @@ def plan_kernels(
 
     ``force`` (default: the ``TNC_TPU_COMPLEX_MULT`` override via
     :func:`complex_mult_forced`) pins the decision for A/B runs:
-    ``naive``/``gauss``/``fused``/``fused_transpose`` uniformly
+    ``naive``/``gauss``/``block``/``fused``/``fused_transpose`` uniformly
     (the fused rungs fall back per step at trace time, counted);
     ``strassen`` promotes every step over the crossover (others run
     gauss); ``chain`` fuses every groupable run (others run gauss) —
@@ -796,7 +955,8 @@ def plan_kernels(
       (square-ish, ≥2^11 per dim) where the multiply saving beats the
       extra passes → **strassen** (when both rungs pay, the larger
       predicted saving wins);
-    - everything else → **gauss**, the tuned default;
+    - everything else by its shape (:func:`default_step_mode`):
+      **block** where ``2k <= 128``, **gauss** above;
     - stem-bucket compute-dominated steps additionally promote their
       dots to the bf16x3 ``high`` rung under the parity budget.
     """
@@ -825,7 +985,7 @@ def plan_kernel_steps(
     pmodes = plan_precision_modes(
         steps, cost_model, precision_force, parity_budget
     )
-    if force in ("naive", "gauss", "fused", "fused_transpose"):
+    if force in ("naive", "gauss", "block", "fused", "fused_transpose"):
         return KernelPolicy((force,) * n, (), pmodes)
     if force == "strassen":
         modes = tuple(
@@ -875,7 +1035,7 @@ def plan_kernel_steps(
             else float("-inf")
         )
         if strassen_gain <= 0.0 and transpose_gain <= 0.0:
-            modes.append("gauss")
+            modes.append(default_step_mode(st))
         elif strassen_gain >= transpose_gain:
             modes.append("strassen")
         else:
@@ -935,14 +1095,18 @@ def kernel_plan_summary(
     deleted pass shows up as ``pred_bytes_planned <
     pred_bytes_naive`` on transpose-carrying buckets — the invariant
     ``scripts/perf_gate.py`` enforces), and the dispatch count
-    (chains collapse to one). ``dtype_bytes`` defaults to the device
-    path's f32 split-pair width (8 B per complex element). The static
-    side of ``bench.py``'s per-bucket MFU report."""
+    (chains collapse to one). ``lowering`` is the whole program by the
+    arithmetic each step resolves to (:func:`resolved_step_mode`): steps
+    and the shares of steps and of multiply-adds under each mode — how
+    far the program runs in the ``block`` form. ``dtype_bytes`` defaults
+    to the device path's f32 split-pair width (8 B per complex element).
+    The static side of ``bench.py``'s per-bucket MFU report."""
     if policy is None:
         policy = plan_kernels(program)
     from tnc_tpu.ops.program import step_elems, step_flops, step_prep_elems
 
     buckets: dict[str, dict] = {}
+    lowering: dict[str, dict] = {}
     for i, st in enumerate(program.steps):
         b = buckets.setdefault(
             step_bucket(st),
@@ -959,6 +1123,9 @@ def kernel_plan_summary(
         )
         mode = policy.modes[i]
         resolved = resolved_step_mode(st, mode)
+        low = lowering.setdefault(resolved, {"steps": 0, "flops": 0.0})
+        low["steps"] += 1
+        low["flops"] += step_flops(st)
         b["steps"] += 1
         b["flops"] += step_flops(st)
         b["effective_flops"] += effective_step_flops(st, resolved)
@@ -983,8 +1150,13 @@ def kernel_plan_summary(
             b["pred_bytes_per_step_planned"] = float(
                 f"{b['pred_bytes_planned'] / b['steps']:.4e}"
             )
+    total_flops = sum(low["flops"] for low in lowering.values())
+    for low in lowering.values():
+        low["step_share"] = round(low["steps"] / len(program.steps), 4)
+        low["flops_share"] = round(low.pop("flops") / max(total_flops, 1.0), 4)
     return {
         "buckets": buckets,
+        "lowering": lowering,
         "dispatches": policy.dispatch_count(),
         "chains": len(policy.chains),
         "chained_steps": len(policy.chained_steps()),
@@ -1100,15 +1272,31 @@ def apply_steps_split(
     :class:`KernelPolicy` planned over exactly these ``steps``, spans
     indexed relative to them) fuses chains into single Pallas
     dispatches and promotes steps per the kernel ladder; None runs
-    every step under the env mode (``gauss`` default).
+    every step under the forcing override, else as its shape decides
+    (:func:`default_step_mode`). Between two ``block`` steps a value
+    stays ONE ``(2,) + stored`` array (``carry``, see
+    :func:`apply_step_split`); every other lowering takes and hands back
+    a pair, and so does a step whose result outlives the walk, so a
+    caller sees pairs alone.
     ``interpret``: see :func:`apply_step_split`."""
     chain_end = (
         {s: e for s, e in policy.chains} if policy is not None else {}
     )
+    # a result is carried only to a later step of this walk: what
+    # outlives the walk leaves as the pair a caller expects
+    read_later: set[int] = set()
+    carried = [False] * len(steps)
+    for i in range(len(steps) - 1, -1, -1):
+        carried[i] = steps[i].lhs in read_later
+        read_later.update((steps[i].lhs, steps[i].rhs))
     i = 0
     while i < len(steps):
         end = chain_end.get(i)
         if end is not None:
+            for st in steps[i:end]:  # a chain reads pairs
+                for slot in (st.lhs, st.rhs):
+                    if state[slot] is not None:
+                        state[slot] = _as_pair(state[slot])
             run_chain_split(
                 xp, steps[i:end], state, precision,
                 precision_mode=policy.precision_mode(i),
@@ -1123,7 +1311,7 @@ def apply_steps_split(
             precision_mode=(
                 policy.precision_mode(i) if policy is not None else None
             ),
-            interpret=interpret,
+            interpret=interpret, carry=carried[i],
         )
         state[step.rhs] = None
         i += 1

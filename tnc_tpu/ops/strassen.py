@@ -24,7 +24,7 @@ no operand is ever transposed; the transpose lives inside the
 Numerics: Strassen's extra additions mix operand magnitudes before the
 products, so rounding error grows a small constant factor over the
 naive dot (same failure family as the Gauss/Karatsuba instability —
-see ``split_complex.complex_mult_env``). The parity pins live in
+see ``split_complex.complex_mult_forced``). The parity pins live in
 ``tests/test_strassen.py``; the documented tolerance rungs vs the
 complex128 numpy oracle are **2e-5 relative (float32)** and **1e-12
 relative (float64)** at one recursion level.
