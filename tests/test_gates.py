@@ -17,7 +17,7 @@ from tnc_tpu.gates import (
 )
 from tnc_tpu.tensornetwork.tensordata import matrix_adjoint
 
-GATE_PARAMS = {"u": 3, "rx": 1, "ry": 1, "rz": 1, "cp": 1, "fsim": 2}
+GATE_PARAMS = {"u": 3, "rx": 1, "ry": 1, "rz": 1, "cp": 1, "fsim": 2, "rzz": 1}
 
 
 def test_all_builtins_present():

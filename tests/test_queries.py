@@ -302,10 +302,15 @@ class TestExpectation:
         finally:
             obs.configure(enabled=False)
         singles = [
-            pauli_expectation(_rotations(3, 2), p) for _, p in terms
+            complex(bind_expectation(_rotations(3, 2)).values([p])[0])
+            for _, p in terms
         ]
         for got, want in zip(vals, singles):
             assert got == want  # same program, same arithmetic: bitwise
+        # pauli_expectation contracts each term's own lightcone: another
+        # network, the same value to rounding
+        for (_, p), want in zip(terms, singles):
+            assert abs(pauli_expectation(_rotations(3, 2), p) - want) < 1e-12
         assert total == complex(
             sum(c * v for (c, _), v in zip(terms, singles))
         )

@@ -8,6 +8,9 @@ from tnc_tpu.builders.connectivity import (  # noqa: F401
     Connectivity,
     ConnectivityLayout,
 )
+from tnc_tpu.builders.kicked_ising_circuit import (  # noqa: F401
+    kicked_ising_circuit,
+)
 from tnc_tpu.builders.peps import peps  # noqa: F401
 from tnc_tpu.builders.random_circuit import (  # noqa: F401
     random_circuit,

@@ -8,8 +8,10 @@ is the conjugate-transpose with the half-dims-swap convention
 (``gates.rs:112-126``); rotation-like gates specialize it by negating
 angles.
 
-The 18 built-ins match ``gates.rs:17-38``: x, y, z, h, t, u, sx, sy, sz,
-rx, ry, rz, cx, cz, swap, cp, iswap, fsim. User gates are registered with
+The first 18 built-ins match ``gates.rs:17-38``: x, y, z, h, t, u, sx, sy,
+sz, rx, ry, rz, cx, cz, swap, cp, iswap, fsim; ``rzz`` (the Ising
+coupling, one diagonal leaf where ``cx rz cx`` is three) is this
+library's own. User gates are registered with
 :func:`register_gate` (lowercase names enforced, ``gates.rs:41-47``).
 """
 
@@ -168,6 +170,14 @@ def _gate_fsim(angles: Sequence[float]) -> np.ndarray:
     return _two_qubit(m)
 
 
+def _gate_rzz(angles: Sequence[float]) -> np.ndarray:
+    """rzz(theta) = exp(-i theta/2 Z x Z): diagonal and symmetric in
+    its two qubits; equal to cx(a, b) rz(theta, b) cx(a, b)."""
+    _check_angles("rzz", angles, 1)
+    lo, hi = cmath.exp(-0.5j * angles[0]), cmath.exp(0.5j * angles[0])
+    return _two_qubit(np.diag(np.array([lo, hi, hi, lo], dtype=_C)))
+
+
 def _negated_angles_adjoint(fn: GateFn) -> GateFn:
     """Adjoint by negating all angles (rotation-like gates)."""
 
@@ -259,6 +269,7 @@ def _register_builtins() -> None:
         Gate("cp", _gate_cp, _negated_angles_adjoint(_gate_cp), 2),
         Gate("iswap", _gate_iswap, _conjugate_adjoint(_gate_iswap), 2),
         Gate("fsim", _gate_fsim, _negated_angles_adjoint(_gate_fsim), 2),
+        Gate("rzz", _gate_rzz, _negated_angles_adjoint(_gate_rzz), 2),
     ]
     for g in builtins:
         register_gate(g)

@@ -172,14 +172,40 @@ def _prelude_fn(hp, split_complex: bool, precision, interpret: bool = False):
     return fn
 
 
+# The last prelude run: its jitted function, the device buffers it read and
+# what it returned. A value or an amplitude is hours of slices asked for in
+# consecutive ranges; every such call places the same resident leaves
+# (``place_buffers`` hands back the stored buffer for unchanged content), and
+# the stem's products are then the ones already on the device. One entry:
+# it holds what the last call held anyway, and no chunk program donates.
+_LAST_PRELUDE: list = [None]
+
+
 def _hoisted_inputs(
     hp, device_full, split_complex: bool, precision, interpret: bool = False
 ):
-    """Run the prelude on device (one jitted dispatch) and assemble the
-    residual program's input buffer list from pass-through leaves and
-    the freshly cached intermediates."""
+    """Run the prelude on device (one jitted dispatch; none where the
+    last call ran it on these very buffers) and assemble the residual
+    program's input buffer list from pass-through leaves and the cached
+    intermediates."""
+    import jax
+
     pins = tuple(device_full[orig] for _, orig in hp.prelude_inputs)
-    cached = _prelude_fn(hp, split_complex, precision, interpret)(pins)
+    fn = _prelude_fn(hp, split_complex, precision, interpret)
+    flat = jax.tree.leaves(pins)
+    last = _LAST_PRELUDE[0]
+    if (
+        last is not None
+        and last[0] is fn
+        and len(last[1]) == len(flat)
+        and all(a is b for a, b in zip(last[1], flat))
+    ):
+        obs.counter_add("chunked.prelude", mode="reused")
+        cached = last[2]
+    else:
+        obs.counter_add("chunked.prelude", mode="run")
+        cached = fn(pins)
+        _LAST_PRELUDE[0] = (fn, flat, cached)
     out = []
     it = iter(cached)
     for kind, ref in hp.residual_sources:

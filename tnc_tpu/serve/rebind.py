@@ -332,6 +332,42 @@ def plan_signature(bound: BoundProgram) -> str:
     return bound.program.signature_digest()
 
 
+# A budgeted plan of a structure with more leaves than this is searched on
+# its rank>=3 cores (plan_structure). Below it the slicer's bounded repair
+# passes reach the whole tree either way, and plans stay what they were.
+CORE_PLAN_MIN_LEAVES = 256
+
+
+def _budget_cores(tn):
+    """The rank>=3 cores a budgeted plan is searched on: ``(prefix,
+    core_ids, next_id, core network)`` after every rank<=2 leaf (kets,
+    bras, one-qubit gates, observable and trace closures) is absorbed
+    into a neighbour — structure only, no data; ``prefix`` holds the
+    absorptions as ssa pairs over the leaves of ``tn``. ``None`` where
+    nothing is absorbed or fewer than three cores are left."""
+    from tnc_tpu.contractionpath.paths.hyper import _simplify
+    from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor
+
+    leaves = list(tn.tensors)
+    if len(leaves) <= CORE_PLAN_MIN_LEAVES or any(
+        not t.is_leaf() for t in leaves
+    ):
+        return None
+    dims: dict[int, int] = {}
+    for t in leaves:
+        dims.update(t.edges())
+    prefix, legs_map, next_id = _simplify(
+        {i: frozenset(t.legs) for i, t in enumerate(leaves)}, dims
+    )
+    if not prefix or len(legs_map) < 3:
+        return None
+    core_ids = sorted(legs_map)
+    cores = CompositeTensor(
+        [LeafTensor.from_map(sorted(legs_map[i]), dims) for i in core_ids]
+    )
+    return prefix, core_ids, next_id, cores
+
+
 def plan_structure(
     tn, pathfinder=None, target_size: float | None = None, cost_model=None
 ):
@@ -347,29 +383,67 @@ def plan_structure(
     repair here is then *seeded* with it — a thin validation pass over
     the plan the search already priced, not a fresh post-pass slicing
     search. ``cost_model`` keeps the repair's leg scoring in the same
-    predicted-seconds domain as a calibrated replanner."""
-    from tnc_tpu.contractionpath.contraction_path import ContractionPath
+    predicted-seconds domain as a calibrated replanner.
+
+    Under a budget, a structure of more than ``CORE_PLAN_MIN_LEAVES``
+    leaves is searched on its rank>=3 cores (:func:`_budget_cores`)
+    and the plan is lifted back to the leaves: the absorptions first,
+    then the cores' path. The slicer's repair
+    passes are bounded in rounds and seconds; spread over a served
+    template's raw leaves (a ket, an ``rx`` and a closure to every
+    core) they reach a fraction of the tree, and a 1230-leaf sandwich
+    sliced to 2^26 slices where its 500 cores slice to 2^20."""
+    from tnc_tpu.contractionpath.contraction_path import (
+        ContractionPath,
+        replace_ssa_ordering,
+        ssa_replace_ordering,
+    )
 
     if pathfinder is None:
         from tnc_tpu.contractionpath.paths import Greedy, OptMethod
 
         pathfinder = Greedy(OptMethod.GREEDY)
-    result = pathfinder.find_path(tn)
+    cores = _budget_cores(tn) if target_size is not None else None
+    planned = tn if cores is None else cores[3]
+    result = pathfinder.find_path(planned)
     slicing = None
+    replace_pairs = None  # over `planned`, where the slicer changed the path
     if target_size is not None and result.size > target_size:
         from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
 
         seed = getattr(pathfinder, "last_slicing", None)
         replace_pairs, slicing = slice_and_reconfigure(
-            list(tn.tensors), result.ssa_path.toplevel, target_size,
+            list(planned.tensors), result.ssa_path.toplevel, target_size,
             cost_model=cost_model,
             seed_slices=seed.legs if seed is not None else None,
         )
         if slicing.num_slices <= 1:
             slicing = None
-        path = ContractionPath.simple(list(replace_pairs))
+    if cores is None:
+        path = (
+            result.replace_path()
+            if replace_pairs is None
+            else ContractionPath.simple(list(replace_pairs))
+        )
     else:
-        path = result.replace_path()
+        # the absorptions first, then the cores' path over their ssa ids
+        prefix, core_ids, next_id, _ = cores
+        m = len(core_ids)
+        ssa_pairs = (
+            result.ssa_path.toplevel
+            if replace_pairs is None
+            else replace_ssa_ordering(list(replace_pairs), m)
+        )
+
+        def lifted(i: int) -> int:
+            return core_ids[i] if i < m else next_id + (i - m)
+
+        path = ssa_replace_ordering(
+            ContractionPath.simple(
+                prefix + [(lifted(a), lifted(b)) for a, b in ssa_pairs]
+            ),
+            len(tn.tensors),
+        )
     program = build_program(tn, path)
     sliced = (
         build_sliced_program(tn, path, slicing)
