@@ -406,3 +406,147 @@ def test_slice_spmd_compiles_for_four_chips(topo):
     compiled = fn.lower(*_pair_specs(shapes, replicated)).compile()
     assert "all-reduce" in compiled.as_text()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
+
+
+# -- the tiled prep of a block step's streamed operand ---------------------
+
+
+def _tiled_chain():
+    """Two large block steps in a row, the second streaming the first's
+    result: steps 39 and 40 of the benchmark's Sycamore-53 residual
+    (seed 3000000061), a stem of 2^21 and 2^22 elements a plane stored
+    with a minor dim of 256, k = 4 from the rows each time."""
+    from tnc_tpu.ops.program import PairStep
+
+    first = PairStep(
+        0, 1, (16, 2, 256, 2, 256), (1, 3, 0, 2, 4), (4, 16, 256, 256), True,
+        (4, 2, 2, 2), None, (4, 2, 2, 2), True, True, (4, 2, 2048, 2, 256),
+    )
+    second = PairStep(
+        0, 2, (4, 2, 2048, 2, 256), (1, 3, 0, 2, 4), (4, 4, 2048, 256), True,
+        (2, 2, 4), None, (2, 2, 4), False, True, (2, 4194304),
+    )
+    return (first, second), [(16, 2, 256, 2, 256), (4, 2, 2, 2), (2, 2, 4)]
+
+
+def _walk_fn(steps):
+    from tnc_tpu.ops.split_complex import apply_steps_split
+
+    def walk(pairs):
+        state = list(pairs)
+        apply_steps_split(jnp, steps, state, "float32")
+        return state[steps[-1].lhs]
+
+    return jax.jit(walk)
+
+
+def _entry_graph(text):
+    """``name -> (op, operand names, elements)`` of the compiled
+    module's entry computation, and the names of its fusions that hold
+    a convolution."""
+    import math
+    import re
+
+    convs, entry, body, name = set(), {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if head:
+            name, body = head.group(2), "entry" if head.group(1) else "other"
+            continue
+        if body == "other" and " convolution(" in line:
+            convs.add(name)
+        inst = re.match(
+            r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]\S*\s+"
+            r"([\w\-]+)\((.*?)\)", line,
+        )
+        if body == "entry" and inst:
+            dims = [int(d) for d in inst.group(2).split(",") if d]
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            entry[inst.group(1)] = (
+                inst.group(3), re.findall(r"%([\w.\-]+)", inst.group(4)),
+                math.prod(dims), called.group(1) if called else None,
+            )
+    dots = [n for n, (_, _, _, called) in entry.items() if called in convs]
+    return entry, dots
+
+
+def test_tiled_chain_has_no_retiling_reshape_before_its_dot(one_chip):
+    """The second of two large block steps compiles to two passes over
+    its streamed operand: from the first step's convolution through
+    bitcasts to ONE ``copy`` (the planned transpose, into the tiled
+    image) and through bitcasts to the convolution that reads it — no
+    ``reshape`` that is not a bitcast in between. (The first step keeps
+    one for the entry parameter's layout.)"""
+    from tnc_tpu.ops.split_complex import step_prep_form
+
+    steps, shapes = _tiled_chain()
+    assert [step_prep_form(st) for st in steps] == ["tiled", "tiled"]
+    text = _walk_fn(steps).lower(
+        _pair_specs(shapes, one_chip)
+    ).compile().as_text()
+    entry, dots = _entry_graph(text)
+    assert len(dots) >= 2, dots
+    large = 2**18
+
+    def producer(name):
+        """Back from an op through bitcasts, along its largest operand."""
+        chain = []
+        while True:
+            operands = [o for o in entry[name][1] if o in entry]
+            if not operands:
+                return chain
+            name = max(operands, key=lambda o: entry[o][2])
+            chain.append((entry[name][0], name))
+            if entry[name][0] != "bitcast":
+                return chain
+
+    last = dots[-1]
+    chain = producer(last)
+    assert chain[-1][0] == "copy", chain
+    assert entry[chain[-1][1]][2] >= large
+    before = producer(chain[-1][1])
+    assert before[-1][1] in dots[:-1], (chain, before)
+    assert not any(op == "reshape" for op, _ in chain + before)
+
+
+SMALL_DIGEST = "27fc53b464245593"
+GAUSS_DIGEST = "4aaf1517c0ade846"
+
+
+def _lowered_digest(step, shapes, mode=None):
+    import hashlib
+
+    from tnc_tpu.ops.split_complex import apply_step_split
+
+    fn = jax.jit(
+        lambda a, b: apply_step_split(
+            jnp, a, b, step, precision="float32", mode=mode
+        )
+    )
+    specs = [
+        (jax.ShapeDtypeStruct(s, jnp.float32),) * 2 for s in shapes
+    ]
+    return hashlib.sha256(fn.lower(*specs).as_text().encode()).hexdigest()[:16]
+
+
+def test_size_rule_leaves_small_and_gauss_steps_as_they_were(topo):
+    """A block step under 2^18 elements a plane and a gauss step lower
+    to the text they lowered to before the tiled prep (digests of the
+    StableHLO text taken at commit 0ee9ab0 with this installation): the
+    rule reads the step's shape and sends only large block steps on."""
+    from tnc_tpu.ops.program import PairStep
+    from tnc_tpu.ops.split_complex import default_step_mode, step_prep_form
+
+    small = PairStep(
+        0, 1, (256, 4, 128), (1, 0, 2), (4, 256, 128), True,
+        (4, 8), None, (4, 8), True, True, (8, 256 * 128),
+    )
+    gauss = PairStep(
+        0, 1, (2**11, 2**8), (1, 0), (2**8, 2**11), True,
+        (2**8, 4), None, (2**8, 4), True, True, (4, 2**11),
+    )
+    assert default_step_mode(small) == "block"
+    assert default_step_mode(gauss) == "gauss"
+    assert step_prep_form(small) == step_prep_form(gauss) == "matrix"
+    assert _lowered_digest(small, [small.a_view, small.b_view]) == SMALL_DIGEST
+    assert _lowered_digest(gauss, [gauss.a_view, gauss.b_view]) == GAUSS_DIGEST

@@ -27,8 +27,17 @@ bandwidth. The compiler therefore guarantees:
   next step contracts (`next_shared`) and emits its free legs as
   [consumer-contracted…, consumer-kept…] (stored-order within each), so
   the consumer's transpose is a ≤4-block permutation. Storage merges
-  also stop at that boundary, keeping the consumer's reshape view a
-  layout-free regroup.
+  also stop at that boundary, so the consumer's reshape view regroups
+  rows only. That is free for the ROWS of a view; a merge that crosses
+  the (8, 128) tile is not: a transposed ``(k…, frees…, 256)`` operand
+  is re-tiled into its 2-D ``(k, M)`` matrix before XLA's convolution
+  reads it, one more pass over the operand (measured on the v5e:
+  PERF.md, PR 34).
+- **The tiled image**: where a one-dot step streams a large operand
+  (`stream_prep_form`), its transpose therefore targets the image of
+  that matrix directly, ``(M / 128, 2k, 128)`` (`tiled_prep_ops`): the
+  bytes a ``(2k, M)`` array tiled (8, 128) holds, so the dot reads what
+  the transpose wrote and no re-tiling pass runs between them.
 
 The whole-program jit then keeps intermediates in HBM, fuses elementwise
 glue, and frees buffers eagerly (the reference frees inputs per step via
@@ -38,6 +47,7 @@ here).
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -74,7 +84,10 @@ def step_prep_elems(st) -> float:
     the dot itself: a materialized macro transpose (or staged op plan)
     reads the whole operand and writes the permuted copy — ``2 ×
     view`` elements per permuted operand. Zero for identity preps
-    (reshape-only — layout-free on TPU). This is the pass the
+    (reshape-only: no planned pass, though XLA re-tiles an operand whose
+    view merges across the (8, 128) tile before its dot reads it — a
+    pass this count does not see, and the one `tiled_prep_ops` removes
+    for the large operands of one-dot steps). This is the pass the
     ``fused_transpose`` kernel rung deletes
     (:mod:`tnc_tpu.ops.pallas_complex`), and the traffic the original
     ``steps_bytes`` under-predicted on transpose-dominated steps (the
@@ -421,6 +434,172 @@ def _staged_ops(
     if axes != tuple(range(len(view))):
         ops.append(("transpose", axes))
     return tuple(ops)
+
+
+def streamed_side(st) -> str:
+    """Which operand of a step a one-dot lowering streams: ``"a"`` or
+    ``"b"``, the one with the larger free extent (the other is small
+    enough to expand). Ties stream the operand the dot takes second."""
+    m, _, n = step_dims(st)
+    expand_a = m < n or (m == n and not st.swap)
+    return "b" if expand_a else "a"
+
+
+def operand_prep(st, side: str) -> tuple:
+    """``(view, perm, dot, cfirst, ops)`` of operand ``"a"`` or ``"b"``."""
+    if side == "a":
+        return st.a_view, st.a_perm, st.a_dot, st.a_cfirst, st.a_ops
+    return st.b_view, st.b_perm, st.b_dot, st.b_cfirst, st.b_ops
+
+
+def _split_digits(digits, cuts):
+    """Refine mixed-radix ``(stride, size)`` digits at every stride in
+    ``cuts`` that falls inside one; ``None`` where a cut does not divide
+    a digit. Order is kept, a digit's parts most significant first."""
+    out = []
+    for stride, size in digits:
+        top = stride * size
+        marks = [stride] + [c for c in cuts if stride < c < top] + [top]
+        parts = []
+        for lo, hi in zip(marks, marks[1:]):
+            if hi % lo:
+                return None
+            parts.append((lo, hi // lo))
+        out.extend(reversed(parts))
+    return out
+
+
+@functools.lru_cache(maxsize=4096)
+def tiled_prep_ops(view, perm, dot, cfirst, staged=False, source=None):
+    """Device ops that bring a streamed operand, both planes in ONE
+    array, to the **tiled image** of its joined ``(2k, M)`` matrix:
+    logical shape ``(M / 128, 2k, 128)`` — rows; plane and contracted
+    legs on the sublane axis; the 128 minor elements of the free legs on
+    the lanes. A ``(2k, M)`` f32 array tiled (8, 128) is byte for byte
+    that array in row-major order, so a dot that contracts axis 1 of it
+    reads the operand as the transpose wrote it: no re-tiling pass
+    between the two.
+
+    ``(view, perm, dot, cfirst)`` are the operand's `PairStep` fields
+    (for a staged operand they are at leg granularity); ``staged`` says
+    the plan moved legs through the lane window, and the ops are then
+    planned by `_staged_ops` (row transposes round one ``lanemix``).
+    ``source`` says how the array lies: ``None`` is ``(2,) + stored``,
+    the plane axis leading; ``(rows, n)`` is a value a tiled step left
+    as ``(rows, 2n, 128)`` where the stored order is ``(n, rows,
+    128)``. Ops are those of `PairStep.a_ops`, ending in the reshape to
+    the image. ``None``: the free extent is no multiple of 128, or the
+    digits of the two orders do not refine to common factors.
+    """
+    total = int(math.prod(view))
+    order = list(perm) if perm is not None else list(range(len(view)))
+    k = int(dot[0] if cfirst else dot[-1])
+    if total % k or (total // k) % _MIN_MINOR:
+        return None
+    strides = [1] * len(view)
+    for i in range(len(view) - 2, -1, -1):
+        strides[i] = strides[i + 1] * view[i + 1]
+    digits = [(strides[ax], view[ax]) for ax in order if view[ax] > 1]
+    # the contracted digits lead (cfirst) or trail the free ones
+    nk, run = 0, 1
+    for _, size in digits if cfirst else reversed(digits):
+        if run == k:
+            break
+        run *= size
+        nk += 1
+    if run != k:
+        return None
+    if cfirst:
+        kdigits, free = digits[:nk], digits[nk:]
+    else:
+        kdigits, free = digits[len(digits) - nk:], digits[:len(digits) - nk]
+    # the lanes: the 128 minor elements of the free legs
+    lanes, need = [], _MIN_MINOR
+    while need > 1:
+        if not free:
+            return None
+        stride, size = free.pop()
+        if need % size == 0:
+            lanes.insert(0, (stride, size))
+            need //= size
+        elif size % need == 0:
+            free.append((stride * need, size // need))
+            lanes.insert(0, (stride, need))
+            need = 1
+        else:
+            return None
+    intact = lanes[-1][0] == 1 and all(
+        hi[0] == lo[0] * lo[1] for hi, lo in zip(lanes, lanes[1:])
+    )
+    if not (staged or intact):
+        # a plain transpose that changes what the lanes hold is re-tiled
+        # before AND after it: the matrix form's passes are fewer (on
+        # the v5e 13.65 ms a Sycamore-53 slice with these steps tiled,
+        # 12.81 without: PERF.md, PR 34)
+        return None
+    plane = (total, 2)
+    above = free + [plane] + kdigits  # the image's axes above its lanes
+    if source is None:
+        src = [plane, (1, total)]
+    else:
+        rows, n = source
+        if rows * n * _MIN_MINOR != total:
+            return None
+        src = [(_MIN_MINOR, rows), plane, (rows * _MIN_MINOR, n),
+               (1, _MIN_MINOR)]
+        src = [d for d in src if d[1] > 1]
+    ends = above + lanes + src
+    cuts = sorted({s for s, _ in ends} | {s * z for s, z in ends})
+    src, above, lanes = (_split_digits(d, cuts) for d in (src, above, lanes))
+    if src is None or above is None or lanes is None:
+        return None
+    target = above + lanes
+    if sorted(src) != sorted(target):
+        return None
+    at = {d: i for i, d in enumerate(src)}
+    dims = [size for _, size in src]
+    axes = [at[d] for d in target]
+    image = ("reshape", (total // (k * _MIN_MINOR), 2 * k, _MIN_MINOR))
+    if staged:
+        ops = _staged_ops(dims, axes)
+        if ops is not None:
+            return ops + (image,)
+    # one transpose over run-fused axes; the lanes stay an axis of their
+    # own at both ends (a sublane digit fused into them is a re-tiling)
+    lane_at = (
+        {i for i, d in enumerate(src) if d[0] < _MIN_MINOR},
+        {at[d] for d in lanes},
+    )
+    runs: list[list[int]] = []
+    where = {ax: j for j, ax in enumerate(axes)}
+    for ax in range(len(dims)):
+        if (
+            runs
+            and where[ax] == where[runs[-1][-1]] + 1
+            and all((ax in w) == (runs[-1][-1] in w) for w in lane_at)
+        ):
+            runs[-1].append(ax)
+        else:
+            runs.append([ax])
+    fused = tuple(int(math.prod(dims[ax] for ax in r)) for r in runs)
+    move = tuple(sorted(range(len(runs)), key=lambda i: where[runs[i][0]]))
+    ops = (("reshape", fused),)
+    if move != tuple(range(len(runs))):
+        ops += (("transpose", move),)
+    return ops + (image,)
+
+
+def stream_prep_form(st) -> str:
+    """Where the prep of a one-dot step's streamed operand ends, read
+    from the step's shape alone: ``"tiled"`` (`tiled_prep_ops`) where
+    the operand holds at least ``_STAGED_MIN_SIZE`` elements a plane and
+    its free extent is a multiple of 128; ``"matrix"``, the ``(2k,
+    frees…)`` form XLA re-tiles for its dot, for every smaller one."""
+    view, perm, dot, cfirst, ops = operand_prep(st, streamed_side(st))
+    if math.prod(view) < _STAGED_MIN_SIZE:
+        return "matrix"
+    tiled = tiled_prep_ops(view, perm, dot, cfirst, ops is not None)
+    return "matrix" if tiled is None else "tiled"
 
 
 @dataclass(frozen=True)

@@ -156,6 +156,21 @@ def default_step_mode(step) -> str:
     return "block" if 2 * k <= BLOCK_MAX_CONTRACT else "gauss"
 
 
+def step_prep_form(step, mode: str | None = None) -> str:
+    """Where the prep of a step's streamed operand ends under ``mode``
+    (as :func:`resolved_step_mode` takes it): ``tiled`` — the image of
+    its joined matrix, :func:`_tiled_block_step` — for a ``block`` step
+    whose streamed operand is large
+    (:func:`tnc_tpu.ops.program.stream_prep_form`: the step's shape, no
+    knob), ``matrix`` for every other step and lowering. What
+    ``ops.step_prep{form=}`` counts."""
+    from tnc_tpu.ops.program import stream_prep_form
+
+    if resolved_step_mode(step, mode) != "block":
+        return "matrix"
+    return stream_prep_form(step)
+
+
 def complex_mult_key() -> str:
     """Trace-time *cache-key* form of the env knob: the forced mode, or
     ``auto`` when unset. An unset env lets the step's shape and the
@@ -366,7 +381,9 @@ def apply_step_split(
     its next reader is a later step of the same walk: a ``block`` step
     then hands its result back as ONE array with the plane axis leading,
     ``(2,) + stored``, so the value stays whole from the dot that made
-    it to the dot that reads it. An operand may be in that form whatever
+    it to the dot that reads it; ``"tiled"`` where that reader streams
+    it through :func:`_tiled_block_step`, which may then leave it as a
+    :class:`TiledValue`. An operand may be in either form whatever
     ``carry`` says; without it a pair goes out.
 
     Off the host oracle the step's ops are traced under
@@ -386,9 +403,56 @@ def apply_step_split(
         )
 
 
+class TiledValue:
+    """A value carried from a tiled step to the tiled step that streams
+    it (:func:`_tiled_block_step`), as the dot wrote it: ``array`` is
+    ``(rows, 2n, 128)`` — the image of the ``(2n, rows * 128)`` result
+    matrix, plane and the ``n`` new elements on the sublane axis — where
+    the stored order of a plane is ``(n, rows, 128)``."""
+
+    __slots__ = ("array", "stored")
+
+    def __init__(self, array, stored):
+        self.array = array
+        self.stored = tuple(stored)
+
+    @property
+    def source(self) -> tuple[int, int]:
+        """``(rows, n)``, as :func:`tiled_prep_ops` takes it."""
+        rows, n2, _ = self.array.shape
+        return int(rows), int(n2) // 2
+
+    def plain(self):
+        """The same value as ``(2,) + stored``: one pass over it."""
+        import jax.numpy as jnp
+
+        rows, n = self.source
+        return jnp.transpose(
+            self.array.reshape(rows, 2, n, -1), (1, 2, 0, 3)
+        ).reshape((2,) + self.stored)
+
+
+def _untiled(value):
+    return value.plain() if isinstance(value, TiledValue) else value
+
+
+def _carry_form(reader, slot: int, mode: str | None):
+    """How a result is carried to ``reader``, the next step of the walk
+    that reads it from ``slot``: ``"tiled"`` where the reader streams it
+    through :func:`_tiled_block_step`, else ``True`` (one ``(2,) +
+    stored`` array)."""
+    from tnc_tpu.ops.program import streamed_side
+
+    streamed = reader.lhs if streamed_side(reader) == "a" else reader.rhs
+    if streamed == slot and step_prep_form(reader, mode) == "tiled":
+        return "tiled"
+    return True
+
+
 def _as_pair(value):
     """A value of the walker as its (real, imag) pair: a pair as it is,
     a carried ``(2,) + stored`` array cut into its planes."""
+    value = _untiled(value)
     return value if isinstance(value, tuple) else (value[0], value[1])
 
 
@@ -400,8 +464,9 @@ def _apply_step_split(
 
     resolved = mode or complex_mult_forced() or default_step_mode(step)
     if resolved == "block" and xp is not np:
-        _note_step_lowering("block")
-        return _block_step(
+        form = step_prep_form(step, resolved)
+        _note_step_lowering(resolved, form)
+        return (_tiled_block_step if form == "tiled" else _block_step)(
             apair, bpair, step,
             _resolve_step_precision(precision, precision_mode), carry,
         )
@@ -520,7 +585,7 @@ def _block_step(a, b, step, precision, carry):
     from jax import lax
 
     from tnc_tpu.ops.backends import _prep_operand
-    from tnc_tpu.ops.program import step_dims
+    from tnc_tpu.ops.program import step_dims, streamed_side
 
     def prep(value, view, perm, dot_shape, ops):
         def one(plane):
@@ -530,11 +595,11 @@ def _block_step(a, b, step, precision, carry):
             return one(value[0]), one(value[1])
         return jax.vmap(one)(value)  # the plane axis rides in front
 
-    a = prep(a, step.a_view, step.a_perm, step.a_dot, step.a_ops)
-    b = prep(b, step.b_view, step.b_perm, step.b_dot, step.b_ops)
+    a = prep(_untiled(a), step.a_view, step.a_perm, step.a_dot, step.a_ops)
+    b = prep(_untiled(b), step.b_view, step.b_perm, step.b_dot, step.b_ops)
     m, k, n = step_dims(step)
     # ties expand the operand the dot takes first: the plane axis leads
-    expand_a = m < n or (m == n and not step.swap)
+    expand_a = streamed_side(step) == "b"
     sides = (
         (a, step.a_cfirst, len(step.a_dot)),
         (b, step.b_cfirst, len(step.b_dot)),
@@ -577,14 +642,109 @@ def _block_step(a, b, step, precision, carry):
     return out if carry else _as_pair(out)
 
 
-def _note_step_lowering(mode: str) -> None:
+def _tiled_block_step(a, b, step, precision, carry):
+    """:func:`_block_step` for a step whose streamed operand is large
+    (:func:`tnc_tpu.ops.program.stream_prep_form`): the operand's prep
+    ends in the **tiled image** of its joined matrix, ``(M / 128, 2k,
+    128)`` (:func:`tnc_tpu.ops.program.tiled_prep_ops`), and the one
+    real dot contracts axis 1 of it against the other operand's 2 x 2
+    block ``(2k, 2n)``: the transpose writes what the dot reads, and
+    the chip runs no re-tiling pass between them. The same dot, the same
+    k-order and the same products as the matrix form.
+
+    ``carry == "tiled"`` is the walker's word that the next reader
+    streams this result through this function: the result then stays as
+    the dot wrote it, a :class:`TiledValue`, and the reader's transpose
+    starts from it. Every other result leaves in stored order, as from
+    :func:`_block_step`."""
+    import jax.numpy as jnp
+
+    from tnc_tpu.ops.backends import _prep_operand
+    from tnc_tpu.ops.program import (
+        operand_prep,
+        step_dims,
+        streamed_side,
+        tiled_prep_ops,
+    )
+
+    stream_a = streamed_side(step) == "a"
+    s, e = (a, b) if stream_a else (b, a)
+    s_view, s_perm, s_dot, s_cfirst, s_ops = operand_prep(
+        step, "a" if stream_a else "b"
+    )
+    e_view, e_perm, e_dot, e_cfirst, e_ops = operand_prep(
+        step, "b" if stream_a else "a"
+    )
+
+    ops = None
+    if isinstance(s, TiledValue):
+        ops = tiled_prep_ops(
+            s_view, s_perm, s_dot, s_cfirst, s_ops is not None, s.source
+        )
+        s = s.array if ops is not None else s.plain()
+    if ops is None:
+        ops = tiled_prep_ops(s_view, s_perm, s_dot, s_cfirst, s_ops is not None)
+        s = jnp.stack(s) if isinstance(s, tuple) else s
+    image = _prep_operand(jnp, s, None, None, ops[-1][1], ops)
+
+    # the other operand's 2 x 2 block, built as it lies and flattened
+    # once: (2k, 2n), the halves of the result side by side
+    er, ei = (
+        _prep_operand(jnp, part, e_view, e_perm, e_dot, e_ops)
+        for part in _as_pair(e)
+    )
+    ke = 0 if e_cfirst else len(e_dot) - 1
+    m, k, n = step_dims(step)
+    n = min(m, n)
+    block = _as_kl(
+        jnp,
+        jnp.stack(
+            [
+                jnp.concatenate([er, -ei], axis=ke),
+                jnp.concatenate([ei, er], axis=ke),
+            ],
+            axis=1 if e_cfirst else 0,
+        ),
+        (2 * k, 2 * n) if e_cfirst else (2 * n, 2 * k),
+        e_cfirst,
+    )
+    # the result's stored order: the legs of the operand the dot takes
+    # first, then the other's
+    stored = "nrl" if stream_a == step.swap else "rln"
+    if not carry and n > k:  # as _block_step: a dot a half
+        return tuple(
+            jnp.einsum(
+                f"kn,rkl->{stored}", half, image, precision=precision
+            ).reshape(step.out_store)
+            for half in (block[:, :n], block[:, n:])
+        )
+    if stored == "rln":  # the plane leads what is stored: its own axis
+        out = jnp.einsum(
+            "kpn,rkl->prln", block.reshape(2 * k, 2, n), image,
+            precision=precision,
+        )
+    elif carry == "tiled":
+        return TiledValue(
+            jnp.einsum("kq,rkl->rql", block, image, precision=precision),
+            step.out_store,
+        )
+    else:
+        out = jnp.einsum("kq,rkl->qrl", block, image, precision=precision)
+    out = out.reshape((2,) + tuple(step.out_store))
+    return out if carry else _as_pair(out)
+
+
+def _note_step_lowering(mode: str, prep: str = "matrix") -> None:
     """Count the lowering one step was traced under
     (``ops.step_lowering{mode=...}``: once a step a trace, the arithmetic
     that runs after every fallback) — what a record quotes to say how
-    far a program ran in the ``block`` form."""
+    far a program ran in the ``block`` form — and, beside it, where the
+    prep of its streamed operand ended (``ops.step_prep{form=tiled |
+    matrix}``: :func:`_tiled_block_step`, or every other path)."""
     from tnc_tpu import obs
 
     obs.counter_add("ops.step_lowering", mode=mode)
+    obs.counter_add("ops.step_prep", form=prep)
 
 
 def _strassen_step_eligible(step) -> bool:
@@ -1098,15 +1258,25 @@ def kernel_plan_summary(
     (chains collapse to one). ``lowering`` is the whole program by the
     arithmetic each step resolves to (:func:`resolved_step_mode`): steps
     and the shares of steps and of multiply-adds under each mode — how
-    far the program runs in the ``block`` form. ``dtype_bytes`` defaults
+    far the program runs in the ``block`` form. ``prep`` is the program by
+    where the prep of each step's streamed operand ends
+    (:func:`step_prep_form`: ``tiled`` or ``matrix``): steps and the
+    shares of steps and of streamed elements. ``dtype_bytes`` defaults
     to the device path's f32 split-pair width (8 B per complex element).
     The static side of ``bench.py``'s per-bucket MFU report."""
     if policy is None:
         policy = plan_kernels(program)
-    from tnc_tpu.ops.program import step_elems, step_flops, step_prep_elems
+    from tnc_tpu.ops.program import (
+        operand_prep,
+        step_elems,
+        step_flops,
+        step_prep_elems,
+        streamed_side,
+    )
 
     buckets: dict[str, dict] = {}
     lowering: dict[str, dict] = {}
+    prep: dict[str, dict] = {}
     for i, st in enumerate(program.steps):
         b = buckets.setdefault(
             step_bucket(st),
@@ -1126,6 +1296,13 @@ def kernel_plan_summary(
         low = lowering.setdefault(resolved, {"steps": 0, "flops": 0.0})
         low["steps"] += 1
         low["flops"] += step_flops(st)
+        form = prep.setdefault(
+            step_prep_form(st, mode), {"steps": 0, "elems": 0.0}
+        )
+        form["steps"] += 1
+        form["elems"] += float(
+            math.prod(operand_prep(st, streamed_side(st))[0])
+        )
         b["steps"] += 1
         b["flops"] += step_flops(st)
         b["effective_flops"] += effective_step_flops(st, resolved)
@@ -1154,9 +1331,16 @@ def kernel_plan_summary(
     for low in lowering.values():
         low["step_share"] = round(low["steps"] / len(program.steps), 4)
         low["flops_share"] = round(low.pop("flops") / max(total_flops, 1.0), 4)
+    total_elems = sum(form["elems"] for form in prep.values())
+    for form in prep.values():
+        form["step_share"] = round(form["steps"] / len(program.steps), 4)
+        form["elems_share"] = round(
+            form.pop("elems") / max(total_elems, 1.0), 4
+        )
     return {
         "buckets": buckets,
         "lowering": lowering,
+        "prep": prep,
         "dispatches": policy.dispatch_count(),
         "chains": len(policy.chains),
         "chained_steps": len(policy.chained_steps()),
@@ -1274,8 +1458,9 @@ def apply_steps_split(
     dispatches and promotes steps per the kernel ladder; None runs
     every step under the forcing override, else as its shape decides
     (:func:`default_step_mode`). Between two ``block`` steps a value
-    stays ONE ``(2,) + stored`` array (``carry``, see
-    :func:`apply_step_split`); every other lowering takes and hands back
+    stays ONE array (``carry``, see :func:`apply_step_split`: ``(2,) +
+    stored``, or the image a tiled step wrote where the next streams
+    it); every other lowering takes and hands back
     a pair, and so does a step whose result outlives the walk, so a
     caller sees pairs alone.
     ``interpret``: see :func:`apply_step_split`."""
@@ -1284,11 +1469,17 @@ def apply_steps_split(
     )
     # a result is carried only to a later step of this walk: what
     # outlives the walk leaves as the pair a caller expects
-    read_later: set[int] = set()
-    carried = [False] * len(steps)
+    reader: dict[int, int] = {}  # slot -> the next step that reads it
+    carried: list[bool | str] = [False] * len(steps)
     for i in range(len(steps) - 1, -1, -1):
-        carried[i] = steps[i].lhs in read_later
-        read_later.update((steps[i].lhs, steps[i].rhs))
+        step = steps[i]
+        j = reader.get(step.lhs)
+        if j is not None:  # a chained reader's mode is ``naive``
+            carried[i] = _carry_form(
+                steps[j], step.lhs,
+                policy.modes[j] if policy is not None else None,
+            )
+        reader[step.lhs] = reader[step.rhs] = i
     i = 0
     while i < len(steps):
         end = chain_end.get(i)
