@@ -183,6 +183,33 @@ class TestRebind:
         assert out.shape[0] == 2
         assert np.array_equal(out[0], out[1])
 
+    @pytest.mark.parametrize(
+        "mask,target",
+        [("******", None), ("0*0**0", None), ("*0000*", None),
+         ("0*0**0", 2.0**5), ("*0000*", 2.0**4)],
+    )
+    def test_open_axes_come_back_in_result_legs_order(self, mask, target):
+        """Fully open or with bras, sliced or not: one order, the
+        executable's result legs (the plan's, not the qubits'); the
+        template's permutor names the same legs in qubit order."""
+        from tnc_tpu.queries.statevector import statevector
+
+        circuit = make_circuit(n=6, depth=3, seed=7)
+        state = statevector(circuit)
+        bp = bind_circuit(circuit, mask=mask, target_size=target)
+        assert (bp.sliced is not None) == (target is not None)
+        executable = bp.program if bp.sliced is None else bp.sliced.program
+        assert bp.result_legs == tuple(executable.result_legs)
+        by_qubit = bp.template.permutor.target_leg_order
+        assert sorted(bp.result_legs) == sorted(by_qubit)
+        request = mask.replace("0", "1")
+        out = bp.amplitudes([request, mask])
+        assert out.shape == (2,) + (2,) * mask.count("*")
+        axes = [bp.result_legs.index(leg) for leg in by_qubit]
+        for row, bits in zip(out, (request, mask)):
+            index = tuple(slice(None) if c == "*" else int(c) for c in bits)
+            assert np.allclose(np.transpose(row, axes), state[index], atol=1e-12)
+
     def test_invalid_request_names_position(self):
         bp = bind_circuit(make_circuit(seed=0))
         with pytest.raises(ValueError, match="position 2"):

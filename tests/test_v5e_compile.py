@@ -291,6 +291,53 @@ def test_northstar_chunk_compiles_within_hbm(
     assert _total_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_last_chunk_with_a_tensor_valued_sum_compiles(one_chip):
+    """Six open legs (a correlated amplitude batch,
+    ``queries/amplitude_batch.py``): the last chunk's row sum and Kahan
+    fold at a ``(64,)`` stored result, and every chunk before it, for
+    the chip's compiler; the accumulator comes back at the stored
+    result's shape."""
+    from tnc_tpu.builders.sycamore_circuit import sycamore_circuit
+    from tnc_tpu.ops.chunked import _compiled_plan
+    from tnc_tpu.ops.hoist import hoist_sliced_program
+    from tnc_tpu.ops.program import flat_leaf_tensors
+    from tnc_tpu.queries.amplitude_batch import bind_amplitude_batch
+
+    prog = bind_amplitude_batch(
+        sycamore_circuit(24, 8, np.random.default_rng(42)),
+        (3, 8, 9, 14, 19, 23), target_size=2.0**14,
+    )
+    sp = prog.bound.sliced
+    assert sp is not None and sp.slicing.num_slices >= 8
+    hp = hoist_sliced_program(sp)
+    stored = tuple(hp.residual.program.stored_result_shape)
+    assert int(np.prod(stored)) == 64
+    shapes = [
+        leaf.data.into_data().shape
+        for leaf in flat_leaf_tensors(prog.bound.template.network)
+    ]
+    chunks, chunk_fns, row_modes = _compiled_plan(
+        hp.residual, 8, 64, True, "float32", interpret=False
+    )
+    assert row_modes[-1] == "loop"
+    idx = jax.ShapeDtypeStruct(
+        (8, len(sp.slicing.dims)), jnp.int32, sharding=one_chip
+    )
+    state = dict(enumerate(_residual_inputs(hp, shapes, one_chip)))
+    for chunk, fn in zip(chunks[:-1], chunk_fns[:-1]):
+        ins = tuple(state[slot] for slot in chunk.in_slots)
+        fn.lower(ins, idx).compile()
+        outs = _on(one_chip, jax.eval_shape(fn, ins, idx))
+        state.update(zip(chunk.out_slots, outs))
+    part = jax.ShapeDtypeStruct(stored, jnp.float32, sharding=one_chip)
+    acc = ((part, part), (part, part))
+    ins = tuple(state[slot] for slot in chunks[-1].in_slots)
+    compiled = chunk_fns[-1].lower(ins, idx, acc).compile()
+    assert _total_bytes(compiled) < V5E_HBM_BYTES
+    out = jax.eval_shape(chunk_fns[-1], ins, idx, acc)
+    assert {leaf.shape for leaf in jax.tree.leaves(out)} == {stored}
+
+
 def test_block_rule_moves_fewer_bytes_than_gauss(one_chip):
     """Why the rule: one slice of a Sycamore-53 m=14 residual (Greedy,
     sliced to 2^25: contractions of 2 to 64 on all but a few steps)

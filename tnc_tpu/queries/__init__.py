@@ -2,7 +2,7 @@
 expectation values and marginal sweeps as first-class query types.
 
 Everything the stack serves is a contraction of one circuit's tensor
-networks; this package adds the three queries a real user fleet asks
+networks; this package adds the queries a real user fleet asks
 for beyond single amplitudes, all riding the existing planning,
 rebinding, batching and serving machinery:
 
@@ -14,6 +14,12 @@ rebinding, batching and serving machinery:
   networks with rebindable observable leaves; Pauli-sum terms batch
   like bras through one compiled program; ``value_and_grad`` through
   the autodiff-capable jax executors.
+- **Correlated amplitude batches** (``amplitude_batch.py``) — ``k``
+  output qubits left OPEN, so one contraction yields the ``2^k``
+  amplitudes of the bitstrings that share the other bits, each at its
+  own bitstring whatever order the plan leaves the axes in; frugal
+  rejection sampling and linear XEB on top. A batch at 53 qubits is
+  hours of slices (``slice_range=``); the service gets no handler.
 - **Marginal sweeps** (``marginal.py``) — wildcard patterns contract
   as traced sandwich legs, returning marginal probabilities of the
   determined positions (this is ``amplitude_sweep``'s lifted ``'*'``
@@ -28,6 +34,13 @@ rebinding, batching and serving machinery:
 See ``docs/serving.md`` ("Query types").
 """
 
+from tnc_tpu.queries.amplitude_batch import (  # noqa: F401
+    AmplitudeBatchProgram,
+    bind_amplitude_batch,
+    frugal_rejection_sample,
+    linear_xeb,
+    sample_from_batches,
+)
 from tnc_tpu.queries.expectation import (  # noqa: F401
     ExpectationProgram,
     bind_expectation,

@@ -9,6 +9,11 @@ handler structures plan through :func:`~tnc_tpu.serve.rebind.
 bind_template` with the service's plan cache, so repeat structures
 are cache hits with zero pathfinding, exactly like amplitude serving.
 
+A correlated amplitude batch (:mod:`tnc_tpu.queries.amplitude_batch`)
+has no handler here: at the widths it is for, a batch is hours of slices
+a request, and the service's sliced path is one slice loop a request;
+it is called directly, a slice range at a time.
+
 Attach with :func:`attach_query_handlers` (or
 ``ContractionService.from_circuit(..., queries=True)``):
 

@@ -148,6 +148,18 @@ class BoundProgram:
     def result_shape(self) -> tuple[int, ...]:
         return tuple(self.program.result_shape)
 
+    @property
+    def result_legs(self) -> tuple[int, ...]:
+        """The open legs in the order :meth:`amplitudes` returns their
+        axes: the result-leg order of the executable that runs, which is
+        the slice loop's program for a sliced structure (its pair steps
+        are built without the sliced legs, so its order need not be
+        ``program.result_legs``) and the flat program otherwise. The
+        planner chooses it; ``template.permutor`` lists the same legs in
+        qubit order."""
+        executable = self.program if self.sliced is None else self.sliced.program
+        return tuple(executable.result_legs)
+
     def _serving_arrays(self, backend) -> list[np.ndarray]:
         """The request-invariant input arrays for ``backend``: the bound
         leaf data, or — under cross-request reuse — the residual's
@@ -173,9 +185,12 @@ class BoundProgram:
     ) -> np.ndarray:
         """Amplitudes for a batch of request bitstrings, one dispatch.
 
-        Returns ``(B,) + result_shape`` (open-leg axes in the program's
-        result-leg order — scalar amplitudes for fully determined
-        templates). On the numpy backend the batched result
+        Returns ``(B,) + result_shape`` — scalar amplitudes for fully
+        determined templates. Open-leg axes come back in
+        :attr:`result_legs` order, on every branch (fully open or with
+        bras, sliced or not): an order the plan chooses, NOT qubit
+        order. :mod:`tnc_tpu.queries.amplitude_batch` is the entry that
+        returns them by qubit. On the numpy backend the batched result
         bit-compares to B sequential singleton contractions.
         """
         return self.amplitudes_det(
@@ -193,7 +208,10 @@ class BoundProgram:
         """:meth:`amplitudes` over already-validated determined-position
         bit strings (``template.request_bits`` output) — the service
         dispatches these directly so per-request validation runs once,
-        at admission, not again on the batching hot path.
+        at admission, not again on the batching hot path. Open-leg axes
+        follow the request axis in :attr:`result_legs` order (see
+        :meth:`amplitudes`; by qubit:
+        :mod:`tnc_tpu.queries.amplitude_batch`).
 
         ``slice_range=(lo, hi)`` (sliced structures only): each
         request's amplitude is the **partial sum** over that contiguous
