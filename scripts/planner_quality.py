@@ -150,6 +150,7 @@ def _plan_predicted_seconds(tn, result, target_size, objective) -> float:
                 inputs, result.ssa_path.toplevel, target_size,
                 reconf_rounds=1, step_budget=None,
                 final_rounds=2, final_budget=None,
+                fuse=False,  # a score of the search, in multiply-adds
             )
         except ValueError:
             return math.inf
@@ -268,6 +269,7 @@ def measure_sliced_gate_network(name: str) -> dict:
             reconf_rounds=1, step_budget=None,
             final_rounds=2, final_budget=None,
             seed_slices=seed.legs if seed is not None else None,
+            fuse=False,  # a score of the search, in multiply-adds
         )
         plan_s = time.perf_counter() - t0
         total = sliced_flops(inputs, pairs, slicing)
@@ -405,7 +407,8 @@ def measure(depth: int, seed: int, ntrials: int, target_log2: float) -> dict:
     while True:
         try:
             pairs, slicing = slice_and_reconfigure(
-                list(tn.tensors), hyper.ssa_path.toplevel, slice_target
+                list(tn.tensors), hyper.ssa_path.toplevel, slice_target,
+                fuse=False,  # a score of the search, in multiply-adds
             )
             break
         except ValueError:

@@ -405,7 +405,9 @@ def test_plan_summary_and_counter_report_both_forms(
         }
         for form in ("matrix", "tiled")
     }
-    assert prep["tiled"]["elems_share"] > 0.5 > prep["tiled"]["step_share"]
+    # 0.53 of the streamed elements before stem fusion joined four of
+    # this plan's six large steps into two (PR 36): 0.41 of what is left
+    assert prep["tiled"]["elems_share"] > 0.4 > prep["tiled"]["step_share"]
     # one count a traced step, beside ops.step_lowering
     first = forms.index("tiled")
     for st in steps[first - 1:first + 1]:

@@ -219,6 +219,7 @@ class Hyperoptimizer(Pathfinder):
                     step_budget=None,
                     final_rounds=2,
                     final_budget=None,
+                    fuse=False,  # a ranking by multiply-adds
                 )
             except ValueError:
                 sliced_cache[key] = math.inf
@@ -316,6 +317,7 @@ class Hyperoptimizer(Pathfinder):
                         final_rounds=2,
                         final_budget=None,
                         cost_model=cost_model,
+                        fuse=False,  # a ranking by multiply-adds
                     )
                 except ValueError:
                     replace2 = None

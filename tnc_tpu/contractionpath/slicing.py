@@ -25,7 +25,7 @@ slicings that keep a large hoistable stem.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Sequence
 
 from tnc_tpu import obs
@@ -54,6 +54,10 @@ class Slicing:
 
     legs: tuple[int, ...]
     dims: tuple[int, ...]
+    # what stem fusion did to the path this slicing was handed out with
+    # (:func:`slice_and_reconfigure`); a note for the program's summary,
+    # no part of the slicing's identity and not persisted
+    fusion: dict | None = field(default=None, compare=False, repr=False)
 
     @property
     def num_slices(self) -> int:
@@ -519,6 +523,7 @@ def slice_and_reconfigure(
     max_leg_candidates: int = 48,
     cost_model=None,
     seed_slices: "Sequence[int] | Slicing | None" = None,
+    fuse: bool = True,
 ) -> tuple[list[tuple[int, int]], Slicing]:
     """Interleaved slicing + subtree reconfiguration (cotengra's
     ``slicing_reconf`` approach): repeatedly slice a leg of the peak
@@ -552,12 +557,23 @@ def slice_and_reconfigure(
     cached plan's slice set warm-starts replanning the same structure.
     Invalid seeds (open legs, dim 1, unknown) are skipped; the loop
     still extends the set when the seeded peak misses the target.
+
+    The path handed out is then **re-associated for passes over
+    memory** (:func:`~tnc_tpu.contractionpath.stem_fusion.
+    fuse_stem_operands`, in the sliced size model): small operands that
+    meet a value of 2^18 elements or more one after another are
+    multiplied together first, so its multiply-adds are no longer the
+    search's minimum (``Slicing.fusion`` says by how much). ``fuse`` is
+    internal: a search that calls this function to RANK candidates by
+    sliced multiply-adds passes ``fuse=False`` and ranks as before; the
+    slice set is the same either way.
     """
     from tnc_tpu.contractionpath.contraction_path import (
         ContractionPath,
         ssa_replace_ordering,
     )
     from tnc_tpu.contractionpath.contraction_tree import ContractionTree
+    from tnc_tpu.contractionpath.stem_fusion import fuse_stem_operands
 
     tree = ContractionTree.from_ssa_path(inputs, list(ssa_path))
     tree.dims = dict(tree.dims)  # private copy: sliced legs become dim 1
@@ -678,12 +694,14 @@ def slice_and_reconfigure(
         if refined_peak <= target_size:
             tree = refined
 
-    replace = ssa_replace_ordering(
-        ContractionPath.simple(tree.to_ssa_path())
-    ).toplevel
+    ssa = tree.to_ssa_path()
+    fusion = None
+    if fuse:
+        ssa, fusion = fuse_stem_operands(inputs, ssa, removed)
+    replace = ssa_replace_ordering(ContractionPath.simple(ssa)).toplevel
     ordered = sorted(removed)
     return list(replace), Slicing(
-        tuple(ordered), tuple(dims[l] for l in ordered)
+        tuple(ordered), tuple(dims[l] for l in ordered), fusion
     )
 
 

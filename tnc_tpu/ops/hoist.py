@@ -184,6 +184,7 @@ def hoist_sliced_program(sp: SlicedProgram) -> HoistedProgram:
         result_shape=prog.result_shape,
         stored_result_shape=prog.stored_result_shape,
         canonical_legs=prog.canonical_legs,
+        fusion=prog.fusion,
     )
     residual = SlicedProgram(
         residual_program, sp.slicing, tuple(res_slot_slices)

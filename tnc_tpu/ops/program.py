@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 from tnc_tpu.contractionpath.contraction_path import ContractionPath
 from tnc_tpu.tensornetwork.tensor import CompositeTensor, LeafTensor, Tensor
@@ -955,6 +955,10 @@ class ContractionProgram:
     # reference leg order (the ``^``-fold, ``contraction.rs:70-86``);
     # public APIs permute the buffer to this order host-side
     canonical_legs: tuple[int, ...] = ()
+    # what stem fusion did to the plan this program was built from (the
+    # report of `contractionpath.stem_fusion.fuse_stem_operands`), for
+    # `kernel_plan_summary`; a note, no part of the program's identity
+    fusion: dict | None = field(default=None, compare=False, repr=False)
 
     def __post_init__(self):
         if not self.stored_result_shape:

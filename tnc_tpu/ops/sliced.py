@@ -30,7 +30,7 @@ slice sets with the same formula
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Sequence
 
 import numpy as np
@@ -129,6 +129,8 @@ def build_sliced_program(
 
     reduced_tn = reduce_network(tn.tensors)
     program = build_program(reduced_tn, contract_path)
+    if slicing.fusion is not None:
+        program = replace(program, fusion=slicing.fusion)
     return SlicedProgram(program, slicing, tuple(slot_slices))
 
 

@@ -1261,7 +1261,13 @@ def kernel_plan_summary(
     far the program runs in the ``block`` form. ``prep`` is the program by
     where the prep of each step's streamed operand ends
     (:func:`step_prep_form`: ``tiled`` or ``matrix``): steps and the
-    shares of steps and of streamed elements. ``dtype_bytes`` defaults
+    shares of steps and of streamed elements. ``fusion`` is what stem
+    fusion did to the plan the program was built from
+    (:func:`tnc_tpu.contractionpath.stem_fusion.fuse_stem_operands`'s
+    report, carried as ``program.fusion``): groups, large steps that
+    became small products, and large steps, the elements they stream
+    and the multiply-adds of a slice as ``[before, after]``; ``None``
+    for a program whose plan never passed it. ``dtype_bytes`` defaults
     to the device path's f32 split-pair width (8 B per complex element).
     The static side of ``bench.py``'s per-bucket MFU report."""
     if policy is None:
@@ -1341,6 +1347,7 @@ def kernel_plan_summary(
         "buckets": buckets,
         "lowering": lowering,
         "prep": prep,
+        "fusion": dict(program.fusion) if program.fusion else None,
         "dispatches": policy.dispatch_count(),
         "chains": len(policy.chains),
         "chained_steps": len(policy.chained_steps()),

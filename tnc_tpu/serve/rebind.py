@@ -31,7 +31,7 @@ from __future__ import annotations
 import logging
 import threading
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Iterable, Sequence
 
 import numpy as np
@@ -410,7 +410,15 @@ def plan_structure(
     passes are bounded in rounds and seconds; spread over a served
     template's raw leaves (a ket, an ``rx`` and a closure to every
     core) they reach a fraction of the tree, and a 1230-leaf sandwich
-    sliced to 2^26 slices where its 500 cores slice to 2^20."""
+    sliced to 2^26 slices where its 500 cores slice to 2^20.
+
+    The path handed out, sliced or not, is **re-associated for passes
+    over memory** (:mod:`tnc_tpu.contractionpath.stem_fusion`): small
+    operands that meet a value of 2^18 elements or more one after
+    another are multiplied together first. Its multiply-adds are
+    therefore no longer the search's minimum (``result`` still holds
+    the search's own count; ``program.fusion`` says what changed); a
+    plan with no such value comes back as the search left it."""
     from tnc_tpu.contractionpath.contraction_path import (
         ContractionPath,
         replace_ssa_ordering,
@@ -425,7 +433,9 @@ def plan_structure(
     planned = tn if cores is None else cores[3]
     result = pathfinder.find_path(planned)
     slicing = None
-    replace_pairs = None  # over `planned`, where the slicer changed the path
+    fusion = None  # the report of stem fusion, where it ran
+    # over `planned`, where the slicer or stem fusion changed the path
+    replace_pairs = None
     if target_size is not None and result.size > target_size:
         from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
 
@@ -435,8 +445,22 @@ def plan_structure(
             cost_model=cost_model,
             seed_slices=seed.legs if seed is not None else None,
         )
+        fusion = slicing.fusion
         if slicing.num_slices <= 1:
             slicing = None
+    elif not result.ssa_path.nested and all(
+        t.is_leaf() for t in planned.tensors
+    ):
+        # unsliced (the served template): the same re-association
+        from tnc_tpu.contractionpath.stem_fusion import fuse_stem_operands
+
+        fused, fusion = fuse_stem_operands(
+            list(planned.tensors), result.ssa_path.toplevel
+        )
+        if fusion["groups"]:
+            replace_pairs = ssa_replace_ordering(
+                ContractionPath.simple(fused)
+            ).toplevel
     if cores is None:
         path = (
             result.replace_path()
@@ -463,6 +487,8 @@ def plan_structure(
             len(tn.tensors),
         )
     program = build_program(tn, path)
+    if fusion is not None:
+        program = replace(program, fusion=fusion)
     sliced = (
         build_sliced_program(tn, path, slicing)
         if slicing is not None

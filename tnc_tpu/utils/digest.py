@@ -16,7 +16,8 @@ processes.
   by their *encoded key bytes* (not hash order), sets likewise;
 - dataclasses (e.g. :class:`~tnc_tpu.ops.program.PairStep`,
   :class:`~tnc_tpu.contractionpath.slicing.Slicing`) encode as their
-  class name + field name/value pairs;
+  class name + field name/value pairs (fields declared
+  ``compare=False`` are no part of the identity and are left out);
 - floats encode as IEEE-754 big-endian doubles, ints as decimal text,
   enums as class + value — never ``repr``.
 
@@ -59,8 +60,12 @@ def _encode(obj: Any, out: list[bytes]) -> None:
         _encode((type(obj).__name__, obj.value), out)
         out.append(b"E")
     elif dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+        # a field declared compare=False is no part of the value's
+        # identity (a note, a cache) and none of its digest
         fields = tuple(
-            (f.name, getattr(obj, f.name)) for f in dataclasses.fields(obj)
+            (f.name, getattr(obj, f.name))
+            for f in dataclasses.fields(obj)
+            if f.compare
         )
         _encode((type(obj).__name__, fields), out)
         out.append(b"D")
