@@ -117,7 +117,7 @@ def test_step_eligibility_staged_and_batch(monkeypatch):
     reason ``batch`` (counted, never an exception)."""
     from tnc_tpu import obs
     from tnc_tpu.ops.split_complex import (
-        _try_fused_transpose_step,
+        _step_lowering,
         fused_transpose_ineligible_reason,
     )
 
@@ -141,7 +141,10 @@ def test_step_eligibility_staged_and_batch(monkeypatch):
             jnp.asarray(_rand(step.b_view, rng)),
             jnp.asarray(_rand(step.b_view, rng)),
         )
-        assert _try_fused_transpose_step(apair, bpair, step, None) is None
+        # routed away: the prep + naive dots, decided before any op
+        assert _step_lowering(apair, bpair, step, "fused_transpose") == (
+            "naive", "matrix",
+        )
         counters = obs.get_registry().snapshot()["counters"]
     finally:
         obs.configure(enabled=False)

@@ -45,6 +45,7 @@ class Trace:
         self.spans = []  # (line id, name, start_ns, end_ns, stats)
         self.host_events = []  # (name, start_ns, end_ns), any host event
         self.modules = set()
+        self.ops = set()  # (module, op) of every XLA op event
         for plane in ProfileData.from_file(path).planes:
             for line_id, line in enumerate(plane.lines):
                 for ev in line.events:
@@ -53,6 +54,8 @@ class Trace:
                     stats = dict(ev.stats)
                     if "hlo_module" in stats:
                         self.modules.add(stats["hlo_module"])
+                        if "hlo_op" in stats:
+                            self.ops.add((stats["hlo_module"], stats["hlo_op"]))
                     if not plane.name.startswith("/host:"):
                         continue
                     self.host_events.append((ev.name, start, end))
@@ -358,6 +361,28 @@ def test_path_runs_programs_named_by_role(path_trace):
     assert not closures & trace.modules, sorted(closures & trace.modules)
 
 
+@pytest.mark.parametrize("path_trace", sorted(EXPECTED), indirect=True)
+def test_every_traced_op_of_the_programs_is_a_key_of_the_op_table(path_trace):
+    """The text asked for afterwards is the text of the executable that
+    ran: every ``module/op`` the session saw of a ``jit_tnc_*`` program
+    is a key of the table, and no module reads as stale."""
+    path, trace, _ = path_trace
+    ran = {(m, op) for m, op in trace.ops if m.startswith("jit_tnc_")}
+    assert {m for m, _ in ran} >= EXPECTED[path][2]
+    table = obs.device_op_table({m for m, _ in ran})
+    for module, op in sorted(ran):
+        variants = table[module]
+        assert all(v["status"] == "ok" for v in variants), (module, variants[0]["why"])
+        assert any(op in v["ops"] for v in variants), (module, op)
+    ops = [(f"{m}/{op}", 1.0) for m, op in sorted(ran)]
+    joined = obs.step_seconds(ops, table)
+    assert joined["unknown_ops"] == []
+    # a second asking gives the same names: two lowerings agree
+    again = obs.device_op_table({m for m, _ in ran})
+    assert {m: [sorted(v["ops"]) for v in vs] for m, vs in again.items()} == {
+        m: [sorted(v["ops"]) for v in vs] for m, vs in table.items()}
+
+
 @pytest.mark.parametrize("path_trace", ["chunked"], indirect=True)
 def test_residual_span_says_how_the_rows_ran(path_trace):
     _, trace, _ = path_trace
@@ -383,6 +408,9 @@ def test_service_stats_total_the_dispatch_boundaries(path_trace):
     assert row["leaves_placed"] == place[4]["placed"] >= 5  # the bras
     assert row["leaf_hits"] == place[4]["hits"]
     assert place[4]["placed"] + place[4]["hits"] == place[4]["n"]
+
+
+_ALIVE: list = []
 
 
 def _lowered(program_kind: str):
@@ -419,6 +447,7 @@ def _lowered(program_kind: str):
             sp, make_mesh(4), "slices", "complex64", True, "float32",
             hoist=True,
         )
+        _ALIVE[:] = [fn]  # no cache holds it, and the op table holds it weakly
         return fn.lower(*full)
     hp = hoist_sliced_program(sp)
     assert not hp.is_noop
@@ -445,15 +474,125 @@ def _lowered(program_kind: str):
     return chunk_fns[-1].lower(ins, idx, acc)
 
 
-@pytest.mark.parametrize("program_kind", [
+PROGRAM_KINDS = [
     "tnc_program", "tnc_program_batched", "tnc_prelude", "tnc_residual_c00",
     "tnc_residual_last", "tnc_spmd_slices",
-])
-def test_lowered_module_name_and_bucket_scopes(program_kind):
+]
+
+
+@pytest.mark.parametrize("program_kind", PROGRAM_KINDS)
+def test_lowered_module_name_and_step_scopes(program_kind):
     text = _lowered(program_kind).as_text(debug_info=True)
     assert f"module @jit_{program_kind} " in text
-    # every step's ops sit under its shape bucket's named scope (the
-    # ring's 4x4 steps are all under the fused kernel's flop floor)
     assert f"jit({program_kind})/" in text
-    assert "tnc.small" in text  # "vmap(tnc.small)/" when batched
-    assert "tnc.stem" not in text and "tnc.medium" not in text
+    # every step's ops sit under the step's own named scope (the ring's
+    # 4x4 steps are all small one-dot steps in the matrix form), with
+    # the three sub-scopes; "vmap(tnc.step....)/" when batched
+    assert "tnc.step.0000.small.block.matrix" in text
+    for part in ("prep", "dot", "out"):
+        assert f".small.block.matrix/{part}/" in text or (
+            f".small.block.matrix)/{part}/" in text
+        ), part
+    # the shape buckets name no scope any more
+    for bucket in ("tnc.small", "tnc.medium", "tnc.stem"):
+        assert bucket + "/" not in text and bucket + ")" not in text
+    # the non-step work of a slice is under scopes of its own
+    if program_kind in ("tnc_residual_c00", "tnc_residual_last"):
+        assert "tnc.chunk.io/" in text and "tnc.slice.index/" in text
+    if program_kind in ("tnc_residual_last", "tnc_spmd_slices"):
+        assert "tnc.slice.sum/" in text
+
+
+def _dot_holding_ops(text: str) -> set:
+    """Instructions of an optimized text that are a dot or convolution,
+    or a fusion whose computation holds one: read off the text with
+    nothing of the program's parser."""
+    import re
+
+    holding, current, ops = set(), None, set()
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$", line)
+        if head:
+            current = head.group(1)
+        elif re.search(r"\s(dot|convolution)\(", line):
+            holding.add(current)
+            ops.add(re.match(r"\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=", line).group(1))
+    for line in text.splitlines():
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if called and called.group(1) in holding:
+            ops.add(re.match(r"\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=", line).group(1))
+    return ops
+
+
+@pytest.mark.parametrize("program_kind", PROGRAM_KINDS)
+def test_compiled_dots_are_in_the_op_table_under_one_step(program_kind):
+    """Each program kind compiled on the CPU: the table asked for
+    afterwards is ``ok``, holds every op of the optimized text, and puts
+    every dot-holding op under exactly one step, part ``dot``."""
+    from tnc_tpu.obs import op_table
+
+    lowered = _lowered(program_kind)
+    text = lowered.compile().as_text()
+    variants = obs.device_op_table([f"jit_{program_kind}"])[f"jit_{program_kind}"]
+    ops = op_table.parse_hlo_ops(text)["ops"]
+    variant = next(v for v in variants if set(v["ops"]) == set(ops))
+    assert variant["status"] == "ok", variant["why"]
+    dots = _dot_holding_ops(text) & set(ops)  # those that run as ops
+    assert dots
+    for op in dots:
+        entry = variant["ops"][op]
+        assert len(entry["steps"]) == 1 and entry["part"] == "dot", (op, entry)
+    # the static facts beside it: one row a step, in scope order
+    facts = variant["steps"]
+    assert [f["number"] for f in facts] == list(range(len(facts)))
+    seen = {n for e in variant["ops"].values() for n in e["steps"]}
+    assert seen == {f["number"] for f in facts}
+    for f in facts:
+        assert f["scope"] == f"tnc.step.{f['number']:04d}.small.block.matrix"
+        assert f["elements"] > 0 and f["macs"] > 0 and f["k"] >= 1
+    runs = {f["runs"] for f in facts}
+    want = {
+        "tnc_residual_c00": {"row"}, "tnc_residual_last": {"row"},
+        "tnc_spmd_slices": {"once", "row"},
+    }.get(program_kind, {"once"})
+    assert runs == want
+    # the ring's four steps, whichever program runs them: the prelude
+    # takes plan step 0, the residual the rest
+    plan = sorted(f["plan_index"] for f in facts)
+    assert plan == {
+        "tnc_program": [0, 1, 2, 3], "tnc_program_batched": [0, 1, 2, 3],
+        "tnc_prelude": [0], "tnc_spmd_slices": [0, 1, 2, 3],
+    }.get(program_kind, plan)
+    assert set(plan) <= {0, 1, 2, 3}
+
+
+def _forget_traced_programs():
+    """Drop every cache that would hand back a function traced before:
+    the next `_lowered` traces anew."""
+    from tnc_tpu.ops import backends, chunked
+
+    backends._PROGRAM_JIT_CACHE.clear()
+    chunked._PLAN_CACHE.clear()
+    chunked._PRELUDE_CACHE.clear()
+
+
+@pytest.mark.parametrize("program_kind", PROGRAM_KINDS)
+def test_the_program_is_the_same_without_the_scopes(program_kind, monkeypatch):
+    """The scopes are metadata: with every one patched to a no-op the
+    lowered text without debug info is byte for byte the same."""
+    import contextlib
+
+    from tnc_tpu.obs import op_table
+
+    _forget_traced_programs()
+    with_scopes = _lowered(program_kind)
+    assert "tnc.step." in with_scopes.as_text(debug_info=True)
+    monkeypatch.setattr(
+        op_table, "named_scope", lambda name: contextlib.nullcontext()
+    )
+    _forget_traced_programs()
+    without = _lowered(program_kind)
+    assert "tnc.step." not in without.as_text(debug_info=True)
+    assert "tnc.slice." not in without.as_text(debug_info=True)
+    assert without.as_text() == with_scopes.as_text()
+    _forget_traced_programs()

@@ -453,6 +453,31 @@ def test_slice_spmd_compiles_for_four_chips(topo):
     compiled = fn.lower(*_pair_specs(shapes, replicated)).compile()
     assert "all-reduce" in compiled.as_text()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
+    # the program's op table of the chip's own optimized text, asked for
+    # afterwards on the recorded arguments (replicated over the mesh):
+    # the same executable, every op a key, the steps' scopes its own
+    from tnc_tpu import obs
+    from tnc_tpu.obs.op_table import parse_hlo_ops
+
+    ops = set(parse_hlo_ops(compiled.as_text())["ops"])
+    variants = obs.device_op_table(["jit_tnc_spmd_slices"])
+    # (other SPMD programs of this process may still be remembered)
+    (variant,) = [
+        v for v in variants["jit_tnc_spmd_slices"] if set(v["ops"]) == ops
+    ]
+    assert variant["status"] == "ok", variant["why"]
+    by_opcode = {}
+    for entry in variant["ops"].values():
+        by_opcode.setdefault(entry["opcode"], []).append(entry)
+    (reduce,) = by_opcode["all-reduce"]
+    assert reduce["owners"] == ["tnc.slice.sum"]
+    fusions = by_opcode["fusion"]
+    owned = [e for e in fusions if len(e["owners"]) == 1]
+    assert len(owned) >= 0.95 * len(fusions)
+    assert {f["runs"] for f in variant["steps"]} == {"once", "row"}
+    assert {n for e in owned for n in e["steps"]} <= {
+        f["number"] for f in variant["steps"]
+    }
 
 
 # -- the tiled prep of a block step's streamed operand ---------------------

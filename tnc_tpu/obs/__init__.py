@@ -55,6 +55,12 @@ from tnc_tpu.obs.calibrate import (  # noqa: F401
     fit_device_model,
     step_samples,
 )
+from tnc_tpu.obs.op_table import (  # noqa: F401
+    device_op_table,
+    parse_hlo_ops,
+    step_scope_name,
+    step_seconds,
+)
 from tnc_tpu.obs.slo import (  # noqa: F401
     BurnWindow,
     DriftDetector,

@@ -31,6 +31,7 @@ from typing import Any, Iterable, Sequence
 import numpy as np
 
 from tnc_tpu import obs
+from tnc_tpu.obs import op_table
 from tnc_tpu.ops.program import ContractionProgram
 from tnc_tpu.resilience import faultinject as _faults
 from tnc_tpu.resilience import retry as _retry
@@ -120,7 +121,7 @@ def _prep_operand(xp, buf, view, perm, dot_shape, ops=None):
     return v.reshape(dot_shape)
 
 
-def apply_step(xp, a: Any, b: Any, step) -> Any:
+def apply_step(xp, a: Any, b: Any, step, number: int = 0) -> Any:
     """One pairwise contraction; the single source of truth for the step
     kernel, shared by the whole-program, sliced-loop, and chunked
     executors.
@@ -128,39 +129,66 @@ def apply_step(xp, a: Any, b: Any, step) -> Any:
     Device path: one ``lax.dot_general`` contracting the single leading
     ``k`` dim of both operands — XLA performs no internal relayout and
     every materialized buffer keeps a large minor dim (see
-    :mod:`tnc_tpu.ops.program`). Host path: the equivalent 2-D matmul."""
+    :mod:`tnc_tpu.ops.program`), traced under the step's named scope
+    (mode ``complex``: one dot of a complex dtype; ``number`` and the
+    sub-scopes as :func:`tnc_tpu.ops.split_complex.apply_step_split`).
+    Host path: the equivalent 2-D matmul."""
+    if xp is not np:
+        from tnc_tpu.ops.program import step_size_class
+
+        scope = op_table.step_scope_name(
+            number, step_size_class(step), "complex", "matrix"
+        )
+        op_table.note_step(number, scope)
+        with op_table.named_scope(scope):
+            return _device_step(xp, a, b, step)
     av = _prep_operand(xp, a, step.a_view, step.a_perm, step.a_dot, step.a_ops)
     bv = _prep_operand(xp, b, step.b_view, step.b_perm, step.b_dot, step.b_ops)
-    if xp is np:
-        a2 = (
-            av.reshape(step.a_mat)
-            if step.a_cfirst
-            else av.reshape(step.a_mat[::-1]).T
-        )  # (k, m)
-        b2 = (
-            bv.reshape(step.b_mat)
-            if step.b_cfirst
-            else bv.reshape(step.b_mat[::-1]).T
-        )  # (k, n)
-        out = (b2.T @ a2) if step.swap else (a2.T @ b2)
-        return out.reshape(step.out_store)
-    from jax import lax
-
-    ca = (0,) if step.a_cfirst else (len(step.a_dot) - 1,)
-    cb = (0,) if step.b_cfirst else (len(step.b_dot) - 1,)
-    if step.swap:
-        out = lax.dot_general(bv, av, ((cb, ca), ((), ())))
-    else:
-        out = lax.dot_general(av, bv, ((ca, cb), ((), ())))
+    a2 = (
+        av.reshape(step.a_mat)
+        if step.a_cfirst
+        else av.reshape(step.a_mat[::-1]).T
+    )  # (k, m)
+    b2 = (
+        bv.reshape(step.b_mat)
+        if step.b_cfirst
+        else bv.reshape(step.b_mat[::-1]).T
+    )  # (k, n)
+    out = (b2.T @ a2) if step.swap else (a2.T @ b2)
     return out.reshape(step.out_store)
 
 
-def apply_steps(xp, steps, state) -> None:
+def _device_step(xp, a, b, step):
+    from jax import lax
+
+    with op_table.named_scope("prep"):
+        av = _prep_operand(
+            xp, a, step.a_view, step.a_perm, step.a_dot, step.a_ops
+        )
+        bv = _prep_operand(
+            xp, b, step.b_view, step.b_perm, step.b_dot, step.b_ops
+        )
+    ca = (0,) if step.a_cfirst else (len(step.a_dot) - 1,)
+    cb = (0,) if step.b_cfirst else (len(step.b_dot) - 1,)
+    with op_table.named_scope("dot"):
+        if step.swap:
+            out = lax.dot_general(bv, av, ((cb, ca), ((), ())))
+        else:
+            out = lax.dot_general(av, bv, ((ca, cb), ((), ())))
+    with op_table.named_scope("out"):
+        return out.reshape(step.out_store)
+
+
+def apply_steps(xp, steps, state, numbers=None) -> None:
     """The one walker of complex-dtype steps: run ``steps`` in order
     over ``state`` (a list or dict, slot -> buffer), in place; a
-    consumed slot is left ``None`` (freed eagerly)."""
-    for step in steps:
-        state[step.lhs] = apply_step(xp, state[step.lhs], state[step.rhs], step)
+    consumed slot is left ``None`` (freed eagerly). ``numbers``: each
+    step's number in the program being traced (default: its index)."""
+    for i, step in enumerate(steps):
+        state[step.lhs] = apply_step(
+            xp, state[step.lhs], state[step.rhs], step,
+            numbers[i] if numbers is not None else i,
+        )
         state[step.rhs] = None
 
 
@@ -378,15 +406,41 @@ def lanemix_env() -> tuple:
     )
 
 
-def named_jit(fn, name: str, **jit_kwargs):
+def named_jit(fn, name: str, steps=None, sharding=None, **jit_kwargs):
     """``jax.jit(fn)`` under a stable name by role: the module is
     ``jit_<name>`` in the lowered text and in the profiler's trace,
     whatever Python happened to call the closure. The ``tnc_*`` names
-    are read by ``perf/metrics`` (device seconds per program)."""
+    are read by ``perf/metrics`` (device seconds per program).
+
+    The program is also remembered by that name
+    (:func:`tnc_tpu.obs.op_table.register`) with what is needed to ask
+    it for its compiled text later: the jitted callable, the abstract
+    arguments of each trace — recorded inside the traced function, so
+    only when JAX traces, never per call — and ``steps``, the step list
+    it was built from as ``(step, "row" | "once", index in the plan
+    handed out)`` triples in the order of the steps' scope numbers.
+    ``sharding`` is put on the abstract arguments when the text is
+    asked for (:func:`tnc_tpu.obs.device_op_table`)."""
+    import weakref
+
     import jax
 
-    fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn, **jit_kwargs)
+    record = op_table.register(name, steps, sharding)
+
+    def traced(*args, **kwargs):
+        with op_table.tracing(record, args, kwargs):
+            return fn(*args, **kwargs)
+
+    traced.__name__ = traced.__qualname__ = name
+    jitted = jax.jit(traced, **jit_kwargs)
+    record.jitted = weakref.ref(jitted)
+    return jitted
+
+
+def program_step_list(program: ContractionProgram, runs: str = "once") -> list:
+    """A whole program's steps as :func:`named_jit` takes them."""
+    origin = program.step_origin or range(len(program.steps))
+    return [(st, runs, origin[i]) for i, st in enumerate(program.steps)]
 
 
 def jit_program(
@@ -506,6 +560,7 @@ def jit_program(
         jitted = named_jit(
             run,
             module if role is None else f"tnc_{role}",
+            steps=program_step_list(program),
             donate_argnums=(0,) if donate else (),
         )
         n_steps = len(program.steps)

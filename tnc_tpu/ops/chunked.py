@@ -36,6 +36,7 @@ import logging
 import numpy as np
 
 from tnc_tpu import obs
+from tnc_tpu.obs import op_table
 from tnc_tpu.ops.backends import named_jit, place_buffers
 from tnc_tpu.ops.program import (
     ContractionProgram,
@@ -155,7 +156,7 @@ def _prelude_fn(hp, split_complex: bool, precision, interpret: bool = False):
             _PRELUDE_CACHE.move_to_end(key)
             return fn
 
-    from tnc_tpu.ops.hoist import run_prelude_steps
+    from tnc_tpu.ops.hoist import prelude_step_list, run_prelude_steps
 
     def run(pins):
         return tuple(
@@ -164,7 +165,7 @@ def _prelude_fn(hp, split_complex: bool, precision, interpret: bool = False):
             )
         )
 
-    fn = named_jit(run, "tnc_prelude")
+    fn = named_jit(run, "tnc_prelude", steps=prelude_step_list(hp))
     with _PLAN_CACHE_LOCK:
         _PRELUDE_CACHE[key] = fn
         while len(_PRELUDE_CACHE) > _PRELUDE_CACHE_MAX:
@@ -261,13 +262,14 @@ def _compiled_plan(
     chunks = split_program(sp.program, chunk_steps)
     num_inputs = sp.program.num_inputs
 
-    def body_of(steps, slots):
-        """The per-slice body over ``steps``, with their kernel
-        promotion ladder (split mode) planned over this subsequence — a
-        chain cannot cross a chunk boundary (the boundary is a dispatch
-        anyway). Cached with the plan; the cache key carries
-        complex_mult_key so forced/auto plans never collide."""
-        steps = tuple(steps)
+    def body_of(numbered, slots):
+        """The per-slice body over the steps of ``numbered`` — ``(number
+        in the chunk, step)`` pairs — with their kernel promotion ladder
+        (split mode) planned over this subsequence — a chain cannot
+        cross a chunk boundary (the boundary is a dispatch anyway).
+        Cached with the plan; the cache key carries complex_mult_key so
+        forced/auto plans never collide."""
+        steps = tuple(step for _, step in numbered)
         policy = None
         if split_complex and steps:
             from tnc_tpu.ops.split_complex import plan_kernel_steps
@@ -275,7 +277,7 @@ def _compiled_plan(
             policy = plan_kernel_steps(steps)
         return slice_body(
             jnp, steps, sp.slot_slices, slots, split_complex, precision,
-            policy, interpret,
+            policy, interpret, tuple(number for number, _ in numbered),
         )
 
     result_shape = sp.program.stored_result_shape
@@ -289,7 +291,11 @@ def _compiled_plan(
         the SPMD loop body runs. ``leaf_in`` slots enter as full sliced
         leaves and are indexed per row, ``row_in`` slots enter stacked
         (an earlier chunk's ``row_out``), every other slot is whole and
-        closed over by the loop."""
+        closed over by the loop. ``once`` and ``rows`` hold ``(number in
+        the chunk, step)`` pairs. The loop is traced under the named
+        scope ``tnc.chunk.io`` (what is left to it once the steps and
+        the pinning take their own: unstacking a row's inputs, stacking
+        its outputs), the sum over rows under ``tnc.slice.sum``."""
         looped = bool(rows)
         run_once, run_row = body_of(once, ()), body_of(rows, leaf_in)
 
@@ -303,9 +309,18 @@ def _compiled_plan(
 
         def scan_rows(body, init, whole, idx):
             xs = (idx, tuple(whole[slot] for slot in row_in))
-            return lax.scan(
-                lambda carry, x: body(carry, one_row(whole, *x)), init, xs
-            )
+            with op_table.named_scope(op_table.CHUNK_IO):
+                return lax.scan(
+                    lambda carry, x: body(carry, one_row(whole, *x)), init, xs
+                )
+
+        def summed(fn):
+            """``fn`` traced under ``tnc.slice.sum``."""
+            def scoped(*args):
+                with op_table.named_scope(op_table.SLICE_SUM):
+                    return fn(*args)
+
+            return scoped
 
         def chunk_fn(ins, idx):
             whole = enter(ins)
@@ -332,30 +347,31 @@ def _compiled_plan(
             whole = enter(ins)
             if looped:
                 # rows added one by one, in row order
-                zero = jax.tree.map(
+                zero = summed(jax.tree.map)(
                     jnp.zeros_like,
                     (acc[0][0], acc[1][0]) if split_complex else acc[0],
                 )
                 total, _ = scan_rows(
-                    lambda total, state: (
+                    summed(lambda total, state: (
                         jax.tree.map(
                             jnp.add, total, stored(state[result_slot])
                         ),
                         None,
-                    ),
+                    )),
                     zero, whole, idx,
                 )
             else:  # slice-independent result: b identical terms
                 b = idx.shape[0]
-                total = jax.tree.map(
+                total = summed(jax.tree.map)(
                     lambda x: x * b, stored(whole[result_slot])
                 )
-            if split_complex:
-                (sr, cr), (si, ci_) = acc
-                sr, cr = kahan_add(sr, cr, total[0])
-                si, ci_ = kahan_add(si, ci_, total[1])
-                return ((sr, cr), (si, ci_))
-            return kahan_add(acc[0], acc[1], total)
+            with op_table.named_scope(op_table.SLICE_SUM):
+                if split_complex:
+                    (sr, cr), (si, ci_) = acc
+                    sr, cr = kahan_add(sr, cr, total[0])
+                    si, ci_ = kahan_add(si, ci_, total[1])
+                    return ((sr, cr), (si, ci_))
+                return kahan_add(acc[0], acc[1], total)
 
         return last_fn if last else chunk_fn
 
@@ -365,6 +381,7 @@ def _compiled_plan(
         slot for slot, info in enumerate(sp.slot_slices) if info
     }
     last_ci = len(chunks) - 1
+    origin = sp.program.step_origin or range(len(sp.program.steps))
     chunk_fns = []
     row_modes = []
     written_before: set[int] = set()
@@ -386,23 +403,32 @@ def _compiled_plan(
             for slot in chunk.in_slots
             if slot in per_slice and slot not in leaf_in
         )
-        once, rows = [], []
-        for step in chunk.steps:
+        once, rows = [], []  # (number in the chunk, step)
+        for number, step in enumerate(chunk.steps):
             if step.lhs in per_slice or step.rhs in per_slice:
                 per_slice.add(step.lhs)
-                rows.append(step)
+                rows.append((number, step))
             else:
-                once.append(step)
+                once.append((number, step))
         row_out = tuple(s for s in chunk.out_slots if s in per_slice)
         fn = chunk_program(
             chunk, leaf_in, row_in, once, rows, row_out, ci == last_ci
         )
+        in_rows = {number for number, _ in rows}
         chunk_fns.append(
             named_jit(
                 fn,
                 "tnc_residual_last"
                 if ci == last_ci
                 else f"tnc_residual_c{ci:02d}",
+                steps=[
+                    (
+                        step,
+                        "row" if number in in_rows else "once",
+                        origin[ci * chunk_steps + number],
+                    )
+                    for number, step in enumerate(chunk.steps)
+                ],
             )
         )
         row_modes.append("loop" if rows else "once")

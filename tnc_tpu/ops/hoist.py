@@ -28,7 +28,7 @@ selection actively prefers slicings that keep a large hoistable stem.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, field, replace
 from functools import lru_cache
 from typing import Any, Sequence
 
@@ -44,13 +44,16 @@ class PreludeStep:
     ``rhs`` slot ids refer to the *original* program and must not be
     used; ``out``/``lhs``/``rhs`` here are prelude slots. ``free_rhs``
     is False when the rhs value is a residual source and must survive
-    the step (never the case for tree paths, kept for safety)."""
+    the step (never the case for tree paths, kept for safety).
+    ``origin`` is the step's index in the program that was split (a
+    note for the op table; no part of the step's identity)."""
 
     out: int
     lhs: int
     rhs: int
     free_rhs: bool
     step: PairStep
+    origin: int = field(default=-1, compare=False)
 
 
 @dataclass(frozen=True)
@@ -149,6 +152,8 @@ def hoist_sliced_program(sp: SlicedProgram) -> HoistedProgram:
     res_sources: list[tuple[str, Any]] = []
     res_slot_slices: list[tuple] = []
     res_steps: list[PairStep] = []
+    res_origin: list[int] = []
+    origin = prog.step_origin or range(len(steps))
 
     def res_input(v: tuple) -> int:
         slot = len(res_sources)
@@ -172,6 +177,7 @@ def hoist_sliced_program(sp: SlicedProgram) -> HoistedProgram:
         if lb is None:
             lb = res_input(vb)
         res_steps.append(replace(st, lhs=la, rhs=lb))
+        res_origin.append(origin[i])
         res_slot_of[("step", i)] = la
 
     final_val = cur[prog.result_slot]
@@ -185,6 +191,7 @@ def hoist_sliced_program(sp: SlicedProgram) -> HoistedProgram:
         stored_result_shape=prog.stored_result_shape,
         canonical_legs=prog.canonical_legs,
         fusion=prog.fusion,
+        step_origin=tuple(res_origin),
     )
     residual = SlicedProgram(
         residual_program, sp.slicing, tuple(res_slot_slices)
@@ -218,7 +225,7 @@ def hoist_sliced_program(sp: SlicedProgram) -> HoistedProgram:
         # for the residual (impossible on tree paths — defensive only)
         out_slot = palloc() if va in needed else la
         prelude_steps.append(
-            PreludeStep(out_slot, la, lb, vb not in needed, st)
+            PreludeStep(out_slot, la, lb, vb not in needed, st, origin[i])
         )
         pslot[("step", i)] = out_slot
 
@@ -265,33 +272,43 @@ def run_prelude_steps(
     model-driven per-step promotion deliberately does NOT — like
     :func:`~tnc_tpu.ops.split_complex.auto_step_mode`, an env-keyed
     trace must never bake in a decision that flaps as calibration
-    evolves."""
+    evolves.
+
+    A prelude step's named scope carries its index in
+    ``hp.prelude_steps`` (:func:`prelude_step_list` is the list a
+    program that runs the prelude registers)."""
     if split_complex:
         from tnc_tpu.ops.split_complex import apply_step_split, auto_step_mode
 
-        def kernel(a, b, step):
+        def kernel(a, b, step, number):
             return apply_step_split(
                 xp, a, b, step, precision, mode=auto_step_mode(step),
-                interpret=interpret,
+                interpret=interpret, number=number,
             )
 
     else:
         from tnc_tpu.ops.backends import apply_step
 
-        def kernel(a, b, step):
-            return apply_step(xp, a, b, step)
+        def kernel(a, b, step, number):
+            return apply_step(xp, a, b, step, number)
 
     buf: list[Any] = [None] * hp.prelude_num_slots
     for (slot, _), val in zip(hp.prelude_inputs, prelude_buffers):
         buf[slot] = val
-    for ps in hp.prelude_steps:
-        out = kernel(buf[ps.lhs], buf[ps.rhs], ps.step)
+    for number, ps in enumerate(hp.prelude_steps):
+        out = kernel(buf[ps.lhs], buf[ps.rhs], ps.step, number)
         if ps.free_rhs:
             buf[ps.rhs] = None
         buf[ps.out] = out
     return [
         buf[ref] for kind, ref in hp.residual_sources if kind == "cached"
     ]
+
+
+def prelude_step_list(hp: HoistedProgram) -> list:
+    """The prelude's steps as :func:`tnc_tpu.ops.backends.named_jit`
+    takes them: each runs once a dispatch."""
+    return [(ps.step, "once", ps.origin) for ps in hp.prelude_steps]
 
 
 def run_prelude(

@@ -130,6 +130,26 @@ def step_elems(st, mode: str | None = None) -> tuple[float, float]:
     return elems_in, float(math.prod(st.out_store))
 
 
+def step_streamed_elems(st) -> float:
+    """Elements a one-pass lowering of the step streams: the larger
+    operand in and the result out (the small operand rides in fast
+    memory). The count stem fusion keeps of a plan's large steps
+    (``Slicing.fusion["streamed_elems"]``) and the one the program's op
+    table divides a step's device seconds by."""
+    return float(
+        max(math.prod(st.a_view), math.prod(st.b_view))
+        + math.prod(st.out_store)
+    )
+
+
+def step_size_class(st) -> str:
+    """``large`` where an operand of the step holds at least
+    ``_STAGED_MIN_SIZE`` (2^18) elements — the stem steps, the threshold
+    `stream_prep_form` and stem fusion read — else ``small``."""
+    biggest = max(math.prod(st.a_view), math.prod(st.b_view))
+    return "large" if biggest >= _STAGED_MIN_SIZE else "small"
+
+
 def step_label(i: int, st) -> str:
     """Self-describing span name for one step: index + matmul dims
     (``step[12] 256x512·512x64``), so Perfetto lanes and roofline rows
@@ -959,6 +979,12 @@ class ContractionProgram:
     # report of `contractionpath.stem_fusion.fuse_stem_operands`), for
     # `kernel_plan_summary`; a note, no part of the program's identity
     fusion: dict | None = field(default=None, compare=False, repr=False)
+    # per step, its index in the plan that was handed out, where the
+    # steps are a selection of another program's (a hoisted residual);
+    # ``None``: the steps are the plan's own, in order. A note too
+    step_origin: tuple[int, ...] | None = field(
+        default=None, compare=False, repr=False
+    )
 
     def __post_init__(self):
         if not self.stored_result_shape:

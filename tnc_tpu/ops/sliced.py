@@ -29,6 +29,7 @@ slice sets with the same formula
 
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass, replace
 from typing import Sequence
@@ -36,6 +37,7 @@ from typing import Sequence
 import numpy as np
 
 from tnc_tpu import obs
+from tnc_tpu.obs import op_table
 from tnc_tpu.contractionpath.contraction_path import ContractionPath
 from tnc_tpu.contractionpath.slicing import Slicing
 from tnc_tpu.ops.program import (
@@ -200,6 +202,7 @@ def slice_body(
     precision: str | None = None,
     policy=None,
     interpret: bool = False,
+    numbers: Sequence[int] | None = None,
 ):
     """``body(state, indices) -> state``: what running one slice means.
 
@@ -213,7 +216,14 @@ def slice_body(
     — under ``policy`` (the kernel ladder planned over exactly these
     ``steps``; split mode). ``state`` is mutated and returned; the
     caller reads the slots it wants. With ``slots=()`` nothing is
-    pinned: the steps run once on whole slots."""
+    pinned: the steps run once on whole slots.
+
+    Off the host oracle the pinning is traced under the named scope
+    ``tnc.slice.index`` and each step under its own
+    (:func:`tnc_tpu.ops.split_complex.apply_step_split`), numbered by
+    ``numbers`` (the step's index in the step list of the program being
+    traced; default: its index in ``steps``), so a loop body is covered
+    whole: :func:`tnc_tpu.obs.device_op_table`."""
     if slots is None:
         slots = range(len(slot_slices))
     pinned = tuple((slot, slot_slices[slot]) for slot in slots)
@@ -224,15 +234,26 @@ def slice_body(
         return index_buffer(xp, buf, info, indices)
 
     def body(state, indices):
-        for slot, info in pinned:
-            state[slot] = pin(state[slot], info, indices)
+        with slice_index_scope(xp):
+            for slot, info in pinned:
+                state[slot] = pin(state[slot], info, indices)
         if split_complex:
-            apply_steps_split(xp, steps, state, precision, policy, interpret)
+            apply_steps_split(
+                xp, steps, state, precision, policy, interpret, numbers
+            )
         else:
-            apply_steps(xp, steps, state)
+            apply_steps(xp, steps, state, numbers)
         return state
 
     return body
+
+
+def slice_index_scope(xp):
+    """The named scope of cutting the sliced leaves for one slice
+    (``tnc.slice.index``; nothing on the host oracle)."""
+    if xp is np:
+        return contextlib.nullcontext()
+    return op_table.named_scope(op_table.SLICE_INDEX)
 
 
 def program_slice_fn(xp, sp: SlicedProgram, **body_options):
@@ -243,7 +264,9 @@ def program_slice_fn(xp, sp: SlicedProgram, **body_options):
     dims, result_slot = sp.slicing.dims, sp.program.result_slot
 
     def fn(full_buffers, s):
-        return body(list(full_buffers), slice_indices(dims, s))[result_slot]
+        with slice_index_scope(xp):
+            indices = slice_indices(dims, s)
+        return body(list(full_buffers), indices)[result_slot]
 
     return fn
 

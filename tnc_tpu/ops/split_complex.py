@@ -361,7 +361,7 @@ def _strassen_step(xp, ar, ai, br, bi, step, precision):
 
 def apply_step_split(
     xp, apair, bpair, step, precision=None, mode=None, precision_mode=None,
-    interpret: bool = False, carry: bool = False,
+    interpret: bool = False, carry: bool = False, number: int = 0,
 ):
     """Split-complex analogue of ``backends.apply_step``: one pairwise
     contraction of (real, imag) pairs. The single source of truth
@@ -386,21 +386,36 @@ def apply_step_split(
     :class:`TiledValue`. An operand may be in either form whatever
     ``carry`` says; without it a pair goes out.
 
-    Off the host oracle the step's ops are traced under
-    ``jax.named_scope("tnc.<bucket>")`` (:func:`step_bucket`: stem /
-    medium / small): a name in the ops' metadata for xprof, at trace
-    time only — the compiled program is the same."""
+    Off the host oracle the step's ops are traced under ONE named scope
+    that says what was decided for it,
+    ``tnc.step.<NNNN>.<size>.<mode>.<form>``
+    (:func:`tnc_tpu.obs.op_table.step_scope_name`): ``number``, the
+    step's index in the step list of the program being traced (the
+    walker's); ``large`` | ``small``
+    (:func:`tnc_tpu.ops.program.step_size_class`); the lowering that
+    runs after every fallback and where the streamed operand's prep
+    ends (:func:`_step_lowering`). Inside it the sub-scopes ``prep``
+    (the planned transposes and staged ops of the operands), ``dot``
+    (the dot, or gauss's three dots and their sums) and ``out`` (the
+    result's way to its stored or carried shape). Names in the ops'
+    metadata, at trace time only — the compiled program is the same —
+    which the optimized program keeps on every instruction:
+    :func:`tnc_tpu.obs.device_op_table` maps a trace's ops back to
+    steps by them."""
     if xp is np:
-        scope = contextlib.nullcontext()
-    else:
-        import jax
+        return _host_step_split(apair, bpair, step, mode)
+    from tnc_tpu.obs import op_table
+    from tnc_tpu.ops.program import step_size_class
 
-        scope = jax.named_scope("tnc." + step_bucket(step))
-    with scope:
-        return _apply_step_split(
-            xp, apair, bpair, step, precision, mode, precision_mode,
-            interpret, carry,
-        )
+    mode, form = _step_lowering(apair, bpair, step, mode)
+    scope = op_table.step_scope_name(number, step_size_class(step), mode, form)
+    op_table.note_step(number, scope)
+    prec = _resolve_step_precision(precision, precision_mode)
+    with op_table.named_scope(scope):
+        if mode == "block":
+            run = _block_step if form == "matrix" else _tiled_block_step
+            return run(apair, bpair, step, prec, carry)
+        return _pair_step(apair, bpair, step, mode, prec, interpret)
 
 
 class TiledValue:
@@ -456,83 +471,162 @@ def _as_pair(value):
     return value if isinstance(value, tuple) else (value[0], value[1])
 
 
-def _apply_step_split(
-    xp, apair, bpair, step, precision, mode, precision_mode, interpret,
-    carry=False,
-):
-    from tnc_tpu.ops.backends import _prep_operand
+def _plane_meta(value) -> tuple[str, int]:
+    """``(dtype, elements of a plane)`` of a walker's value in any of
+    its forms: a pair, a carried ``(2,) + stored`` array, a
+    :class:`TiledValue`. Shapes only: no op is traced."""
+    if isinstance(value, tuple):
+        return str(value[0].dtype), int(value[0].size)
+    array = value.array if isinstance(value, TiledValue) else value
+    return str(array.dtype), int(array.size) // 2
+
+
+def _tiled_stream_ops(value, step):
+    """``(ops, from_image)`` of the streamed operand ``value`` of a
+    tiled step: the ops that bring it to the tiled image
+    (:func:`tnc_tpu.ops.program.tiled_prep_ops`), starting from the
+    image a tiled step left (``from_image``: ``value`` is a
+    :class:`TiledValue` and its digits refine) or from ``(2,) +
+    stored``."""
+    from tnc_tpu.ops.program import operand_prep, streamed_side, tiled_prep_ops
+
+    view, perm, dot, cfirst, ops = operand_prep(step, streamed_side(step))
+    if isinstance(value, TiledValue):
+        tiled = tiled_prep_ops(
+            view, perm, dot, cfirst, ops is not None, value.source
+        )
+        if tiled is not None:
+            return tiled, True
+    return tiled_prep_ops(view, perm, dot, cfirst, ops is not None), False
+
+
+def _step_lowering(a, b, step, mode) -> tuple[str, str]:
+    """``(mode, form)`` of one step on the device, decided before any of
+    its ops is traced (shapes and dtypes alone) and counted
+    (:func:`_note_step_lowering`): the lowering that runs after every
+    fallback — ``block``, ``gauss``, ``naive``, ``strassen``,
+    ``fused``, ``fused_transpose``; a fused kernel's routing away is
+    counted with its reason here — and where the prep of the streamed
+    operand ends: ``tiled`` (the image of its joined matrix,
+    :func:`_tiled_block_step`), ``staged`` (tiled through a ``lanemix``
+    plan) or ``matrix`` (every other step and lowering)."""
+    from tnc_tpu.ops.program import step_dims, streamed_side
 
     resolved = mode or complex_mult_forced() or default_step_mode(step)
-    if resolved == "block" and xp is not np:
-        form = step_prep_form(step, resolved)
-        _note_step_lowering(resolved, form)
-        return (_tiled_block_step if form == "tiled" else _block_step)(
-            apair, bpair, step,
-            _resolve_step_precision(precision, precision_mode), carry,
-        )
-    apair, bpair = _as_pair(apair), _as_pair(bpair)
-    if mode == "fused_transpose" and xp is not np:
-        # the fused transpose-dot consumes the RAW stored views — it
-        # must run BEFORE _prep_operand materializes the macro
-        # transpose (that pass is exactly what it deletes); a step the
-        # eligibility gate routes away takes the prep+naive path below
-        out = _try_fused_transpose_step(
-            apair, bpair, step,
-            _resolve_step_precision(precision, precision_mode),
-            interpret,
-        )
-        if out is not None:
+    if resolved == "block":
+        form = "matrix"
+        if step_prep_form(step, resolved) == "tiled":
+            streamed = a if streamed_side(step) == "a" else b
+            ops, _ = _tiled_stream_ops(streamed, step)
+            staged = any(op[0] == "lanemix" for op in ops)
+            form = "staged" if staged else "tiled"
+        _note_step_lowering("block", "matrix" if form == "matrix" else "tiled")
+        return "block", form
+    if mode == "fused_transpose":
+        reason = fused_transpose_ineligible_reason(
+            step
+        ) or fused_transpose_runtime_ineligible_reason(a, b, step)
+        if reason is None:
             _note_step_lowering("fused_transpose")
-            return out
-        mode = "naive"
-
-    ar = _prep_operand(
-        xp, apair[0], step.a_view, step.a_perm, step.a_dot, step.a_ops
-    )
-    ai = _prep_operand(
-        xp, apair[1], step.a_view, step.a_perm, step.a_dot, step.a_ops
-    )
-    br = _prep_operand(
-        xp, bpair[0], step.b_view, step.b_perm, step.b_dot, step.b_ops
-    )
-    bi = _prep_operand(
-        xp, bpair[1], step.b_view, step.b_perm, step.b_dot, step.b_ops
-    )
+            return "fused_transpose", "matrix"
+        m, k, n = step_dims(step)
+        _note_fused_transpose_fallback(reason, k, m, n)
+        mode = "naive"  # the prep + naive path: same arithmetic
     if mode is None:
         mode = resolved
     if mode == "strassen" and not _strassen_step_eligible(step):
         mode = "gauss"  # forced-strassen steps below the crossover
-    if xp is np:
-        if mode == "strassen":
-            return _strassen_step(np, ar, ai, br, bi, step, None)
+    if mode == "fused":
+        fallback = _fused_ineligible_reason(step, _plane_meta(a)[0])
+        if fallback is not None:
+            _note_fused_fallback(*fallback)
+            mode = "naive"  # routed away by eligibility: same arithmetic
+    if mode not in ("strassen", "fused", "naive"):
+        mode = "gauss"
+    _note_step_lowering(mode)
+    return mode, "matrix"
 
-        def as_km(part, mat, cfirst):
-            return part.reshape(mat) if cfirst else part.reshape(mat[::-1]).T
 
-        ar = as_km(ar, step.a_mat, step.a_cfirst)
-        ai = as_km(ai, step.a_mat, step.a_cfirst)
-        br = as_km(br, step.b_mat, step.b_cfirst)
-        bi = as_km(bi, step.b_mat, step.b_cfirst)
-        if step.swap:
-            ar, ai, br, bi = br.T, bi.T, ar, ai
-        else:
-            ar, ai = ar.T, ai.T
-        if mode in ("naive", "block", "fused", "fused_transpose"):
-            # the block form and the fused kernels run naive arithmetic
-            # on host oracles
-            re = ar @ br - ai @ bi
-            im = ar @ bi + ai @ br
-        else:
-            re, im = gauss_matmul(np, ar, ai, br, bi)
-        return re.reshape(step.out_store), im.reshape(step.out_store)
+def _host_step_split(apair, bpair, step, mode):
+    """One step on the host oracle (numpy, f64 planes)."""
+    from tnc_tpu.ops.backends import _prep_operand
 
+    apair, bpair = _as_pair(apair), _as_pair(bpair)
+    ar = _prep_operand(
+        np, apair[0], step.a_view, step.a_perm, step.a_dot, step.a_ops
+    )
+    ai = _prep_operand(
+        np, apair[1], step.a_view, step.a_perm, step.a_dot, step.a_ops
+    )
+    br = _prep_operand(
+        np, bpair[0], step.b_view, step.b_perm, step.b_dot, step.b_ops
+    )
+    bi = _prep_operand(
+        np, bpair[1], step.b_view, step.b_perm, step.b_dot, step.b_ops
+    )
+    if mode is None:
+        mode = complex_mult_forced() or default_step_mode(step)
+    if mode == "strassen" and _strassen_step_eligible(step):
+        return _strassen_step(np, ar, ai, br, bi, step, None)
+
+    def as_km(part, mat, cfirst):
+        return part.reshape(mat) if cfirst else part.reshape(mat[::-1]).T
+
+    ar = as_km(ar, step.a_mat, step.a_cfirst)
+    ai = as_km(ai, step.a_mat, step.a_cfirst)
+    br = as_km(br, step.b_mat, step.b_cfirst)
+    bi = as_km(bi, step.b_mat, step.b_cfirst)
+    if step.swap:
+        ar, ai, br, bi = br.T, bi.T, ar, ai
+    else:
+        ar, ai = ar.T, ai.T
+    if mode in ("naive", "block", "fused", "fused_transpose"):
+        # the block form and the fused kernels run naive arithmetic
+        # on host oracles
+        re = ar @ br - ai @ bi
+        im = ar @ bi + ai @ br
+    else:
+        re, im = gauss_matmul(np, ar, ai, br, bi)
+    return re.reshape(step.out_store), im.reshape(step.out_store)
+
+
+def _pair_step(a, b, step, mode, prec, interpret):
+    """One step of a lowering that takes and hands back (real, imag)
+    pairs (device path): ``gauss``, ``naive``, ``strassen`` and the two
+    fused kernels, as :func:`_step_lowering` decided."""
     import jax.numpy as jnp
     from jax import lax
 
-    prec = _resolve_step_precision(precision, precision_mode)
+    from tnc_tpu.obs.op_table import named_scope
+    from tnc_tpu.ops.backends import _prep_operand
+
+    with named_scope("prep"):
+        apair, bpair = _as_pair(a), _as_pair(b)
+    if mode == "fused_transpose":
+        # the fused transpose-dot consumes the RAW stored views: the
+        # macro transpose _prep_operand would materialize is exactly the
+        # pass it deletes
+        with named_scope("dot"):
+            return _fused_transpose_step(apair, bpair, step, prec, interpret)
+    with named_scope("prep"):
+        ar = _prep_operand(
+            jnp, apair[0], step.a_view, step.a_perm, step.a_dot, step.a_ops
+        )
+        ai = _prep_operand(
+            jnp, apair[1], step.a_view, step.a_perm, step.a_dot, step.a_ops
+        )
+        br = _prep_operand(
+            jnp, bpair[0], step.b_view, step.b_perm, step.b_dot, step.b_ops
+        )
+        bi = _prep_operand(
+            jnp, bpair[1], step.b_view, step.b_perm, step.b_dot, step.b_ops
+        )
     if mode == "strassen":
-        _note_step_lowering("strassen")
-        return _strassen_step(jnp, ar, ai, br, bi, step, prec)
+        with named_scope("dot"):
+            return _strassen_step(jnp, ar, ai, br, bi, step, prec)
+    if mode == "fused":
+        with named_scope("dot"):
+            return _fused_step(ar, ai, br, bi, step, prec, interpret)
     ca = (0,) if step.a_cfirst else (len(step.a_dot) - 1,)
     cb = (0,) if step.b_cfirst else (len(step.b_dot) - 1,)
 
@@ -541,22 +635,24 @@ def _apply_step_split(
             return lax.dot_general(y, x, ((cb, ca), ((), ())), precision=prec)
         return lax.dot_general(x, y, ((ca, cb), ((), ())), precision=prec)
 
-    if mode == "fused":
-        out = _try_fused_step(ar, ai, br, bi, step, prec, interpret)
-        if out is not None:
-            _note_step_lowering("fused")
-            return out
-        mode = "naive"  # routed away by eligibility: same arithmetic
+    def stored(part):
+        with named_scope("out"):
+            return part.reshape(step.out_store)
+
     if mode == "naive":
-        _note_step_lowering("naive")
-        re = dot(ar, br) - dot(ai, bi)
-        im = dot(ar, bi) + dot(ai, br)
-        return re.reshape(step.out_store), im.reshape(step.out_store)
-    _note_step_lowering("gauss")
-    k1 = dot(ar + ai, br)
-    k2 = dot(ar, bi - br)
-    k3 = dot(ai, br + bi)
-    return (k1 - k3).reshape(step.out_store), (k1 + k2).reshape(step.out_store)
+        with named_scope("dot"):
+            re = dot(ar, br) - dot(ai, bi)
+            im = dot(ar, bi) + dot(ai, br)
+        return stored(re), stored(im)
+    with named_scope("dot"):
+        k1 = dot(ar + ai, br)
+        k2 = dot(ar, bi - br)
+        k3 = dot(ai, br + bi)
+        re = k1 - k3
+    re = stored(re)
+    with named_scope("dot"):
+        im = k1 + k2
+    return re, stored(im)
 
 
 def _block_step(a, b, step, precision, carry):
@@ -584,6 +680,7 @@ def _block_step(a, b, step, precision, carry):
     import jax.numpy as jnp
     from jax import lax
 
+    from tnc_tpu.obs.op_table import named_scope
     from tnc_tpu.ops.backends import _prep_operand
     from tnc_tpu.ops.program import step_dims, streamed_side
 
@@ -595,8 +692,9 @@ def _block_step(a, b, step, precision, carry):
             return one(value[0]), one(value[1])
         return jax.vmap(one)(value)  # the plane axis rides in front
 
-    a = prep(_untiled(a), step.a_view, step.a_perm, step.a_dot, step.a_ops)
-    b = prep(_untiled(b), step.b_view, step.b_perm, step.b_dot, step.b_ops)
+    with named_scope("prep"):
+        a = prep(_untiled(a), step.a_view, step.a_perm, step.a_dot, step.a_ops)
+        b = prep(_untiled(b), step.b_view, step.b_perm, step.b_dot, step.b_ops)
     m, k, n = step_dims(step)
     # ties expand the operand the dot takes first: the plane axis leads
     expand_a = streamed_side(step) == "b"
@@ -608,38 +706,45 @@ def _block_step(a, b, step, precision, carry):
         sides if expand_a else sides[::-1]
     )
     ks = 0 if s_cfirst else rank_s - 1
-    if s_cfirst and not isinstance(s, tuple):
-        joined = s.reshape((2 * s.shape[1],) + s.shape[2:])
-    else:
-        joined = jnp.concatenate(_as_pair(s), axis=ks)
-    er, ei = _as_pair(e)
     ke = 0 if e_cfirst else rank_e - 1
-    halves = [
-        jnp.concatenate([er, -ei], axis=ke),
-        jnp.concatenate([ei, er], axis=ke),
-    ]
+    with named_scope("prep"):
+        if s_cfirst and not isinstance(s, tuple):
+            joined = s.reshape((2 * s.shape[1],) + s.shape[2:])
+        else:
+            joined = jnp.concatenate(_as_pair(s), axis=ks)
+        er, ei = _as_pair(e)
+        halves = [
+            jnp.concatenate([er, -ei], axis=ke),
+            jnp.concatenate([ei, er], axis=ke),
+        ]
     expanded_first = expand_a != step.swap
 
     def dot(expanded, ke):
-        if expanded_first:
+        with named_scope("dot"):
+            if expanded_first:
+                return lax.dot_general(
+                    expanded, joined, (((ke,), (ks,)), ((), ())),
+                    precision=precision,
+                )
             return lax.dot_general(
-                expanded, joined, (((ke,), (ks,)), ((), ())),
+                joined, expanded, (((ks,), (ke,)), ((), ())),
                 precision=precision,
             )
-        return lax.dot_general(
-            joined, expanded, (((ks,), (ke,)), ((), ())), precision=precision
-        )
+
+    def stored(out):
+        with named_scope("out"):
+            return out.reshape(step.out_store)
 
     if not carry and min(m, n) > k:  # the result outweighs the streamed
-        return tuple(dot(h, ke).reshape(step.out_store) for h in halves)
-    out = dot(
-        jnp.stack(halves, axis=1 if e_cfirst else 0),
-        ke if e_cfirst else ke + 1,
-    )
-    if not expanded_first:
-        out = jnp.moveaxis(out, rank_s - 1, 0)
-    out = out.reshape((2,) + tuple(step.out_store))
-    return out if carry else _as_pair(out)
+        return tuple(stored(dot(h, ke)) for h in halves)
+    with named_scope("prep"):
+        block = jnp.stack(halves, axis=1 if e_cfirst else 0)
+    out = dot(block, ke if e_cfirst else ke + 1)
+    with named_scope("out"):
+        if not expanded_first:
+            out = jnp.moveaxis(out, rank_s - 1, 0)
+        out = out.reshape((2,) + tuple(step.out_store))
+        return out if carry else _as_pair(out)
 
 
 def _tiled_block_step(a, b, step, precision, carry):
@@ -659,79 +764,75 @@ def _tiled_block_step(a, b, step, precision, carry):
     :func:`_block_step`."""
     import jax.numpy as jnp
 
+    from tnc_tpu.obs.op_table import named_scope
     from tnc_tpu.ops.backends import _prep_operand
-    from tnc_tpu.ops.program import (
-        operand_prep,
-        step_dims,
-        streamed_side,
-        tiled_prep_ops,
-    )
+    from tnc_tpu.ops.program import operand_prep, step_dims, streamed_side
 
     stream_a = streamed_side(step) == "a"
     s, e = (a, b) if stream_a else (b, a)
-    s_view, s_perm, s_dot, s_cfirst, s_ops = operand_prep(
-        step, "a" if stream_a else "b"
-    )
     e_view, e_perm, e_dot, e_cfirst, e_ops = operand_prep(
         step, "b" if stream_a else "a"
     )
-
-    ops = None
-    if isinstance(s, TiledValue):
-        ops = tiled_prep_ops(
-            s_view, s_perm, s_dot, s_cfirst, s_ops is not None, s.source
-        )
-        s = s.array if ops is not None else s.plain()
-    if ops is None:
-        ops = tiled_prep_ops(s_view, s_perm, s_dot, s_cfirst, s_ops is not None)
-        s = jnp.stack(s) if isinstance(s, tuple) else s
-    image = _prep_operand(jnp, s, None, None, ops[-1][1], ops)
-
-    # the other operand's 2 x 2 block, built as it lies and flattened
-    # once: (2k, 2n), the halves of the result side by side
-    er, ei = (
-        _prep_operand(jnp, part, e_view, e_perm, e_dot, e_ops)
-        for part in _as_pair(e)
-    )
+    ops, from_image = _tiled_stream_ops(s, step)
     ke = 0 if e_cfirst else len(e_dot) - 1
     m, k, n = step_dims(step)
     n = min(m, n)
-    block = _as_kl(
-        jnp,
-        jnp.stack(
-            [
-                jnp.concatenate([er, -ei], axis=ke),
-                jnp.concatenate([ei, er], axis=ke),
-            ],
-            axis=1 if e_cfirst else 0,
-        ),
-        (2 * k, 2 * n) if e_cfirst else (2 * n, 2 * k),
-        e_cfirst,
-    )
     # the result's stored order: the legs of the operand the dot takes
     # first, then the other's
     stored = "nrl" if stream_a == step.swap else "rln"
-    if not carry and n > k:  # as _block_step: a dot a half
-        return tuple(
-            jnp.einsum(
-                f"kn,rkl->{stored}", half, image, precision=precision
-            ).reshape(step.out_store)
-            for half in (block[:, :n], block[:, n:])
+    halved = not carry and n > k  # as _block_step: a dot a half
+
+    with named_scope("prep"):
+        if from_image:
+            s = s.array
+        else:
+            s = _untiled(s)
+            s = jnp.stack(s) if isinstance(s, tuple) else s
+        image = _prep_operand(jnp, s, None, None, ops[-1][1], ops)
+
+        # the other operand's 2 x 2 block, built as it lies and flattened
+        # once: (2k, 2n), the halves of the result side by side
+        er, ei = (
+            _prep_operand(jnp, part, e_view, e_perm, e_dot, e_ops)
+            for part in _as_pair(e)
         )
-    if stored == "rln":  # the plane leads what is stored: its own axis
-        out = jnp.einsum(
-            "kpn,rkl->prln", block.reshape(2 * k, 2, n), image,
-            precision=precision,
+        block = _as_kl(
+            jnp,
+            jnp.stack(
+                [
+                    jnp.concatenate([er, -ei], axis=ke),
+                    jnp.concatenate([ei, er], axis=ke),
+                ],
+                axis=1 if e_cfirst else 0,
+            ),
+            (2 * k, 2 * n) if e_cfirst else (2 * n, 2 * k),
+            e_cfirst,
         )
+        if halved:
+            halves = (block[:, :n], block[:, n:])
+        elif stored == "rln":  # the plane leads what is stored: its own axis
+            block = block.reshape(2 * k, 2, n)
+
+    def dot(spec, small):
+        with named_scope("dot"):
+            return jnp.einsum(spec, small, image, precision=precision)
+
+    if halved:
+        out = []
+        for half in halves:
+            part = dot(f"kn,rkl->{stored}", half)
+            with named_scope("out"):
+                out.append(part.reshape(step.out_store))
+        return tuple(out)
+    if stored == "rln":
+        out = dot("kpn,rkl->prln", block)
     elif carry == "tiled":
-        return TiledValue(
-            jnp.einsum("kq,rkl->rql", block, image, precision=precision),
-            step.out_store,
-        )
+        return TiledValue(dot("kq,rkl->rql", block), step.out_store)
     else:
-        out = jnp.einsum("kq,rkl->qrl", block, image, precision=precision)
-    out = out.reshape((2,) + tuple(step.out_store))
-    return out if carry else _as_pair(out)
+        out = dot("kq,rkl->qrl", block)
+    with named_scope("out"):
+        out = out.reshape((2,) + tuple(step.out_store))
+        return out if carry else _as_pair(out)
 
 
 def _note_step_lowering(mode: str, prep: str = "matrix") -> None:
@@ -777,35 +878,36 @@ def _note_fused_fallback(reason: str, k: int, m: int, n: int, detail=""):
         logger.warning(msg)
 
 
-def _try_fused_step(ar, ai, br, bi, step, precision, interpret=False):
-    """Route one step through the fused Pallas kernel when its layout
-    allows (both operands contract-dim-leading, tileable shapes, big
-    enough to amortize the grid); None means 'use the naive dots'.
-    Every routed-away step is counted (``ops.fused_fallback``) with its
-    eligibility reason — layout vs dtype vs tile/flop floor — so
-    records show *why* fused didn't fire. Routing is planning; a kernel
-    that was routed here and cannot trace or compile fails the run.
-    """
+def _fused_ineligible_reason(step, dtype: str) -> tuple | None:
+    """Why the fused Pallas kernel cannot take one step, as the
+    arguments of :func:`_note_fused_fallback` — ``(reason, k, m, n,
+    detail)``: layout (both operands must be contract-dim-leading) vs
+    dtype vs tile/flop floor — or ``None`` where it can. Shapes and the
+    planes' dtype alone: routing is planning, decided before any op of
+    the step is traced (:func:`_step_lowering`)."""
     k = int(step.a_dot[0]) if step.a_cfirst else int(step.a_dot[-1])
     m = int(np.prod(step.a_dot, dtype=np.int64)) // max(k, 1)
     n = int(np.prod(step.b_dot, dtype=np.int64)) // max(k, 1)
     if step.swap:
         m, n = n, m
     if not (step.a_cfirst and step.b_cfirst):
-        _note_fused_fallback("layout", k, m, n)
-        return None
-    from tnc_tpu.ops.pallas_complex import (
-        fused_complex_dot_kl,
-        ineligible_reason,
-    )
+        return ("layout", k, m, n, "")
+    from tnc_tpu.ops.pallas_complex import ineligible_reason
 
-    if str(ar.dtype) != "float32":
-        _note_fused_fallback("dtype", k, m, n, str(ar.dtype))
-        return None
+    if dtype != "float32":
+        return ("dtype", k, m, n, dtype)
     reason = ineligible_reason(k, m, n)
-    if reason is not None:
-        _note_fused_fallback(reason, k, m, n)
-        return None
+    return None if reason is None else (reason, k, m, n, "")
+
+
+def _fused_step(ar, ai, br, bi, step, precision, interpret=False):
+    """One step through the fused Pallas kernel
+    (:func:`_fused_ineligible_reason` found no reason against it). A
+    kernel that was routed here and cannot trace or compile fails the
+    run."""
+    from tnc_tpu.ops.pallas_complex import fused_complex_dot_kl
+
+    k = int(step.a_dot[0])
     a2r, a2i = ar.reshape(k, -1), ai.reshape(k, -1)
     b2r, b2i = br.reshape(k, -1), bi.reshape(k, -1)
     if step.swap:
@@ -862,7 +964,7 @@ def _fused_transpose_layouts(step):
 def fused_transpose_ineligible_reason(step) -> str | None:
     """Why the fused transpose-dot cannot take one step — ``None``
     when it can (the static half of the gate; dtype and batch checks
-    need live buffers and happen in :func:`_try_fused_transpose_step`).
+    need live buffers and happen in :func:`_step_lowering`).
     ``staged_prep`` rejects operands carrying a staged op plan: their
     minor-dim-safe reshape/lanemix sequence is the materialization the
     kernel would otherwise have to replicate per tile."""
@@ -877,7 +979,7 @@ def fused_transpose_ineligible_reason(step) -> str | None:
 
 
 def fused_transpose_step_eligible(step) -> bool:
-    """Can :func:`_try_fused_transpose_step` take this step?"""
+    """Can the fused transpose-dot take this step (the static gate)?"""
     return fused_transpose_ineligible_reason(step) is None
 
 
@@ -888,38 +990,28 @@ def fused_transpose_runtime_ineligible_reason(apair, bpair, step) -> str | None:
     carrying an extra leading batch axis (``batch`` — serving rebind
     threading cannot stream through the static block geometry). The
     ONE predicate shared by the kernel route
-    (:func:`_try_fused_transpose_step`) and the span accounting
+    (:func:`_step_lowering`) and the span accounting
     (``backends.run_steps_timed``), so what the spans credit and what
     the kernel actually does can never diverge."""
-    ar, br = apair[0], bpair[0]
-    if str(ar.dtype) != "float32" or str(br.dtype) != "float32":
+    (a_dtype, a_size), (b_dtype, b_size) = _plane_meta(apair), _plane_meta(bpair)
+    if a_dtype != "float32" or b_dtype != "float32":
         return "dtype"
-    if ar.size != int(np.prod(step.a_view, dtype=np.int64)) or br.size != int(
+    if a_size != int(np.prod(step.a_view, dtype=np.int64)) or b_size != int(
         np.prod(step.b_view, dtype=np.int64)
     ):
         return "batch"
     return None
 
 
-def _try_fused_transpose_step(apair, bpair, step, precision, interpret=False):
-    """Route one step through the fused transpose-dot Pallas kernel
-    (:func:`tnc_tpu.ops.pallas_complex.fused_transpose_dot_kl`) when
-    its layout allows; ``None`` means 'run the standard prep + naive
-    dots'. Takes the RAW stored (real, imag) pairs — the whole point
-    is that the macro transpose is applied in the kernel's index maps,
-    not materialized through HBM. Every routed-away step is counted
-    (``ops.fused_transpose_fallback{reason=...}``); like
-    :func:`_try_fused_step`, a kernel that cannot trace or compile
-    fails the run."""
-    from tnc_tpu.ops.program import step_dims
-
-    m, k, n = step_dims(step)
-    reason = fused_transpose_ineligible_reason(
-        step
-    ) or fused_transpose_runtime_ineligible_reason(apair, bpair, step)
-    if reason is not None:
-        _note_fused_transpose_fallback(reason, k, m, n)
-        return None
+def _fused_transpose_step(apair, bpair, step, precision, interpret=False):
+    """One step through the fused transpose-dot Pallas kernel
+    (:func:`tnc_tpu.ops.pallas_complex.fused_transpose_dot_kl`), the
+    gate passed (:func:`_step_lowering`: every routed-away step is
+    counted there, ``ops.fused_transpose_fallback{reason=...}``). Takes
+    the RAW stored (real, imag) pairs — the whole point is that the
+    macro transpose is applied in the kernel's index maps, not
+    materialized through HBM. Like :func:`_fused_step`, a kernel that
+    cannot trace or compile fails the run."""
     ar, ai = apair
     br, bi = bpair
     from tnc_tpu.ops.pallas_complex import fused_transpose_dot_kl
@@ -1456,6 +1548,7 @@ def apply_steps_split(
     precision=None,
     policy: KernelPolicy | None = None,
     interpret: bool = False,
+    numbers: Sequence[int] | None = None,
 ) -> None:
     """The one walker of split-complex steps: run ``steps`` in order
     over ``state`` (a list or dict, slot -> (real, imag) pair), in
@@ -1470,7 +1563,12 @@ def apply_steps_split(
     it); every other lowering takes and hands back
     a pair, and so does a step whose result outlives the walk, so a
     caller sees pairs alone.
-    ``interpret``: see :func:`apply_step_split`."""
+    ``interpret``: see :func:`apply_step_split`. ``numbers``: the
+    number of each step in the step list of the program being traced,
+    for its named scope (default: its index here, right where these
+    ``steps`` are all the jitted module runs)."""
+    if numbers is None:
+        numbers = range(len(steps))
     chain_end = (
         {s: e for s, e in policy.chains} if policy is not None else {}
     )
@@ -1495,11 +1593,12 @@ def apply_steps_split(
                 for slot in (st.lhs, st.rhs):
                     if state[slot] is not None:
                         state[slot] = _as_pair(state[slot])
-            run_chain_split(
-                xp, steps[i:end], state, precision,
-                precision_mode=policy.precision_mode(i),
-                interpret=interpret,
-            )
+            with _chain_scope(xp, steps[i], numbers[i]):
+                run_chain_split(
+                    xp, steps[i:end], state, precision,
+                    precision_mode=policy.precision_mode(i),
+                    interpret=interpret,
+                )
             i = end
             continue
         step = steps[i]
@@ -1509,10 +1608,25 @@ def apply_steps_split(
             precision_mode=(
                 policy.precision_mode(i) if policy is not None else None
             ),
-            interpret=interpret, carry=carried[i],
+            interpret=interpret, carry=carried[i], number=numbers[i],
         )
         state[step.rhs] = None
         i += 1
+
+
+def _chain_scope(xp, head, number: int):
+    """The scope of a chain: one fused dispatch, named after its head
+    step (mode ``chain``; the steps it swallows get no scope)."""
+    if xp is np:
+        return contextlib.nullcontext()
+    from tnc_tpu.obs import op_table
+    from tnc_tpu.ops.program import step_size_class
+
+    scope = op_table.step_scope_name(
+        number, step_size_class(head), "chain", "matrix"
+    )
+    op_table.note_step(number, scope)
+    return op_table.named_scope(scope)
 
 
 def run_steps_split(

@@ -116,7 +116,16 @@ def _make_spmd_fn(
             hp = cand
     loop_sp = hp.residual if hp is not None else sp
 
+    from tnc_tpu.obs import op_table
+    from tnc_tpu.ops.backends import program_step_list
+    from tnc_tpu.ops.hoist import prelude_step_list
     from tnc_tpu.ops.split_complex import interpret_for, plan_kernels
+
+    # the module's step list: the prelude's steps, once a dispatch, then
+    # the loop's, once a slice; a step's scope carries its index in it
+    module_steps = prelude_step_list(hp) if hp is not None else []
+    first_row = len(module_steps)
+    module_steps += program_step_list(loop_sp.program, "row")
 
     # Pallas interpret mode follows the mesh's devices, not the process
     interpret = interpret_for(mesh.devices.flat[0])
@@ -125,6 +134,7 @@ def _make_spmd_fn(
         # the kernel ladder, planned over the whole residual
         policy=plan_kernels(loop_sp.program) if split_complex else None,
         interpret=interpret,
+        numbers=range(first_row, len(module_steps)),
     )
     part_dtype = "float64" if "128" in str(dtype) else "float32"
 
@@ -152,12 +162,15 @@ def _make_spmd_fn(
             loop_buffers = full_buffers
 
         def add_slice(k, acc):
-            return jax.tree.map(
-                jnp.add, acc, one_slice(loop_buffers, my * chunk + k)
-            )
+            contribution = one_slice(loop_buffers, my * chunk + k)
+            with op_table.named_scope(op_table.SLICE_SUM):
+                return jax.tree.map(jnp.add, acc, contribution)
 
-        partial = lax.fori_loop(0, chunk, add_slice, zeros())
-        return lax.psum(partial, axis)
+        with op_table.named_scope(op_table.SLICE_SUM):
+            zero = zeros()
+        partial = lax.fori_loop(0, chunk, add_slice, zero)
+        with op_table.named_scope(op_table.SLICE_SUM):
+            return lax.psum(partial, axis)
 
     in_specs = tuple(P() for _ in range(sp.program.num_inputs))  # replicated
     # check_vma off: the psum inside the body trips the strict
@@ -166,7 +179,13 @@ def _make_spmd_fn(
         device_fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
         check_vma=False,
     )
-    return named_jit(fn, "tnc_spmd_slices")
+    from jax.sharding import NamedSharding
+
+    return named_jit(
+        fn, "tnc_spmd_slices", steps=module_steps,
+        # the leaves arrive committed and replicated over the mesh
+        sharding=NamedSharding(mesh, P()),
+    )
 
 
 # Executable cache: _make_spmd_fn builds a fresh closure per call, so
