@@ -5,6 +5,7 @@ traffic kind through ``perf.run.drive`` (the harness without its look
 for a chip), the faults and the lower-precision control that have to
 come out as not correct, and the exit code without a TPU."""
 
+import bisect
 import copy
 import json
 import os
@@ -16,6 +17,9 @@ import pytest
 
 from perf import circuits, common, compare, reference, roofline, trace_reduce
 from perf import run as perf_run
+from perf.trace_reduce import (
+    COLLECTIVE, SPAN_PREFIX, WINDOW_SPAN, short_module_name, short_op_name,
+)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 PLANNER = {"finder": "Hyperoptimizer", "seed": 42, "ntrials": 2,
@@ -77,6 +81,271 @@ def test_trace_reduction_by_hand():
     assert [nm for nm, _ in looped["device_ops"]] == ["fusion.1", "fusion.2"]
     with pytest.raises(ValueError):
         trace_reduce.reduce_events({}, spans)
+
+
+# The oracle: the reduction as it stood before the sweep and the arrays, in
+# plain Python, verbatim with the helpers it used.
+
+
+def union(intervals):
+    """Sorted, merged ``[(start, end)]``."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            if e > out[-1][1]:
+                out[-1][1] = e
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def total(intervals) -> float:
+    return float(sum(e - s for s, e in intervals))
+
+
+def complement(merged, lo, hi):
+    gaps, at = [], lo
+    for s, e in merged:
+        if s > at:
+            gaps.append((at, s))
+        at = max(at, e)
+    if hi > at:
+        gaps.append((at, hi))
+    return gaps
+
+
+def leaves_only(events):
+    """``events`` (name, start, end) without those that enclose another
+    one: a ``while`` or ``conditional`` op's event spans its body's ops,
+    and summing both would count the body twice."""
+    ordered = sorted(events, key=lambda e: (e[1], -e[2]))
+    encloses = [False] * len(ordered)
+    stack = []  # indices of events still open
+    for i, (_, start, end) in enumerate(ordered):
+        while stack and ordered[stack[-1]][2] <= start:
+            stack.pop()
+        if stack and end <= ordered[stack[-1]][2]:
+            encloses[stack[-1]] = True
+        stack.append(i)
+    return [e for e, outer in zip(ordered, encloses) if not outer]
+
+
+def _reduce_events_pairwise(devices: dict, spans: list, chips: int | None = None) -> dict:
+    """The reduction as it was before the sweep, kept verbatim as the
+    oracle: every idle gap walks the host spans from the first."""
+    windows = [s for s in spans if s[0] == WINDOW_SPAN]
+    if windows:
+        lo, hi = min(s[1] for s in windows), max(s[2] for s in windows)
+    else:  # no window span: the extent of the device's work
+        every = [e for d in devices.values() for e in (d["ops"] or d["modules"])]
+        if not every:
+            raise ValueError("the trace holds no device op")
+        lo, hi = min(e[1] for e in every), max(e[2] for e in every)
+    ordinals = sorted(devices)[: chips or len(devices)]
+    if not ordinals:
+        raise ValueError("the trace holds no /device:TPU plane")
+    host = sorted((s for s in spans if s[0] != WINDOW_SPAN), key=lambda s: s[1])
+    per_device = []
+    for n in ordinals:
+        dev = devices[n]
+        events = dev["ops"] or dev["modules"]
+        inside = [(nm, max(s, lo), min(e, hi)) for nm, s, e in leaves_only(events)
+                  if e > lo and s < hi]
+        busy = union((s, e) for _, s, e in inside)
+        gaps = complement(busy, lo, hi)
+        mods = sorted(dev["modules"], key=lambda m: m[1])
+        mod_starts = [m[1] for m in mods]
+        by_name: dict[str, float] = {}
+        collective = 0.0
+        for nm, s, e in inside:
+            op = short_op_name(nm)
+            if COLLECTIVE.match(op):
+                collective += e - s
+            i = bisect.bisect_right(mod_starts, s + 1.0) - 1
+            if dev["ops"] and i >= 0 and mods[i][2] >= s:
+                op = f"{short_module_name(mods[i][0])}/{op}"
+            by_name[op] = by_name.get(op, 0.0) + (e - s)
+        by_span: dict[str, float] = {}
+        for gs, ge in gaps:
+            covered = 0.0
+            for nm, s, e in host:
+                if s >= ge:
+                    break
+                o = min(e, ge) - max(s, gs)
+                if o > 0:
+                    key = nm[len(SPAN_PREFIX):]
+                    by_span[key] = by_span.get(key, 0.0) + o
+                    covered += o
+            if ge - gs > covered:
+                by_span["no span"] = by_span.get("no span", 0.0) + (ge - gs - covered)
+        per_device.append({
+            "ordinal": n, "busy_ns": total(busy), "op_ns": sum(by_name.values()),
+            "collective_ns": collective, "ops": by_name, "gaps": by_span,
+            "longest_gap_ns": max((ge - gs for gs, ge in gaps), default=0.0),
+        })
+    window_ns = hi - lo
+    idlest = min(per_device, key=lambda d: d["busy_ns"])
+    ops_total: dict[str, float] = {}
+    for d in per_device:
+        for nm, ns in d["ops"].items():
+            ops_total[nm] = ops_total.get(nm, 0.0) + ns / len(per_device)
+    return {
+        "window_s": window_ns * 1e-9,
+        "busy_s": sum(d["busy_ns"] for d in per_device) / len(per_device) * 1e-9,
+        "idle_pct_idlest": 100.0 * (1.0 - idlest["busy_ns"] / window_ns),
+        "op_s": sum(d["op_ns"] for d in per_device) / len(per_device) * 1e-9,
+        "op_s_max": max(d["op_ns"] for d in per_device) * 1e-9,
+        "collective_s_max": max(d["collective_ns"] for d in per_device) * 1e-9,
+        "devices": len(per_device),
+        "device_ops": [[nm, ns * 1e-9] for nm, ns in
+                       sorted(ops_total.items(), key=lambda kv: -kv[1])],
+        "idle_gaps": [[nm, ns * 1e-9] for nm, ns in
+                      sorted(idlest["gaps"].items(), key=lambda kv: -kv[1])],
+        "longest_gap_s": idlest["longest_gap_ns"] * 1e-9,
+    }
+
+
+def _synthetic_window(rng, chips, ops, calls, *, window=True, edges=True, depth=True):
+    """A traced window written as the reduction reads it: ``chips`` device
+    planes of ``ops`` ops each in ``calls`` calls (one module event a call,
+    ``while`` ops round parts of the body, an ``all-reduce`` now and then,
+    short gaps between ops and longer ones between calls, a gap no span
+    covers), and per call the nested host spans of a slice-SPMD call
+    (``call`` > ``tnc.spmd.contract`` > ``build``, ``place`` >
+    ``place_buffers``, ``execute``, ``fetch``), a second thread's spans
+    that overlap them, spans that start or end exactly on an op's edge,
+    and spans wholly before and after the window. Times are whole ns."""
+    per_call = ops // calls
+    dur = rng.integers(200, 20_000, size=(chips, calls, per_call))
+    gap = rng.integers(0, 3_000, size=(chips, calls, per_call))
+    gap[:, :, 0] += rng.integers(50_000, 400_000, size=(chips, calls))  # between calls
+    devices, spans = {}, []
+    call_edges = []
+    for c in range(chips):
+        starts = np.cumsum(gap[c] + dur[c], axis=None).reshape(calls, per_call) - dur[c]
+        ends = starts + dur[c]
+        names = rng.integers(0, 40, size=(calls, per_call))
+        op_list, mods = [], []
+        for k in range(calls):
+            s_row, e_row, n_row = starts[k].tolist(), ends[k].tolist(), names[k].tolist()
+            for s, e, nm in zip(s_row, e_row, n_row):
+                kind = "all-reduce" if nm == 0 else "fusion"
+                shape = "f32[8]" if nm % 3 else "f32[16]"  # one short name, two texts
+                op_list.append((f"%{kind}.{nm % 20} = {shape} {kind}(x)", float(s), float(e)))
+            if k % 7 == 3:  # a while op spans part of the body
+                op_list.append(("%while.1 = (s32[]) while(x)", float(s_row[1]), float(e_row[-2])))
+            if k % 5 == 2:  # an op outside every module, after the call
+                op_list.append((f"%copy-start.{k} = f32[8] copy-start(x)",
+                                float(e_row[-1] + 10_000), float(e_row[-1] + 15_000)))
+            mods.append((f"jit_tnc_spmd_slices({1000 + k % 3})", float(s_row[0]), float(e_row[-1])))
+            if c == 0:
+                call_edges.append((s_row, e_row))
+        devices[c] = {"ops": op_list, "modules": mods}
+    first, last = call_edges[0][0][0], call_edges[-1][1][-1]
+    if window:
+        spans.append(("perf:window", float(first - 30_000), float(last + 30_000)))
+    spans.append(("perf:plan", float(first - 90_000), float(first - 40_000)))  # before it
+    spans.append(("perf:reference", float(last + 40_000), float(last + 90_000)))  # after it
+    for k, (s_row, e_row) in enumerate(call_edges):
+        lo, hi = s_row[0] - 40_000, e_row[-1] + 1_000
+        if k == calls // 2:
+            continue  # a call's gap that no span covers
+        mid = s_row[len(s_row) // 2]
+        spans.append(("perf:call", float(lo), float(hi)))
+        if depth:
+            spans += [("perf:tnc.spmd.contract", float(lo + 10), float(hi - 10)),
+                      ("perf:tnc.spmd.build", float(lo + 10), float(lo + 20_000)),
+                      ("perf:tnc.spmd.place", float(lo + 20_000), float(lo + 35_000)),
+                      ("perf:tnc.backend.place_buffers", float(lo + 21_000), float(lo + 34_000)),
+                      ("perf:tnc.spmd.execute", float(lo + 35_000), float(s_row[0])),
+                      ("perf:tnc.spmd.fetch", float(s_row[0]), float(hi - 10))]
+        if edges:  # ends on one op's end, starts on a later op's start
+            spans.append(("perf:tnc.edge", float(e_row[2]), float(s_row[5])))
+            spans.append(("perf:other_thread", float(mid), float(mid + 250_000)))
+    rng.shuffle(spans)
+    return devices, spans
+
+
+def _assert_same_reduction(got, want):
+    assert set(got) == set(want)
+    for key in ("window_s", "busy_s", "idle_pct_idlest", "op_s", "op_s_max",
+                "collective_s_max", "longest_gap_s"):
+        assert got[key] == pytest.approx(want[key], rel=1e-9, abs=0.0), key
+    assert got["devices"] == want["devices"]
+    for key in ("device_ops", "idle_gaps"):
+        assert [nm for nm, _ in got[key]] == [nm for nm, _ in want[key]], key
+        assert [s for _, s in got[key]] == pytest.approx(
+            [s for _, s in want[key]], rel=1e-9, abs=0.0), key
+
+
+def test_sweep_agrees_with_the_pairwise_reduction_on_recorded_trace():
+    devices, spans = trace_reduce.read_planes(os.path.join(DATA, "probe.xplane.pb"))
+    got = trace_reduce.reduce_events(devices, spans)
+    _assert_same_reduction(got, _reduce_events_pairwise(devices, spans))
+    assert got == _reduce_events_pairwise(devices, spans)  # the same sums, in the same order
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_sweep_agrees_with_the_pairwise_reduction_on_random_windows(seed):
+    rng = np.random.default_rng(seed)
+    chips = int(rng.integers(1, 5))
+    devices, spans = _synthetic_window(
+        rng, chips, ops=int(rng.integers(200, 2_000)), calls=int(rng.integers(3, 12)),
+        window=seed % 4 != 1, edges=seed % 3 != 2, depth=seed % 5 != 4)
+    want = _reduce_events_pairwise(devices, spans)
+    got = trace_reduce.reduce_events(devices, spans)
+    _assert_same_reduction(got, want)
+    assert got == want
+    gaps = dict(map(tuple, got["idle_gaps"]))
+    assert gaps["no span"] > 0
+    if seed % 4 != 1:  # spans wholly outside the window read nothing
+        assert "plan" not in gaps and "reference" not in gaps
+    if seed % 3 != 2:
+        assert gaps["tnc.edge"] > 0 and gaps["other_thread"] > 0
+    # one chip at a time, as perf/chip_lib.py reduces it
+    for n in devices:
+        one = {n: devices[n]}
+        _assert_same_reduction(trace_reduce.reduce_events(one, spans),
+                               _reduce_events_pairwise(one, spans))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_interval_steps_agree_with_the_plain_ones(seed):
+    """Leaves, union and gaps by arrays against the loops above, on whole-ns
+    events that tie, repeat, touch, nest, overlap in part and last 0 ns."""
+    rng = np.random.default_rng(100 + seed)
+    n = int(rng.integers(1, 400))
+    starts = rng.integers(0, 60, size=n).astype(float)
+    ends = starts + rng.choice([0, 1, 2, 5, 20], size=n)
+    events = [(f"op{i}", s, e) for i, (s, e) in enumerate(zip(starts.tolist(), ends.tolist()))]
+    leaves = trace_reduce.leaves_only(starts, ends)
+    assert [events[i] for i in leaves.tolist()] == leaves_only(events)
+    busy = trace_reduce.union(starts[leaves], ends[leaves])
+    plain = union((s, e) for _, s, e in leaves_only(events))
+    assert list(zip(*(b.tolist() for b in busy))) == plain
+    for lo, hi in ((0.0, 90.0), (-5.0, 30.0), (float(starts.min()), float(ends.max()))):
+        clipped = [(max(s, lo), min(e, hi)) for s, e in plain if e > lo and s < hi]
+        got = trace_reduce.complement(*trace_reduce.union(
+            np.array([c[0] for c in clipped]), np.array([c[1] for c in clipped])), lo, hi)
+        assert list(zip(*(g.tolist() for g in got))) == complement(union(clipped), lo, hi)
+
+
+def test_sweep_reduces_a_four_chip_window_in_linear_time():
+    """4 chips x 250 000 ops, 1000 host spans: the gap-by-span loop needs
+    some 75 s of one CPU core for this, so a reduction that walks every
+    span for every gap cannot pass. Timed inside the test (no plugin for
+    timeouts)."""
+    import time
+
+    devices, spans = _synthetic_window(np.random.default_rng(7), 4, ops=250_000, calls=143,
+                                       edges=False)
+    assert len(spans) == 3 + 7 * 142
+    t0 = time.monotonic()
+    out = trace_reduce.reduce_events(devices, spans)
+    seconds = time.monotonic() - t0
+    print("four-chip window reduced in", seconds, "s")
+    assert seconds < 20.0
+    assert out["devices"] == 4 and 0 < out["busy_s"] < out["window_s"]
 
 
 # -- roofline count ------------------------------------------------------
