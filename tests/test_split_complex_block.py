@@ -73,6 +73,7 @@ def _run(step, a, b, mode, xp=jnp, dtype="float32", **kwargs):
     pa, pb = split_array(a, dtype), split_array(b, dtype)
     if xp is not np:
         pa, pb = tuple(map(jnp.asarray, pa)), tuple(map(jnp.asarray, pb))
+    kwargs.setdefault("interpret", True)  # the CPU runs Pallas interpreted
     re, im = apply_step_split(
         xp, pa, pb, step, precision="float32", mode=mode, **kwargs
     )
@@ -171,13 +172,13 @@ def test_block_step_takes_a_carried_operand(case, carried):
     pa = tuple(map(jnp.asarray, split_array(a)))
     pb = tuple(map(jnp.asarray, split_array(b)))
     want = apply_step_split(
-        jnp, pa, pb, step, precision="float32", mode="block"
+        jnp, pa, pb, step, precision="float32", mode="block", interpret=True
     )
     got = apply_step_split(
         jnp,
         jnp.stack(pa) if carried in ("a", "both") else pa,
         jnp.stack(pb) if carried in ("b", "both") else pb,
-        step, precision="float32", mode="block", carry=True,
+        step, precision="float32", mode="block", carry=True, interpret=True,
     )
     assert got.shape == (2,) + tuple(step.out_store)
     assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
@@ -190,7 +191,9 @@ def test_other_lowerings_take_a_carried_operand_and_hand_back_a_pair(mode):
     a, b = _operands(step, 7)
     pa = tuple(map(jnp.asarray, split_array(a)))
     pb = tuple(map(jnp.asarray, split_array(b)))
-    want = apply_step_split(jnp, pa, pb, step, precision="float32", mode=mode)
+    want = apply_step_split(
+        jnp, pa, pb, step, precision="float32", mode=mode, interpret=True
+    )
     got = apply_step_split(
         jnp, jnp.stack(pa), pb, step, precision="float32", mode=mode,
         interpret=True, carry=True,
@@ -219,7 +222,8 @@ def test_block_step_under_vmap(batched):
     )
     fn = jax.vmap(
         lambda pa, pb: apply_step_split(
-            jnp, pa, pb, step, precision="float32", mode="block"
+            jnp, pa, pb, step, precision="float32", mode="block",
+            interpret=True,
         ),
         in_axes=axes,
     )
@@ -309,7 +313,9 @@ def test_walker_carries_between_block_steps_and_hands_back_pairs(
     for upto in (1, 2, 3):  # a walk that ends on a carried value, too
         state = fresh()
         policy = plan_kernel_steps(steps[:upto])
-        apply_steps_split(jnp, steps[:upto], state, "float32", policy)
+        apply_steps_split(
+            jnp, steps[:upto], state, "float32", policy, interpret=True
+        )
         assert isinstance(state[0], tuple) and state[upto] is None
         ref = list(arrays)
         for st in steps[:upto]:
@@ -335,7 +341,7 @@ def test_walker_carries_to_a_later_step_and_no_further(upto, dots, monkeypatch):
 
     def walk(pairs):
         state = list(pairs)
-        apply_steps_split(jnp, steps[:upto], state, "float32")
+        apply_steps_split(jnp, steps[:upto], state, "float32", interpret=True)
         return state[0]
 
     pairs = [tuple(map(jnp.asarray, split_array(a))) for a in arrays]
@@ -358,7 +364,8 @@ def test_a_result_that_leaves_as_a_pair(k, dots):
     pb = tuple(map(jnp.asarray, split_array(b)))
     text = jax.jit(
         lambda x, y: apply_step_split(
-            jnp, x, y, step, precision="float32", mode="block"
+            jnp, x, y, step, precision="float32", mode="block",
+            interpret=True,
         )
     ).lower(pa, pb).as_text()
     assert text.count("stablehlo.dot_general") == dots

@@ -153,7 +153,8 @@ def _matrix_form(step, pa, pb, monkeypatch, **kwargs):
     with monkeypatch.context() as patch:
         patch.setattr(program_mod, "stream_prep_form", lambda st: "matrix")
         return apply_step_split(
-            jnp, pa, pb, step, precision="float32", mode="block", **kwargs
+            jnp, pa, pb, step, precision="float32", mode="block",
+            interpret=True, **kwargs,
         )
 
 
@@ -172,7 +173,7 @@ def test_tiled_step_is_the_step(case, monkeypatch):
     scale = float(np.max(np.abs(want)))
     pa, pb = _pairs(a, b)
     got = _complex(
-        apply_step_split(jnp, pa, pb, step, precision="float32"),
+        apply_step_split(jnp, pa, pb, step, precision="float32", interpret=True),
         step.out_store,
     )
     assert np.max(np.abs(got - want)) / scale < 1e-5
@@ -202,8 +203,12 @@ def test_tiled_step_takes_and_hands_back_every_form(case, operands, carry):
     side, view, perm, dot, cfirst, ops = _stream(step)
     a, b = _operands(step, seed=2)
     pa, pb = _pairs(a, b)
-    pair = apply_step_split(jnp, pa, pb, step, precision="float32")
-    want = apply_step_split(jnp, pa, pb, step, precision="float32", carry=carry)
+    pair = apply_step_split(
+        jnp, pa, pb, step, precision="float32", interpret=True
+    )
+    want = apply_step_split(
+        jnp, pa, pb, step, precision="float32", carry=carry, interpret=True
+    )
     want = want.plain() if isinstance(want, TiledValue) else want
     ins = {"a": pa, "b": pb}
     if operands != "pairs":
@@ -216,7 +221,8 @@ def test_tiled_step_takes_and_hands_back_every_form(case, operands, carry):
         ) is not None
         ins[side] = _as_tiled(ins[side], rows, n, view)
     got = apply_step_split(
-        jnp, ins["a"], ins["b"], step, precision="float32", carry=carry
+        jnp, ins["a"], ins["b"], step, precision="float32", carry=carry,
+        interpret=True,
     )
     stored_nrl = (side == "a") == step.swap
     if carry == "tiled" and stored_nrl:
@@ -243,7 +249,9 @@ def test_tiled_staged_step_under_either_lanemix(case, lanemix, monkeypatch):
     a, b = _operands(step, seed=5)
     want = np.asarray(apply_step(np, a, b, step))
     got = _complex(
-        apply_step_split(jnp, *_pairs(a, b), step, precision="float32"),
+        apply_step_split(
+            jnp, *_pairs(a, b), step, precision="float32", interpret=True
+        ),
         step.out_store,
     )
     assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 1e-5
@@ -267,7 +275,9 @@ def test_tiled_step_under_vmap(case, batched):
         ops[name] = rng.standard_normal(shape) + 1j * rng.standard_normal(shape)
         axes.append((0, 0) if has else None)
     fn = jax.vmap(
-        lambda pa, pb: apply_step_split(jnp, pa, pb, step, precision="float32"),
+        lambda pa, pb: apply_step_split(
+            jnp, pa, pb, step, precision="float32", interpret=True
+        ),
         in_axes=tuple(axes),
     )
     re, im = fn(*_pairs(ops["a"], ops["b"]))
@@ -289,8 +299,13 @@ def test_tiled_value_a_matrix_step_reads(monkeypatch):
     rows, n = 2**_LEGS // (4 * 128), 4
     tiled = _as_tiled(jnp.stack(pa), rows, n, step.a_view)
     for mode in ("gauss", "naive"):
-        want = apply_step_split(jnp, pa, pb, step, precision="float32", mode=mode)
-        got = apply_step_split(jnp, tiled, pb, step, precision="float32", mode=mode)
+        want = apply_step_split(
+            jnp, pa, pb, step, precision="float32", mode=mode, interpret=True
+        )
+        got = apply_step_split(
+            jnp, tiled, pb, step, precision="float32", mode=mode,
+            interpret=True,
+        )
         for g, w in zip(got, want):
             assert np.array_equal(np.asarray(g), np.asarray(w))
     want = _matrix_form(step, pa, pb, monkeypatch)
@@ -412,7 +427,9 @@ def test_plan_summary_and_counter_report_both_forms(
     first = forms.index("tiled")
     for st in steps[first - 1:first + 1]:
         a, b = _operands(st)
-        apply_step_split(jnp, *_pairs(a, b), st, precision="float32")
+        apply_step_split(
+            jnp, *_pairs(a, b), st, precision="float32", interpret=True
+        )
         apply_step_split(
             np, split_array(a, "float64"), split_array(b, "float64"), st
         )  # the host oracle counts nothing
@@ -458,8 +475,8 @@ def test_walker_hands_a_tiled_value_to_a_tiled_reader_only(monkeypatch):
 
     tiled_step = split_complex._tiled_block_step
 
-    def spy(a, b, step, precision, carry):
-        out = tiled_step(a, b, step, precision, carry)
+    def spy(a, b, step, precision, carry, interpret):
+        out = tiled_step(a, b, step, precision, carry, interpret)
         seen.append((type(a).__name__, carry, type(out).__name__))
         return out
 
@@ -467,7 +484,9 @@ def test_walker_hands_a_tiled_value_to_a_tiled_reader_only(monkeypatch):
     for upto in (1, 2, 3):
         state = [tuple(map(jnp.asarray, split_array(x))) for x in arrays]
         policy = plan_kernel_steps(steps[:upto])
-        apply_steps_split(jnp, steps[:upto], state, "float32", policy)
+        apply_steps_split(
+            jnp, steps[:upto], state, "float32", policy, interpret=True
+        )
         assert isinstance(state[0], tuple)
         ref = list(arrays)
         for st in steps[:upto]:
@@ -498,7 +517,9 @@ def test_every_tiled_step_of_a_sycamore_plan_is_the_step(sycamore24, monkeypatch
         scale = float(np.max(np.abs(want)))
         pa, pb = _pairs(a, b)
         got = _complex(
-            apply_step_split(jnp, pa, pb, st, precision="float32"),
+            apply_step_split(
+                jnp, pa, pb, st, precision="float32", interpret=True
+            ),
             st.out_store,
         )
         assert np.max(np.abs(got - want)) / scale < 1e-5
@@ -545,7 +566,7 @@ def test_sycamore24_in_float32_planes(sycamore24, monkeypatch):
     sp, arrays = sycamore24
     got = execute_sliced_batched_jax(
         sp, arrays, batch=2, chunk_steps=64, split_complex=True,
-        dtype="complex64", hoist=True, slice_range=(0, 4),
+        dtype="complex64", hoist=True, slice_range=(0, 4), interpret=True,
     )
     want = _oracle(sp, arrays, 0, 4)
     assert abs(complex(got) - complex(want)) / abs(complex(want)) < 1e-4

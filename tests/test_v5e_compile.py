@@ -622,3 +622,66 @@ def test_size_rule_leaves_small_and_gauss_steps_as_they_were(topo):
     assert step_prep_form(small) == step_prep_form(gauss) == "matrix"
     assert _lowered_digest(small, [small.a_view, small.b_view]) == SMALL_DIGEST
     assert _lowered_digest(gauss, [gauss.a_view, gauss.b_view]) == GAUSS_DIGEST
+
+
+# -- the staged prep as one Pallas pass ------------------------------------
+
+
+def test_staged_steps_compile_to_the_pass(one_chip):
+    """One staged step of each lane window of the benchmark's
+    ``sycamore53_m14`` plan among those the pass takes (an operand of
+    `MIN_PASS_ELEMS` elements or more), its streamed operand carried in
+    as the walker hands it over: the compiled step runs ONE
+    ``tnc_staged_pass`` kernel in its staged prep, and no ``dot`` or
+    ``convolution`` (the one-hot matmul) under any ``prep`` scope."""
+    import math
+    import re
+
+    from _pinned_plan import pinned_residual
+
+    from tnc_tpu.ops.program import operand_prep, streamed_side, tiled_prep_ops
+    from tnc_tpu.ops.split_complex import apply_step_split, step_prep_form
+    from tnc_tpu.ops.staged_pass import MIN_PASS_ELEMS
+
+    seen = set()
+    for step in (st for chunk in pinned_residual() for st in chunk):
+        side = streamed_side(step)
+        view, perm, dot, cfirst, ops = operand_prep(step, side)
+        if ops is None or step_prep_form(step) != "tiled":
+            continue
+        if 2 * math.prod(view) < MIN_PASS_ELEMS:
+            continue
+        window = [
+            op[1] for op in tiled_prep_ops(view, perm, dot, cfirst, True)
+            if op[0] == "lanemix"
+        ]
+        if not window or window[0] in seen:
+            continue
+        seen.add(window[0])
+        other = step.b_view if side == "a" else step.a_view
+        stream = jax.ShapeDtypeStruct(
+            (2, math.prod(view) // 128, 128), jnp.float32, sharding=one_chip
+        )
+        pair = (
+            jax.ShapeDtypeStruct((math.prod(other),), jnp.float32,
+                                 sharding=one_chip),
+        ) * 2
+
+        def run(s, e, step=step, side=side):
+            a, b = (s, e) if side == "a" else (e, s)
+            return apply_step_split(
+                jnp, a, b, step, precision="float32", carry=True
+            )
+
+        text = jax.jit(run).lower(stream, pair).compile().as_text()
+        lines = text.splitlines()
+        kernels = [
+            line for line in lines
+            if " custom-call(" in line and "tnc_staged_pass" in line
+        ]
+        assert len(kernels) == 1 and ".staged/prep" in kernels[0]
+        assert not [
+            line for line in lines
+            if re.search(r" (dot|convolution)\(", line) and "/prep" in line
+        ]
+    assert seen == {256, 512, 1024}

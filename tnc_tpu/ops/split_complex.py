@@ -413,8 +413,9 @@ def apply_step_split(
     prec = _resolve_step_precision(precision, precision_mode)
     with op_table.named_scope(scope):
         if mode == "block":
-            run = _block_step if form == "matrix" else _tiled_block_step
-            return run(apair, bpair, step, prec, carry)
+            if form == "matrix":
+                return _block_step(apair, bpair, step, prec, carry)
+            return _tiled_block_step(apair, bpair, step, prec, carry, interpret)
         return _pair_step(apair, bpair, step, mode, prec, interpret)
 
 
@@ -500,6 +501,18 @@ def _tiled_stream_ops(value, step):
     return tiled_prep_ops(view, perm, dot, cfirst, ops is not None), False
 
 
+def _stream_pass(value, ops):
+    """The plan of the one Pallas pass that runs a staged prep of the
+    streamed operand ``value`` (:func:`tnc_tpu.ops.staged_pass.pass_plan`:
+    the plan's shape decides, float32 planes only), or ``None`` where
+    the one-hot matmul keeps it. Shapes and dtypes alone: no op is
+    traced."""
+    from tnc_tpu.ops.staged_pass import pass_plan
+
+    dtype, plane = _plane_meta(value)
+    return pass_plan(2 * plane, ops) if dtype == "float32" else None
+
+
 def _step_lowering(a, b, step, mode) -> tuple[str, str]:
     """``(mode, form)`` of one step on the device, decided before any of
     its ops is traced (shapes and dtypes alone) and counted
@@ -509,18 +522,25 @@ def _step_lowering(a, b, step, mode) -> tuple[str, str]:
     counted with its reason here — and where the prep of the streamed
     operand ends: ``tiled`` (the image of its joined matrix,
     :func:`_tiled_block_step`), ``staged`` (tiled through a ``lanemix``
-    plan) or ``matrix`` (every other step and lowering)."""
+    plan) or ``matrix`` (every other step and lowering). A staged prep
+    runs as one Pallas pass or through the one-hot matmul, as its shape
+    says (:func:`_stream_pass`), counted ``ops.lanemix{impl=pass |
+    matmul}``."""
     from tnc_tpu.ops.program import step_dims, streamed_side
 
     resolved = mode or complex_mult_forced() or default_step_mode(step)
     if resolved == "block":
-        form = "matrix"
+        form, lanemix = "matrix", None
         if step_prep_form(step, resolved) == "tiled":
             streamed = a if streamed_side(step) == "a" else b
             ops, _ = _tiled_stream_ops(streamed, step)
             staged = any(op[0] == "lanemix" for op in ops)
             form = "staged" if staged else "tiled"
-        _note_step_lowering("block", "matrix" if form == "matrix" else "tiled")
+            if staged:
+                lanemix = "matmul" if _stream_pass(streamed, ops) is None else "pass"
+        _note_step_lowering(
+            "block", "matrix" if form == "matrix" else "tiled", lanemix
+        )
         return "block", form
     if mode == "fused_transpose":
         reason = fused_transpose_ineligible_reason(
@@ -747,7 +767,7 @@ def _block_step(a, b, step, precision, carry):
         return out if carry else _as_pair(out)
 
 
-def _tiled_block_step(a, b, step, precision, carry):
+def _tiled_block_step(a, b, step, precision, carry, interpret=False):
     """:func:`_block_step` for a step whose streamed operand is large
     (:func:`tnc_tpu.ops.program.stream_prep_form`): the operand's prep
     ends in the **tiled image** of its joined matrix, ``(M / 128, 2k,
@@ -761,12 +781,18 @@ def _tiled_block_step(a, b, step, precision, carry):
     streams this result through this function: the result then stays as
     the dot wrote it, a :class:`TiledValue`, and the reader's transpose
     starts from it. Every other result leaves in stored order, as from
-    :func:`_block_step`."""
+    :func:`_block_step`.
+
+    A staged prep whose shape the pass takes (:func:`_stream_pass`) is
+    ONE Pallas kernel from the stored buffer to the image
+    (:func:`tnc_tpu.ops.staged_pass.staged_image`; ``interpret`` as
+    :func:`apply_step_split` has it); every other prep runs its ops."""
     import jax.numpy as jnp
 
     from tnc_tpu.obs.op_table import named_scope
     from tnc_tpu.ops.backends import _prep_operand
     from tnc_tpu.ops.program import operand_prep, step_dims, streamed_side
+    from tnc_tpu.ops.staged_pass import staged_image
 
     stream_a = streamed_side(step) == "a"
     s, e = (a, b) if stream_a else (b, a)
@@ -774,6 +800,7 @@ def _tiled_block_step(a, b, step, precision, carry):
         step, "b" if stream_a else "a"
     )
     ops, from_image = _tiled_stream_ops(s, step)
+    plan = _stream_pass(s, ops)
     ke = 0 if e_cfirst else len(e_dot) - 1
     m, k, n = step_dims(step)
     n = min(m, n)
@@ -788,7 +815,10 @@ def _tiled_block_step(a, b, step, precision, carry):
         else:
             s = _untiled(s)
             s = jnp.stack(s) if isinstance(s, tuple) else s
-        image = _prep_operand(jnp, s, None, None, ops[-1][1], ops)
+        if plan is None:
+            image = _prep_operand(jnp, s, None, None, ops[-1][1], ops)
+        else:
+            image = staged_image(s, plan, interpret)
 
         # the other operand's 2 x 2 block, built as it lies and flattened
         # once: (2k, 2n), the halves of the result side by side
@@ -835,17 +865,23 @@ def _tiled_block_step(a, b, step, precision, carry):
         return out if carry else _as_pair(out)
 
 
-def _note_step_lowering(mode: str, prep: str = "matrix") -> None:
+def _note_step_lowering(
+    mode: str, prep: str = "matrix", lanemix: str | None = None
+) -> None:
     """Count the lowering one step was traced under
     (``ops.step_lowering{mode=...}``: once a step a trace, the arithmetic
     that runs after every fallback) — what a record quotes to say how
     far a program ran in the ``block`` form — and, beside it, where the
     prep of its streamed operand ended (``ops.step_prep{form=tiled |
-    matrix}``: :func:`_tiled_block_step`, or every other path)."""
+    matrix}``: :func:`_tiled_block_step`, or every other path) and, for
+    a staged prep, how its lane permutation runs (``ops.lanemix{impl=pass
+    | matmul}``: :func:`_stream_pass`)."""
     from tnc_tpu import obs
 
     obs.counter_add("ops.step_lowering", mode=mode)
     obs.counter_add("ops.step_prep", form=prep)
+    if lanemix is not None:
+        obs.counter_add("ops.lanemix", impl=lanemix)
 
 
 def _strassen_step_eligible(step) -> bool:
