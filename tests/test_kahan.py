@@ -115,7 +115,10 @@ def test_sliced_executors_with_kahan_match_oracle(slice_batch, monkeypatch):
     for divisor in (8.0, 4.0, 2.0):
         try:
             replace_pairs, slicing = slice_and_reconfigure(
-                inputs, result.ssa_path.toplevel, max(result.size / divisor, 2.0)
+                inputs, result.ssa_path.toplevel, max(result.size / divisor, 2.0),
+                # the divisors rely on the slicer refusing slicings too
+                # large for this test to run
+                max_slices=1 << 26,
             )
             break
         except ValueError:
@@ -175,7 +178,10 @@ def test_parallel_oracle_pool_path_matches_serial():
     for divisor in (8.0, 4.0, 2.0):
         try:
             replace_pairs, slicing = slice_and_reconfigure(
-                inputs, result.ssa_path.toplevel, max(result.size / divisor, 2.0)
+                inputs, result.ssa_path.toplevel, max(result.size / divisor, 2.0),
+                # the divisors rely on the slicer refusing slicings too
+                # large for this test to run
+                max_slices=1 << 26,
             )
             break
         except ValueError:

@@ -35,9 +35,17 @@ from tnc_tpu.contractionpath.contraction_path import (
 )
 from tnc_tpu.contractionpath.paths.base import Pathfinder
 from tnc_tpu.contractionpath.paths.greedy import _ssa_greedy
+from tnc_tpu.contractionpath.sliced_cost import MAX_SLICES
 from tnc_tpu.partitioning.bisect import bisect
 from tnc_tpu.partitioning.hypergraph import Hypergraph
 from tnc_tpu.tensornetwork.tensor import LeafTensor
+
+#: The search ranks first the trials whose unrepaired greedy slicing
+#: needs at most this many slices (:func:`Hyperoptimizer._solve_toplevel`):
+#: a preference, not a cap. A trial past it is usually a poor tree that
+#: repair would not save, and the configurations' plans were chosen under
+#: this ranking.
+RANK_SLICES = 1 << 26
 
 
 class Hyperoptimizer(Pathfinder):
@@ -261,12 +269,39 @@ class Hyperoptimizer(Pathfinder):
             ).toplevel
             ev = SlicedCostEvaluator(inputs, replace, cost_model=cost_model)
             try:
-                greedy_slice_to_target(ev, self.target_size)
+                greedy_slice_to_target(ev, self.target_size, rank_slices)
                 entry = (ev.cost(), tuple(sorted(ev.removed)))
             except ValueError:
                 entry = (math.inf, ())
             rank_cache[key] = entry
             return entry[0]
+
+        # The ranking prefers trials whose unrepaired greedy slicing stays
+        # within RANK_SLICES: one that needs more reads inf and ranks
+        # behind them. Where no trial does, the raw-flops best is left to
+        # the caller's classic slice-and-repair pass, as the plans of
+        # every configuration were made — unless that pass too needs more
+        # than RANK_SLICES (Sycamore m=20 at one chip's 2^29 target: 2^39
+        # where the joint search finds 2^31): then every trial is ranked
+        # by its whole sliced cost, up to the slicers' MAX_SLICES, and the
+        # joint search refines the best of them.
+        rank_slices = RANK_SLICES
+        if use_joint and all(
+            math.isinf(trial_sliced_rank(c)) for c in candidates
+        ):
+            from tnc_tpu.contractionpath.slicing import slice_and_reconfigure
+
+            try:
+                classic = slice_and_reconfigure(
+                    inputs, min(candidates, key=evaluate), self.target_size,
+                    reconf_rounds=1, step_budget=None, final_rounds=2,
+                    final_budget=None, fuse=False,
+                )[1].num_slices
+            except ValueError:
+                classic = math.inf
+            if classic > RANK_SLICES:
+                rank_cache.clear()
+                rank_slices = MAX_SLICES
 
         def joint_final(candidate: list[tuple[int, int]]) -> tuple:
             """Joint mode, stage 2 (finalists + polish snapshots):

@@ -48,6 +48,15 @@ from typing import Sequence
 from tnc_tpu.contractionpath.contraction_tree import ContractionTree
 from tnc_tpu.tensornetwork.tensor import LeafTensor
 
+#: The most slices any slicer here hands out: a plan's slice ids and the
+#: end of its last range (``num_slices`` itself) are int64 on the host
+#: (the chunked executor's index rows, the checkpoint cursor), so this is
+#: the largest count whose ids stay exact. It is no budget: a fleet gives
+#: each device a contiguous range of such a plan (Sycamore at m = 20 is
+#: 2^31 slices at one chip's 2^29 target), and the slicers stop at the
+#: memory target, never here, on every plan that can run.
+MAX_SLICES = (1 << 63) - 1
+
 
 class SlicedCostEvaluator:
     """Incremental hoist-aware sliced-cost evaluator.
@@ -430,7 +439,7 @@ class SlicedCostEvaluator:
 def greedy_slice_to_target(
     ev: SlicedCostEvaluator,
     target_size: float,
-    max_slices: int = 1 << 26,
+    max_slices: int = MAX_SLICES,
     max_leg_candidates: int = 48,
 ) -> None:
     """Greedily grow ``ev``'s slice set until the sliced peak fits
@@ -509,7 +518,7 @@ def anneal_sliced(
     t_start: float,
     t_end: float,
     target_size: float,
-    max_slices: int = 1 << 26,
+    max_slices: int = MAX_SLICES,
     p_slice_move: float = 0.25,
     p_partition_move: float = 0.0,
 ) -> None:
@@ -709,7 +718,7 @@ def joint_slice_search(
     reconf_rounds: int = 1,
     final_rounds: int = 2,
     seed: int = 42,
-    max_slices: int = 1 << 26,
+    max_slices: int = MAX_SLICES,
     temps: tuple[float, float] = (0.3, 0.01),
     p_partition_move: float = 0.0,
 ) -> tuple[list[tuple[int, int]], "Slicing", float]:

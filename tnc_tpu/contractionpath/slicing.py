@@ -30,6 +30,7 @@ from typing import Sequence
 
 from tnc_tpu import obs
 from tnc_tpu.contractionpath.contraction_path import ContractionPath
+from tnc_tpu.contractionpath.sliced_cost import MAX_SLICES
 from tnc_tpu.tensornetwork.tensor import LeafTensor
 
 __all__ = [
@@ -313,7 +314,7 @@ def find_slicing(
     inputs: Sequence[LeafTensor],
     replace_path: Sequence[tuple[int, int]],
     target_size: float,
-    max_slices: int = 1 << 24,
+    max_slices: int = MAX_SLICES,
 ) -> Slicing:
     """Greedily pick legs to slice until the path's peak intermediate size
     (in elements, out+in1+in2 model) is at most ``target_size``.
@@ -453,7 +454,7 @@ def find_parallel_slicing(
     if base is None and target_size is not None:
         removed = set(
             find_slicing(
-                inputs, replace_path, target_size, max_slices=1 << 40
+                inputs, replace_path, target_size
             ).legs
         )
 
@@ -519,7 +520,7 @@ def slice_and_reconfigure(
     final_rounds: int = 8,
     step_budget: float | None = 4.0,
     final_budget: float | None = 45.0,
-    max_slices: int = 1 << 26,
+    max_slices: int = MAX_SLICES,
     max_leg_candidates: int = 48,
     cost_model=None,
     seed_slices: "Sequence[int] | Slicing | None" = None,

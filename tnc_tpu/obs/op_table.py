@@ -38,6 +38,7 @@ See ``docs/observability.md`` ("Device time by plan step").
 
 from __future__ import annotations
 
+import math
 import re
 import threading
 import time
@@ -447,7 +448,8 @@ def step_facts(record: ModuleRecord) -> list[dict]:
     """Per step of a registered program the static facts the program
     already computes: the scope it was traced under, split into
     ``size`` / ``mode`` / ``form``; the elements it streams a run
-    (larger operand in + result out); its multiply-adds and ``k``;
+    (larger operand in + result out) and its larger operand's alone
+    (``operand``); its multiply-adds and ``k``;
     ``runs`` (``row``: once a slice; ``once``: once a dispatch); its
     index in the plan that was handed out."""
     from tnc_tpu.ops.program import step_dims, step_flops, step_streamed_elems
@@ -461,6 +463,7 @@ def step_facts(record: ModuleRecord) -> list[dict]:
         facts.append({
             "number": number, "scope": scope, "size": size, "mode": mode,
             "form": form, "elements": step_streamed_elems(step),
+            "operand": max(math.prod(step.a_view), math.prod(step.b_view)),
             "macs": step_flops(step), "k": step_dims(step)[1],
             "runs": runs, "plan_index": plan_index,
         })

@@ -670,10 +670,15 @@ def run_sliced_chunked_placed(
             sp, batch, chunk_steps, split_complex, precision, interpret
         )
 
-    # per-slot slice indices, shape [num, n_sliced_legs]
+    # per-slot slice indices of this call's rows alone, shape
+    # [num - lo, n_sliced_legs]: a range at any offset of a plan of 2^31
+    # slices costs its own rows (ids are int64 here; each digit is below
+    # its leg's dim)
     all_indices = np.stack(
-        slice_indices(sp.slicing.dims, np.arange(num)), axis=1
+        slice_indices(sp.slicing.dims, np.arange(lo, num, dtype=np.int64)),
+        axis=1,
     ).astype(np.int32)
+    obs.counter_add("sliced.index_rows", num - lo)
 
     import jax
 
@@ -695,7 +700,7 @@ def run_sliced_chunked_placed(
         # zero-step program: the result is the (sliced) leaf itself —
         # sum its first `num` slices in one dispatch
         info = sp.slot_slices[sp.program.result_slot]
-        idx_all = place(all_indices[lo:num])
+        idx_all = place(all_indices)
 
         def leaf_sum(buf, idx):
             rows = jax.vmap(lambda i: index_buffer(jnp, buf, info, i))(idx)
@@ -730,7 +735,7 @@ def run_sliced_chunked_placed(
         dispatches = 0
         while start < num:
             b = min(batch, num - start)
-            idx = place(all_indices[start : start + b])
+            idx = place(all_indices[start - lo : start - lo + b])
 
             # leaf in_slots receive the FULL buffers; each chunk's jit
             # indexes them row by row and the last one folds the

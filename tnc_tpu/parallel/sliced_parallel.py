@@ -106,6 +106,13 @@ def _make_spmd_fn(
             f"num_slices ({num}) must be divisible by mesh size ({n_devices})"
         )
     chunk = _effective_chunk(num, n_devices, max_slices)
+    if n_devices * chunk > 1 << 31:
+        # the loop's slice id `my * chunk + k` is an int32 on the device
+        raise ValueError(
+            f"{n_devices * chunk} slices: on-device slice ids are exact "
+            "below 2^31; run a range of such a plan through the chunked "
+            "executor's slice_range"
+        )
 
     hp = None
     if hoist:

@@ -48,10 +48,12 @@ from __future__ import annotations
 
 import json
 import logging
+import math
 import os
 import threading
 import time
 import uuid
+from dataclasses import dataclass
 from pathlib import Path
 
 from tnc_tpu import obs
@@ -103,9 +105,16 @@ class PlanCache:
     True
     """
 
-    def __init__(self, directory: str | Path, max_entries: int = 256):
+    def __init__(
+        self, directory: str | Path, max_entries: int = 256,
+        read_only: bool = False,
+    ):
         self.directory = Path(directory)
-        self.directory.mkdir(parents=True, exist_ok=True)
+        # a fleet's shared copy: loads never touch, delete or write an
+        # entry, and store/invalidate refuse
+        self.read_only = bool(read_only)
+        if not self.read_only:
+            self.directory.mkdir(parents=True, exist_ok=True)
         self.max_entries = max(1, int(max_entries))
         # explicit per-key hit counts (process-local; the on-disk LRU
         # touch only *implies* heat via mtime): what the background
@@ -233,18 +242,20 @@ class PlanCache:
             )
             self._count("corrupt")
             self._count("miss")
-            try:
-                target.unlink(missing_ok=True)
-            except OSError:
-                pass
+            if not self.read_only:
+                try:
+                    target.unlink(missing_ok=True)
+                except OSError:
+                    pass
             return None
         self._count("hit")
         with self._hits_lock:
             self._hits[key] = self._hits.get(key, 0) + 1
-        try:  # LRU touch: mtime records last use
-            os.utime(target)
-        except OSError:
-            pass
+        if not self.read_only:
+            try:  # LRU touch: mtime records last use
+                os.utime(target)
+            except OSError:
+                pass
         return plan
 
     def hits(self, key: str) -> int:
@@ -295,6 +306,7 @@ class PlanCache:
         directory): the temp file is uniquely named per writer (pid +
         random suffix), so two replicas racing on one key can never
         interleave bytes — the last complete ``os.replace`` wins."""
+        self._writable("store")
         target = self._path(key)
         tmp = target.with_name(
             f"{key}.{os.getpid()}.{uuid.uuid4().hex[:8]}.json.tmp"
@@ -320,6 +332,7 @@ class PlanCache:
         self._evict()
 
     def invalidate(self, key: str) -> None:
+        self._writable("invalidate")
         try:
             self._path(key).unlink(missing_ok=True)
         except OSError:
@@ -327,6 +340,12 @@ class PlanCache:
         with self._hits_lock:
             self._hits.pop(key, None)
         self._count("invalidated")
+
+    def _writable(self, what: str) -> None:
+        if self.read_only:
+            raise PermissionError(
+                f"plan store {self.directory} is read-only: no {what}"
+            )
 
     def _entries(self) -> list[Path]:
         return [
@@ -367,3 +386,75 @@ class PlanCache:
 
     def __len__(self) -> int:
         return len(self._entries())
+
+
+class PlanStoreMiss(LookupError):
+    """The store holds no plan for this network's structure and budget,
+    or one made for another structure: a fleet never plans at run
+    time, so the caller fails instead."""
+
+
+@dataclass
+class StoredPlan:
+    """A plan read from a shared store and rebuilt for one network."""
+
+    key: str
+    path: ContractionPath
+    slicing: Slicing
+    sliced: object  # SlicedProgram
+    sig_drift: bool  # the rebuilt program's signature is not the stored one
+    slice_peak_log2: float  # largest intermediate of one slice, log2 elements
+
+
+def load_stored_plan(
+    directory: str | Path, tn: CompositeTensor, target_size: float
+) -> StoredPlan:
+    """The sliced plan a fleet made once and shares, for ``tn``: looked
+    up by structure digest in a read-only :class:`PlanCache` and rebuilt
+    from its stored pairs and slicing, under the phase ``plan.store``
+    (``hit``, ``sig_drift``, ``bytes``, ``legs``, ``num_slices``).
+
+    What is pinned is the PLAN, not the program built from it: where the
+    rebuilt program's signature differs from the stored ``program_sig``
+    (a later change to how steps are built) the stored pairs and slicing
+    still run, and the drift is recorded, never a reason to replan. A
+    missing entry, or one stored for another structure, raises
+    :class:`PlanStoreMiss`. Sets the gauge ``plan.slice_peak_log2``: the
+    largest intermediate of one slice, log2 of its elements."""
+    from tnc_tpu.ops.sliced import build_sliced_program
+
+    with obs.phase("plan.store", directory=str(directory)) as span:
+        cache = PlanCache(directory, read_only=True)
+        key = cache.key_for_network(tn, target_size)
+        plan = cache.load(key)
+        if plan is None:
+            span.set(hit=0)
+            raise PlanStoreMiss(
+                f"plan store {directory} has no entry {key}.json for this "
+                f"network at target {target_size:.6g}"
+            )
+        if plan.get("structure") != key:
+            span.set(hit=0)
+            raise PlanStoreMiss(
+                f"plan store entry {key}.json was stored for structure "
+                f"{plan.get('structure')!r}, not this network's {key!r}"
+            )
+        path = cache.plan_path(plan)
+        slicing = cache.plan_slicing(plan) or Slicing((), ())
+        sliced = build_sliced_program(tn, path, slicing)
+        drift = not cache.validate(plan, sliced.program) or plan.get(
+            "sliced_sig"
+        ) not in (None, sliced.signature_digest())
+        if drift:
+            logger.warning(
+                "plan store entry %s rebuilds a program of another "
+                "signature; running its stored pairs and slicing", key,
+            )
+        span.set(hit=1, sig_drift=int(drift), legs=len(slicing.legs),
+                 num_slices=slicing.num_slices)
+        span.add(bytes=cache._path(key).stat().st_size)
+    peak = math.log2(max(
+        (math.prod(st.out_store) for st in sliced.program.steps), default=1
+    ))
+    obs.gauge_set("plan.slice_peak_log2", peak)
+    return StoredPlan(key, path, slicing, sliced, drift, peak)
